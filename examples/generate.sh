@@ -5,6 +5,8 @@
 #
 # Usage: CHECKPOINT_PATH=./checkpoints/llama2-7b TOKENIZER_MODEL=tok.model \
 #        bash examples/generate.sh
+# (the tool's default tokenizer is SentencePieceTokenizer, which needs
+# --tokenizer_model; pass other --tokenizer_type flags after the script)
 set -euo pipefail
 
 CHECKPOINT_PATH=${CHECKPOINT_PATH:?set CHECKPOINT_PATH}
@@ -12,6 +14,7 @@ PORT=${PORT:-5000}
 
 python tools/run_text_generation_server.py \
   --load "$CHECKPOINT_PATH" \
+  --model "${MODEL:-llama}" \
   --port "$PORT" \
   ${TOKENIZER_MODEL:+--tokenizer_model "$TOKENIZER_MODEL"} \
   "$@"

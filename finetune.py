@@ -42,6 +42,10 @@ def model_provider(args, mcfg):
 
 
 def main(argv=None):
+    from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     parser = build_base_parser()
     args = parser.parse_args(argv)
 

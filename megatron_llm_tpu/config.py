@@ -384,10 +384,10 @@ class ParallelConfig:
                 raise ValueError(
                     "quantized_grad_reduce is only available on pure-dp "
                     "meshes (tp=pp=cp=1): the explicit reduce-scatter "
-                    "path runs the fwd/bwd inside a data-manual "
-                    "shard_map, which cannot nest inside the tp/pp/cp "
-                    "programs on this XLA build (docs/GUIDE.md, 'ZeRO-1 "
-                    "distributed optimizer')"
+                    "path runs the fwd/bwd inside a fully manual "
+                    "shard_map, which the tp/pp/cp programs are not "
+                    "written for (docs/GUIDE.md, 'ZeRO-1 distributed "
+                    "optimizer')"
                 )
             if self.data_parallel_size <= 1:
                 raise ValueError(
@@ -413,8 +413,8 @@ class ParallelConfig:
                 raise ValueError(
                     f"{flag} is only available on pure-dp meshes "
                     f"(tp=pp=cp=1): the explicit path runs the fwd/bwd "
-                    f"inside a data-manual shard_map, which cannot nest "
-                    f"inside the tp/pp/cp programs on this XLA build "
+                    f"inside a fully manual shard_map, which the "
+                    f"tp/pp/cp programs are not written for "
                     f"(docs/GUIDE.md, 'Collective overlap scheduling')")
             if self.data_parallel_size <= 1:
                 raise ValueError(

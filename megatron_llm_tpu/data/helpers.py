@@ -2,8 +2,9 @@
 
 ref analogue: megatron/data/dataset_utils.py `compile_helper` +
 `from megatron.data import helpers`. Here the C++ is compiled once with g++
-into `_helpers.so` next to the source and bound via ctypes; a pure-numpy
-fallback keeps everything working when no compiler is available.
+into `_helpers.so` next to the source (built on demand, never committed)
+and bound via ctypes; a pure-numpy fallback keeps everything working when
+no compiler is available. Which of the two is in use is printed once.
 """
 
 from __future__ import annotations
@@ -21,7 +22,8 @@ _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 
-def _compile() -> bool:
+def _compile() -> Optional[str]:
+    """Build _helpers.so; returns None on success, else why it failed."""
     src = os.path.join(_CSRC, "helpers.cpp")
     try:
         subprocess.run(
@@ -30,9 +32,11 @@ def _compile() -> bool:
             capture_output=True,
             timeout=120,
         )
-        return True
-    except Exception:
-        return False
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{type(e).__name__}: {e}"
+    except subprocess.CalledProcessError as e:
+        return "g++ failed: " + e.stderr.decode(errors="replace")[-500:]
+    return None
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -40,15 +44,21 @@ def _load() -> Optional[ctypes.CDLL]:
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
+    why = None
     if not os.path.exists(_SO_PATH) or os.path.getmtime(_SO_PATH) < os.path.getmtime(
         os.path.join(_CSRC, "helpers.cpp")
     ):
-        if not _compile():
-            return None
-    try:
-        lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+        why = _compile()
+    lib = None
+    if why is None:
+        try:
+            lib = ctypes.CDLL(_SO_PATH)
+        except OSError as e:
+            why = f"OSError: {e}"
+    if lib is None:
+        print(f"dataset index builder: numpy fallback ({why})", flush=True)
         return None
+    print(f"dataset index builder: native ({_SO_PATH})", flush=True)
     lib.build_sample_idx.argtypes = [
         ctypes.POINTER(ctypes.c_int32),
         ctypes.POINTER(ctypes.c_int32),

@@ -22,90 +22,90 @@ checkpoints drop in unchanged.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from megatron_llm_tpu.models.remat import tag as _savepoint
 from megatron_llm_tpu.models.rope import apply_rope
 from megatron_llm_tpu.ops.quantization import qdot
 from megatron_llm_tpu.parallel.mesh import (
     CONTEXT_AXIS,
+    DATA_AXIS,
+    MODEL_AXIS,
     get_context,
     in_manual_region,
     shard_activation,
-    shard_map as _shard_map,
+    shard_kernel,
 )
 
+# how the attention kernels' operands split over the mesh: batch over
+# `data`, KV groups (and with them the q heads) over `model`
+_Q_SPEC = P(DATA_AXIS, None, MODEL_AXIS, None, None)  # (b, s, g, qpk, d)
+_KV_SPEC = P(DATA_AXIS, None, MODEL_AXIS, None)  # (b, t, g, d) "tgd"
+_KV_GTD_SPEC = P(DATA_AXIS, MODEL_AXIS, None, None)  # (b, g, T, d)
 
-def _ring_dispatch(pctx, q, k, v, doc_start=None):
-    """Ring attention over the `context` mesh axis. Outside any manual
-    region: a seq-sharded shard_map with `data`/`model` GSPMD-auto inside.
-    Inside the pipeline's manual region `context` is already a manual axis
-    of the enclosing shard_map (pipeline.py declares it when cp>1), so the
-    ring body is called directly on the local seq shard. `doc_start`
+
+def _ring_dispatch(q, k, v, doc_start=None):
+    """Ring attention over the `context` mesh axis, in a region manual
+    over the WHOLE mesh (`shard_kernel`: the per-hop flash kernel must sit
+    in a fully manual region) with the sequence over `context`, batch over
+    `data` and groups over `model` — the ring body is row- and
+    group-independent. Inside the pipeline's manual region `context` is
+    already a manual axis of the enclosing shard_map (pipeline.py declares
+    it when cp>1) and the operands are the local seq shards: where the hop
+    is the Mosaic kernel `shard_kernel` makes the remaining axes manual
+    round the same body; where it is XLA (off the TPU, packed documents,
+    a shape the flash gate turns away) the body is called as is — it
+    needs no more than `context`, and a nested shard_map round XLA hops
+    trips jax 0.9.0's residual naming (KNOWN_FAILURES.md). `doc_start`
     (b, s) — global document-start indices — rides along seq-sharded for
     packed-document (--reset_attention_mask) training."""
-    import functools
-
-    from jax.sharding import PartitionSpec as P
-
+    from megatron_llm_tpu.ops.flash_attention import flash_reaches_kernel
     from megatron_llm_tpu.parallel.ring_attention import ring_self_attention
 
-    if in_manual_region():
+    def ring(q, k, v, *ds):
         return ring_self_attention(q, k, v, CONTEXT_AXIS, causal=True,
-                                   doc_start=doc_start)
+                                   doc_start=ds[0] if ds else None)
 
-    # the batch axis is manual too (the ring body is row-independent and
-    # the activations are already data-sharded): with `data` inside the
-    # manual set, pure dp x cp meshes reach this XLA build's fully-manual
-    # path instead of its broken partial-manual partitioner
-    # (parallel/mesh.py shard_map adapter) — and on newer builds it is
-    # an equivalent, equally-correct manualization.
-    from megatron_llm_tpu.parallel.mesh import DATA_AXIS
-
-    qspec = P(DATA_AXIS, CONTEXT_AXIS, None, None, None)
-    kspec = P(DATA_AXIS, CONTEXT_AXIS, None, None)
-    if doc_start is None:
-        ring = _shard_map(
-            functools.partial(
-                ring_self_attention, axis_name=CONTEXT_AXIS, causal=True
-            ),
-            in_specs=(qspec, kspec, kspec),
-            out_specs=qspec,
-            axis_names={DATA_AXIS, CONTEXT_AXIS},
-            mesh=pctx.mesh,
-        )
-        return ring(q, k, v)
-
-    ring = _shard_map(
-        lambda q_, k_, v_, ds: ring_self_attention(
-            q_, k_, v_, CONTEXT_AXIS, causal=True, doc_start=ds
-        ),
-        in_specs=(qspec, kspec, kspec, P(DATA_AXIS, CONTEXT_AXIS)),
-        out_specs=qspec,
-        axis_names={DATA_AXIS, CONTEXT_AXIS},
-        mesh=pctx.mesh,
-    )
-    return ring(q, k, v, doc_start.astype(jnp.int32))
+    operands = (q, k, v)
+    if doc_start is not None:
+        operands += (doc_start.astype(jnp.int32),)
+    if in_manual_region() and not (
+            doc_start is None and flash_reaches_kernel(q.shape, k.shape[1])):
+        return ring(*operands)
+    qspec = P(DATA_AXIS, CONTEXT_AXIS, MODEL_AXIS, None, None)
+    kspec = P(DATA_AXIS, CONTEXT_AXIS, MODEL_AXIS, None)
+    in_specs = (qspec, kspec, kspec, P(DATA_AXIS, CONTEXT_AXIS))
+    return shard_kernel(ring, in_specs[:len(operands)], qspec)(*operands)
 
 
-def _decode_kernel_block(cfg, s: int, t: int):
+def _decode_kernel_block(cfg, s: int, t: int, layout: str):
     """Static gate for the Pallas decode-attention kernel on the KV-cache
     paths: returns the cache block size, or None for the XLA fallback.
     Kernel territory is the single-token decode step (s == 1) against a
     cache of at least `decode_attn_min_cache` positions; prefill chunks
-    (s > 1) keep the batched-GEMM path, which is compute-bound."""
-    if not cfg.use_decode_attn:
+    (s > 1) keep the batched-GEMM path, which is compute-bound. A decode
+    step the gate turns away is reported (ops/dispatch.py); a cache
+    shorter than `decode_attn_min_cache` is the config's own routing and
+    is not."""
+    if not cfg.use_decode_attn or s != 1:
         return None
     from megatron_llm_tpu.ops.decode_attention import decode_attn_block
+    from megatron_llm_tpu.ops.dispatch import report_fallback
 
-    return decode_attn_block(
+    bt = decode_attn_block(
         s, cfg.q_per_kv, cfg.head_dim, t,
         min_cache=cfg.decode_attn_min_cache,
         interpret=cfg.decode_attn_interpret,
     )
+    if bt is None and t >= cfg.decode_attn_min_cache:
+        report_fallback("decode_attention", "decode_attn_block",
+                        qpk=cfg.q_per_kv, d=cfg.head_dim, T=t, layout=layout)
+    return bt
 
 
 def split_qkv(mixed: jnp.ndarray, cfg) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -258,15 +258,15 @@ def attention_block(
       step (s == 1): every slot is a width-1 chunk at its length.
 
     On a tp serving mesh (DecodeEngine(serving_tp>1), ISSUE 14) BOTH
-    paged forms run group-sharded with no changes here: the pools
-    arrive sharded on the group axis (kv_pool_spec), the existing
-    shard_activation("groups"/"heads") constraint sites steer q and
-    the attention output onto the same split, and GSPMD partitions the
-    scatter + attention per shard (each chip runs the kernels — or
-    their XLA twins — over its own groups against replicated page
-    tables/lengths). The wo matmul below is the step's one collective
-    (row-parallel partial-sum all-reduce, pinned by the tp2 audit
-    rows).
+    paged forms run group-sharded: the pools arrive sharded on the group
+    axis (kv_pool_spec), the shard_activation("groups"/"heads")
+    constraint sites steer q and the attention output onto the same
+    split, and the scatter + attention call runs per shard inside
+    `shard_kernel` (each chip runs the kernel — or its XLA twin — over
+    its own groups against replicated page tables/lengths; Mosaic
+    kernels cannot be partitioned by GSPMD). The wo matmul below is the
+    step's one collective (row-parallel partial-sum all-reduce, pinned
+    by the tp2 audit rows).
     """
     b, s, h = hidden.shape
     compute_dtype = cfg.compute_dtype
@@ -329,17 +329,40 @@ def attention_block(
         # key like "chunk_lens": present only when the caller packs
         # documents, absent from the engine's carries.
         doc_starts = kv_cache.get("doc_starts")
-        res = ragged_paged_attention(
-            q, k, v, kv_cache["k_pages"], kv_cache["v_pages"],
-            page_table, lengths, chunk_lens,
-            use_pallas=cfg.use_decode_attn,
-            min_cache=cfg.decode_attn_min_cache,
-            interpret=cfg.decode_attn_interpret,
-            k_scales=kv_cache.get("k_scales"),
-            v_scales=kv_cache.get("v_scales"),
-            window_size=getattr(cfg, "attention_window_size", None),
-            doc_starts=doc_starts,
-        )
+        window = getattr(cfg, "attention_window_size", None)
+
+        def paged(q, k, v, k_pages, v_pages, page_table, lengths,
+                  chunk_lens, *rest):
+            k_scales, v_scales = rest[:2] if quantized else (None, None)
+            return ragged_paged_attention(
+                q, k, v, k_pages, v_pages, page_table, lengths,
+                chunk_lens,
+                use_pallas=cfg.use_decode_attn,
+                min_cache=cfg.decode_attn_min_cache,
+                interpret=cfg.decode_attn_interpret,
+                k_scales=k_scales, v_scales=v_scales,
+                window_size=window,
+                doc_starts=rest[-1] if doc_starts is not None else None,
+            )
+
+        # on a tp serving mesh the pools arrive sharded on the group
+        # axis (kv_pool_spec): each chip scatters and attends over its
+        # own groups against the replicated page table / lengths
+        pool = P(None, None, MODEL_AXIS, None)
+        qspec = P(None, None, MODEL_AXIS, None, None)
+        operands = [q, k, v, kv_cache["k_pages"], kv_cache["v_pages"],
+                    page_table, lengths, chunk_lens]
+        in_specs = [qspec, pool, pool, pool, pool, P(), P(), P()]
+        out_specs = [qspec, pool, pool]
+        if quantized:
+            operands += [kv_cache["k_scales"], kv_cache["v_scales"]]
+            in_specs += [P(None, None, MODEL_AXIS)] * 2
+            out_specs += [P(None, None, MODEL_AXIS)] * 2
+        if doc_starts is not None:
+            operands.append(doc_starts)
+            in_specs.append(P())
+        res = shard_kernel(paged, in_specs, tuple(out_specs),
+                           check_vma=False)(*operands)
         # cache pytree layout is carry-stable: "chunk_lens" stays a key
         # only in the chunked form (the decode scan's carry never grows)
         new_cache = {"page_table": page_table,
@@ -382,7 +405,7 @@ def attention_block(
             )
             new_cache = {"k_gtd": kc, "v_gtd": vc, "offset": offset + s}
             t = kc.shape[2]
-            bt = _decode_kernel_block(cfg, s, t)
+            bt = _decode_kernel_block(cfg, s, t, "gtd")
             if bt is not None:
                 # Pallas decode-attention kernel: streams the cache at
                 # line rate with in-kernel length masking (the XLA
@@ -391,10 +414,13 @@ def attention_block(
                     decode_attention,
                 )
 
-                ctx = decode_attention(
-                    q, kc, vc, offset + s, layout="gtd", use_pallas=True,
-                    block_t=bt, interpret=cfg.decode_attn_interpret,
-                )
+                ctx = shard_kernel(
+                    functools.partial(
+                        decode_attention, layout="gtd", use_pallas=True,
+                        block_t=bt, interpret=cfg.decode_attn_interpret),
+                    (_Q_SPEC, _KV_GTD_SPEC, _KV_GTD_SPEC, P()), _Q_SPEC,
+                    check_vma=False,
+                )(q, kc, vc, offset + s)
             else:
                 from megatron_llm_tpu.ops.decode_attention import (
                     _xla_decode,
@@ -436,7 +462,7 @@ def attention_block(
                 kv_cache["v"], v, offset, axis=1)
             new_cache = {"k": k_full, "v": v_full, "offset": offset + s}
         t = k_full.shape[1]
-        bt = _decode_kernel_block(cfg, s, t)
+        bt = _decode_kernel_block(cfg, s, t, "tgd")
         if bt is not None:
             # stage-ring pipelined decode ticks land here (stacked cache,
             # s == 1): stream this layer's (b, T, g, d) cache slice
@@ -446,11 +472,13 @@ def attention_block(
                 decode_attention,
             )
 
-            ctx = decode_attention(
-                q, k_full, v_full, offset + s, layout="tgd",
-                use_pallas=True, block_t=bt,
-                interpret=cfg.decode_attn_interpret,
-            ).reshape(b, s, -1)
+            ctx = shard_kernel(
+                functools.partial(
+                    decode_attention, layout="tgd", use_pallas=True,
+                    block_t=bt, interpret=cfg.decode_attn_interpret),
+                (_Q_SPEC, _KV_SPEC, _KV_SPEC, P()), _Q_SPEC,
+                check_vma=False,
+            )(q, k_full, v_full, offset + s).reshape(b, s, -1)
         else:
             # rows attend to cols <= offset+row
             rows = offset + jnp.arange(s)[:, None]
@@ -519,7 +547,7 @@ def attention_block(
         flash_ok = cfg.use_flash_attn and mask is None and no_dropout \
             and doc_start is None
         if ring_ok:
-            ctx = _ring_dispatch(pctx, q, k, v, doc_start=doc_start)
+            ctx = _ring_dispatch(q, k, v, doc_start=doc_start)
             ctx = _savepoint(ctx, "attn_ctx").reshape(b, s, -1)
         elif flash_ok:
             from megatron_llm_tpu.ops.flash_attention import flash_attention
@@ -528,7 +556,10 @@ def attention_block(
             # ("attn_ctx"/"flash_lse", ops/flash_attention.py) so the
             # selective policy can keep both and the backward never
             # re-runs the forward kernel
-            ctx = flash_attention(q, k, v, causal=True)
+            ctx = shard_kernel(
+                functools.partial(flash_attention, causal=True),
+                (_Q_SPEC, _KV_SPEC, _KV_SPEC), _Q_SPEC,
+            )(q, k, v)
             ctx = ctx.reshape(b, s, -1)
         else:
             if mask is None:

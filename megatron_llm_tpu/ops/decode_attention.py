@@ -53,10 +53,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from megatron_llm_tpu.ops import dispatch
 from megatron_llm_tpu.ops.flash_attention import (
     LOG2E,
     _causal_invalid,
-    _compiler_params,
     _out_struct,
     _softmax_accum,
     _softmax_finalize,
@@ -98,7 +98,7 @@ def decode_attn_block(s: int, qpk: int, d: int, T: int, *,
     the interpreter (the test path); otherwise TPU-only, mirroring
     flash_attention's backend dispatch.
     """
-    if not (interpret or jax.default_backend() == "tpu"):
+    if not (interpret or dispatch.on_tpu()):
         return None
     if s != 1 or s * qpk > MAX_DECODE_ROWS or d % 128 != 0:
         return None
@@ -217,10 +217,15 @@ def _decode_pallas(q, k, v, length, layout, block_t, interpret):
             ),
         )
     else:  # "tgd"
+        # a (block_t, d) block of (b, T, g, d) would squeeze the second-
+        # minor group axis, which Mosaic refuses; the free row-major
+        # (b, T, g*d) view puts group ig at lane block ig instead
+        k = k.reshape(b, T, g * d)
+        v = v.reshape(b, T, g * d)
         kv_spec = pl.BlockSpec(
-            (None, block_t, None, d),
+            (None, block_t, d),
             lambda ib, ig, j, len_ref: (
-                ib, jnp.minimum(j, last_block(len_ref)), ig, 0
+                ib, jnp.minimum(j, last_block(len_ref)), ig
             ),
         )
 
@@ -241,7 +246,7 @@ def _decode_pallas(q, k, v, length, layout, block_t, interpret):
         out_shape=_out_struct((b, g, rows, d), out_dtype, qf, k, v),
         # (b, g) steps are independent; only the cache dim carries the
         # online-softmax scratch state
-        compiler_params=None if interpret else _compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -282,13 +287,14 @@ def decode_attention(
     >= `length` are masked in-kernel; within the step rows are causal
     (row r attends through position length - s + r)."""
     assert layout in ("gtd", "tgd"), layout
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
+    if dispatch.want_kernel(use_pallas, interpret):
         b, s, g, qpk, d = q.shape
         T = k.shape[2] if layout == "gtd" else k.shape[1]
         bt = decode_attn_block(s, qpk, d, T, requested=block_t,
                                interpret=interpret)
         if bt is not None:
+            dispatch.note_kernel("decode_attention")
             return _decode_pallas(q, k, v, length, layout, bt, interpret)
+        dispatch.report_fallback("decode_attention", "decode_attn_block", s=s,
+                        qpk=qpk, d=d, T=T, layout=layout)
     return _xla_decode(q, k, v, length, layout)

@@ -35,6 +35,11 @@ from megatron_llm_tpu.analysis.contracts import (
     CompileContract,
     register_contract,
 )
+from megatron_llm_tpu.ops.dispatch import (
+    note_kernel,
+    report_fallback,
+    want_kernel,
+)
 
 register_contract(CompileContract(
     name="ops.flash_attention",
@@ -68,14 +73,6 @@ MAX_ROWS = 2048
 # backward holds two such blocks) — keeps wide-GQA shapes inside VMEM now
 # that the default block_k is 1024
 MAX_CELLS = 1 << 20
-
-
-def _compiler_params(**kw):
-    """pltpu.CompilerParams under current JAX; TPUCompilerParams on the
-    0.4.x line — both accept dimension_semantics."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
 
 
 def _xla_reference(q, k, v, causal: bool):
@@ -117,16 +114,10 @@ def _out_struct(shape, dtype, *likes):
     manual-axes sets: inside a shard_map manual region (ring attention's
     per-hop call, the pipelined decode's stage region) the kernel
     outputs must declare how they vary across the manual axes or tracing
-    rejects them (check_vma). On JAX builds without jax.typeof there are
-    no manual regions to satisfy."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    vma = set()
-    for x in likes:
-        vma |= set(getattr(typeof(x), "vma", None) or ())
+    rejects them (check_vma)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in likes))
     if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
@@ -372,7 +363,7 @@ def _flash_fwd_pallas(q, k, v, causal, block_q, block_k, interpret=False):
         ],
         # (bg, q) grid steps are independent; only the k dim carries the
         # online-softmax accumulator state
-        compiler_params=None if interpret else _compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -574,7 +565,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, qpk * d), lambda h, i, j: (h, i, 0)),
         out_shape=_out_struct((b * g, s, qpk * d), q.dtype, qf),
         scratch_shapes=[pltpu.VMEM((block_q * qpk, d), jnp.float32)],
-        compiler_params=None if interpret else _compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -608,7 +599,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=None if interpret else _compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -711,14 +702,29 @@ def flash_attention_with_lse(
     (b, s, g, qpk) fp32, differentiable through both outputs — the
     building block for merging attention across blocks that live on
     different devices (ring attention's per-hop step)."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
+    if want_kernel(use_pallas, interpret):
         blocks = _pick_blocks(q.shape[1], k.shape[1], q.shape[-1],
                               q.shape[3], block_q, block_k)
         if blocks is not None:
+            note_kernel("flash_attention")
             return _flash_lse((causal, *blocks, interpret), q, k, v)
+        _report_no_blocks(q, k)
     return _xla_reference_with_lse(q, k, v, causal)
+
+
+def flash_reaches_kernel(q_shape, t: int) -> bool:
+    """Whether a default `flash_attention[_with_lse]` call of q
+    (b, s, g, qpk, d) against t keys runs the Pallas kernel — what a call
+    site under a mesh must know, since only the Mosaic call needs a fully
+    manual region (parallel/mesh.shard_kernel)."""
+    return want_kernel(None) and _pick_blocks(
+        q_shape[1], t, q_shape[-1], q_shape[3], DEFAULT_BLOCK_Q,
+        DEFAULT_BLOCK_K) is not None
+
+
+def _report_no_blocks(q, k):
+    report_fallback("flash_attention", "_pick_blocks", s=q.shape[1],
+                    t=k.shape[1], qpk=q.shape[3], d=q.shape[-1])
 
 
 def _pick_blocks(s, t, d, qpk, block_q, block_k):
@@ -759,13 +765,13 @@ def flash_attention(
     named-savepoint remat policies (models/remat.py) can keep it."""
     from jax.ad_checkpoint import checkpoint_name
 
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
+    if want_kernel(use_pallas, interpret):
         blocks = _pick_blocks(q.shape[1], k.shape[1], q.shape[-1],
                               q.shape[3], block_q, block_k)
         if blocks is not None:
+            note_kernel("flash_attention")
             return checkpoint_name(
                 _flash((causal, *blocks, interpret), q, k, v), "attn_ctx"
             )
+        _report_no_blocks(q, k)
     return checkpoint_name(_xla_reference(q, k, v, causal), "attn_ctx")

@@ -70,11 +70,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from megatron_llm_tpu.ops import dispatch
 from megatron_llm_tpu.ops.flash_attention import (
     LOG2E,
     NEG_INF,
     _causal_invalid,
-    _compiler_params,
     _out_struct,
     _softmax_accum,
     _softmax_finalize,
@@ -114,7 +114,7 @@ def ragged_paged_block(s: int, qpk: int, d: int, page_size: int,
     step on the same pool, so a near-tie argmax can never flip when
     admission starts mid-stream.
     """
-    if not (interpret or jax.default_backend() == "tpu"):
+    if not (interpret or dispatch.on_tpu()):
         return None
     if s < 1 or d % 128 != 0:
         return None
@@ -141,9 +141,10 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
     is chunk token i*block_q + r // qpk (head fastest) at global
     position starts[c] + token; rows at tokens >= lens[c] are pad.
     `quantized` selects the int8-KV epilogue (ISSUE 9): k/v arrive int8
-    with per-(token, group) fp32 scale columns as two extra
-    (page_size, 1) operands, dequantized in-register before the
-    unchanged fp32 template math.
+    with the page's per-(token, group) fp32 scales as two extra
+    (page_size, g) operands — this group's column is picked out
+    in-register — and are dequantized before the unchanged fp32
+    template math.
 
     Lower-bound masks (ISSUE 19) are extra parameterizations of the
     SAME body, not new kernels — both default off, and off means the
@@ -167,12 +168,22 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     c = pl.program_id(0)
+    gi = pl.program_id(1)
     i = pl.program_id(2)
     j = pl.program_id(3)
     rows = block_q * qpk
     start = starts_ref[c]
     clen = lens_ref[c]
     doc0 = doc_ref[c] if has_doc else None
+
+    def _scale_col(s_ref):
+        # Mosaic takes a scale block only at the pool's full (page_size,
+        # g) trailing dims; a one-hot lane reduce picks this grid step's
+        # group column out of it as the (page_size, 1) the dequant needs
+        sc = s_ref[:]
+        lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        return jnp.sum(jnp.where(lane == gi, sc, 0.0), axis=1,
+                       keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -182,9 +193,9 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
         qb = q_ref[:].reshape(rows, d)
         kb = k_ref[:].reshape(page_size, d).astype(jnp.float32)
         if quantized:
-            # dequantize in-register against the page's (page_size, 1)
-            # scale column — HBM saw only the int8 bytes
-            kb = kb * ks_ref[:].reshape(page_size, 1)
+            # dequantize in-register against the page's scale column —
+            # HBM saw only the int8 bytes
+            kb = kb * _scale_col(ks_ref)
         sc = jax.lax.dot_general(
             qb.astype(jnp.float32), kb,
             (((1,), (1,)), ((), ())),
@@ -210,7 +221,7 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
             )
         if quantized:
             vb = v_ref[:].reshape(page_size, d).astype(jnp.float32) \
-                * vs_ref[:].reshape(page_size, 1)
+                * _scale_col(vs_ref)
             _softmax_accum(sc, vb, m_scr, l_scr, acc_scr)
         else:
             _softmax_accum(sc, v_ref[:].reshape(page_size, d), m_scr,
@@ -290,6 +301,12 @@ def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
     has_doc = doc_starts is not None
 
     qf = q.transpose(0, 2, 1, 3, 4).reshape(nc, g, C * qpk, d)
+    # Mosaic wants a block's last two dims tile-aligned or whole, and a
+    # (page_size, d) block of the (P, page_size, g, d) pool would
+    # squeeze the second-minor group axis. The row-major (P, page_size,
+    # g*d) view is free and puts group gi at lane block gi instead.
+    k_pages = k_pages.reshape(*k_pages.shape[:2], g * d)
+    v_pages = v_pages.reshape(*v_pages.shape[:2], g * d)
     # rows below one fp32 sublane tile: launch q/o in fp32 (the small-
     # memref Mosaic workaround shared with the dense decode kernel)
     out_dtype = q.dtype if rows % 8 == 0 else jnp.float32
@@ -334,18 +351,18 @@ def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
         lambda c, gi, i, j, *s_refs: (c, gi, i, 0),
     )
     kv_spec = pl.BlockSpec(
-        (None, page_size, None, d),
+        (None, page_size, d),
         lambda c, gi, i, j, *s_refs: (
-            page_index(c, i, j, *s_refs), 0, gi, 0
+            page_index(c, i, j, *s_refs), 0, gi
         ),
     )
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qf, k_pages, v_pages]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (None, page_size, 1),
+            (None, page_size, g),
             lambda c, gi, i, j, *s_refs: (
-                page_index(c, i, j, *s_refs), 0, gi
+                page_index(c, i, j, *s_refs), 0, 0
             ),
         )
         in_specs += [scale_spec, scale_spec]
@@ -373,7 +390,7 @@ def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
                               v_pages),
         # (chunk, group, q_block) steps are independent; only the page
         # dim carries the online-softmax scratch state
-        compiler_params=None if interpret else _compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -574,15 +591,22 @@ def ragged_paged_attention(
         k_pages, v_pages = scatter_chunk_kv(
             k_new, v_new, k_pages, v_pages, page_table, starts,
             chunk_lens)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
+    if dispatch.want_kernel(use_pallas, interpret):
         bq = ragged_paged_block(C, qpk, d, k_pages.shape[1],
                                 page_table.shape[1],
                                 min_cache=min_cache,
                                 kv_dtype=k_pages.dtype,
                                 interpret=interpret)
-        if bq is not None:
+        if bq is None:
+            # a reach below min_cache is the caller's routing, not a
+            # refusal
+            if page_table.shape[1] * k_pages.shape[1] >= min_cache:
+                dispatch.report_fallback(
+                    "ragged_paged_attention", "ragged_paged_block", C=C,
+                    qpk=qpk, d=d, page_size=k_pages.shape[1],
+                    slot_pages=page_table.shape[1], kv=k_pages.dtype.name)
+        else:
+            dispatch.note_kernel("ragged_paged_attention")
             out = _paged_pallas(q, k_pages, v_pages, page_table,
                                 starts, chunk_lens, bq, interpret,
                                 k_scales=k_scales, v_scales=v_scales,

@@ -33,6 +33,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from megatron_llm_tpu.ops.dispatch import (
+    note_kernel,
+    report_fallback,
+    want_kernel,
+)
+
 DEFAULT_BLOCK_ROWS = 256
 # The backward holds ~4 fp32 row blocks (x, g, u, x_hat) + 2 bf16 blocks
 # live at once; block*h is capped so the worst case stays well under the
@@ -156,16 +162,16 @@ def fused_rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6,
     from megatron_llm_tpu.models.norms import rms_norm
 
     h = x.shape[-1]
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas and h % 128 == 0:
+    if want_kernel(use_pallas, interpret):
         lead = x.shape[:-1]
         n = 1
         for d in lead:
             n *= d
-        block_rows = _choose_rows(n, h)
+        block_rows = _choose_rows(n, h) if h % 128 == 0 else None
         if block_rows is not None:
+            note_kernel("fused_rms_norm")
             out = _fused((x.reshape(n, h)), scale, eps, block_rows,
                          interpret)
             return out.reshape(*lead, h)
+        report_fallback("fused_rms_norm", "_choose_rows", rows=n, h=h)
     return rms_norm(x, scale, eps)

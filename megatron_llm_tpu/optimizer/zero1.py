@@ -60,9 +60,13 @@ the psum'd denominator AFTER the local numerator reduction), and
 psum/psum_scatter accumulate partials in the same rank order, so no
 term is rounded differently.
 
-Mixed meshes (tp/pp/cp > 1) keep the GSPMD-spec path: partial-manual
-shard_map (auto axes) hard-crashes this XLA build's partitioner, and
-pp's train step is its own stage-manual program. There the m/v
+Mixed meshes (tp/pp/cp > 1) keep the GSPMD-spec path: the explicit
+body is written for a FULLY manual region (it skips the activation
+sharding constraints tp/sp steer by, parallel/mesh.manual_region), and
+pp's train step is its own stage-manual program. Partial-manual
+shard_map itself lowers on jax 0.9.0 (the pipeline runs stage-manual
+with data/model auto), so a data-manual, model-auto variant is open
+work rather than a partitioner limit. There the m/v
 sharding still buys the 1/dp state memory and train_step steers the
 update shard-wise + gathers params explicitly; on TPU the SPMD
 partitioner's reduce-scatter creation applies to the steered
@@ -445,10 +449,9 @@ def reduce_scatter_grads(grads, plan: Zero1Plan, quantized: bool = False,
 def explicit_zero1_supported(model, pcfg, ctx: Optional[ParallelContext],
                              batch_builder=None) -> bool:
     """Whether the decomposed shard_map path can serve this run: pure-dp
-    mesh (every non-data axis size 1 — partial-manual shard_map is not
-    available on this XLA build), dp > 1, and a model exposing
-    loss_terms (the GPT family). Everything else keeps the GSPMD-spec
-    path."""
+    mesh (every non-data axis size 1 — the body is fully manual, see
+    the module docstring), dp > 1, and a model exposing loss_terms (the
+    GPT family). Everything else keeps the GSPMD-spec path."""
     return (
         ctx is not None
         and pcfg.use_distributed_optimizer
@@ -591,7 +594,6 @@ def make_zero1_grad_fn(model, ctx: ParallelContext, plan,
     mesh. `plan` selects the schedule: a Zero1Plan runs the eager
     post-backward sweep (the bitwise oracle), an OverlapPlan the
     backward-interleaved issue points (--overlap_grad_reduce)."""
-    from megatron_llm_tpu.parallel.mesh import shard_map
 
     mesh = ctx.mesh
     dp = plan.dp
@@ -708,11 +710,11 @@ def make_zero1_grad_fn(model, ctx: ParallelContext, plan,
             ls = rest.pop(0) if loss_scale is not None else None
             return body(params, batch, r, ls)
 
-        return shard_map(
+        return jax.shard_map(
             wrapped, mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=(g_specs, P()),
-            check_rep=False,
+            check_vma=False,
         )(*args)
 
     return grad_fn
@@ -738,7 +740,6 @@ def make_explicit_param_gather(ctx: ParallelContext, plan):
     either plan flavor (the bucket units follow the active grad
     layout) and composes with --quantized_grad_reduce (the wire format
     of the REDUCE leg is irrelevant here)."""
-    from megatron_llm_tpu.parallel.mesh import shard_map
 
     mesh = ctx.mesh
     dp = plan.dp
@@ -824,9 +825,9 @@ def make_explicit_param_gather(ctx: ParallelContext, plan):
             else zero1_out_specs(plan, jax.tree.structure(new_params)))
         out_specs = jax.tree.map(lambda _: P(), new_params)
         body = _overlap_body if overlap else _eager_body
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(in_specs,), out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )(new_params)
 
     return gather

@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 from megatron_llm_tpu.parallel.mesh import (
     MODEL_AXIS,
     get_context,
-    shard_map as _shard_map,
 )
 
 
@@ -96,7 +95,7 @@ def vocab_parallel_cross_entropy(
     if not explicit or ctx is None or ctx.tp == 1:
         return cross_entropy(logits, targets, label_smoothing)
     vocab_per_shard = logits.shape[-1] // ctx.tp
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_ce_shard, vocab_per_shard=vocab_per_shard,
                 label_smoothing=label_smoothing),
         mesh=ctx.mesh,

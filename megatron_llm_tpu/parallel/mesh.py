@@ -28,82 +28,6 @@ CONTEXT_AXIS = "context"
 MODEL_AXIS = "model"
 AXIS_NAMES = (DATA_AXIS, STAGE_AXIS, CONTEXT_AXIS, MODEL_AXIS)
 
-# jax.shard_map landed as a top-level name only on newer JAX lines; the
-# baked-in 0.4.37 still spells it jax.experimental.shard_map.shard_map
-# and declares manual axes as `auto` (the complement of the new API's
-# `axis_names`). Every call site imports THIS adapter, so the whole
-# pp/cp/zero1 shard_map surface works on both lines — this was the
-# KNOWN_FAILURES.md "jax.shard_map AttributeError" drift that
-# dead-ended the pipeline/context-parallel/pp-inference slow suites and
-# the pp>1 MULTICHIP dryrun layouts in this environment.
-if hasattr(jax, "shard_map"):
-    import inspect as _inspect
-
-    _new_params = _inspect.signature(jax.shard_map).parameters
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_rep=True, auto=frozenset()):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        # the rep/vma checker kwarg was renamed check_rep -> check_vma
-        # on the new surface; pass whichever this jax spells
-        if "check_vma" in _new_params:
-            kw["check_vma"] = check_rep
-        elif "check_rep" in _new_params:
-            kw["check_rep"] = check_rep
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_rep=True, auto=frozenset()):
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        # size-1 auto axes are vacuous — treat them as manual. This is
-        # load-bearing: ANY non-empty auto set routes this XLA build
-        # into its partial-manual partitioner, which is broken
-        # (PartitionId UNIMPLEMENTED, or a hard IsManualSubgroup CHECK
-        # that ABORTS the process) — so pure-pp/cp/dp meshes must reach
-        # it with auto = {} to work at all. Genuinely mixed meshes are
-        # rejected HERE with a catchable error: the CHECK-abort variant
-        # would otherwise kill the whole test/serve process.
-        auto = frozenset(a for a in auto if mesh.shape[a] > 1)
-        if auto:
-            raise NotImplementedError(
-                f"partial-manual shard_map (manual={sorted(set(mesh.axis_names) - auto)}, "
-                f"auto={sorted(auto)}) is broken in this jax/XLA build "
-                f"(0.4.37 CPU partitioner: PartitionId UNIMPLEMENTED / "
-                f"IsManualSubgroup CHECK abort). Use a mesh where the "
-                f"non-manual axes are size 1, or a newer jax with "
-                f"jax.shard_map (KNOWN_FAILURES.md)")
-        # the experimental rep-checker predates the varying-manual type
-        # system the new call sites are written for (lax.pcast markers,
-        # check_vma) — its inference rejects bodies the new API accepts.
-        # Replication checking is a diagnostic, not a semantic: off.
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False,
-                              auto=auto)
-
-
-def pcast(x, axes, to="varying"):
-    """jax.lax.pcast where it exists (the new varying-manual type
-    system); a no-op marker on older lines, where the experimental
-    shard_map (check_rep=False above) needs no varying annotations."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to=to)
-    return x
-
-
-def axis_size(name) -> int:
-    """jax.lax.axis_size where it exists; on older lines the canonical
-    psum-of-1 idiom, which trace-time folds to a concrete int inside
-    shard_map bodies."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return int(jax.lax.psum(1, name))
-
 _CONTEXT: Optional["ParallelContext"] = None
 
 # Thread-local context OVERRIDE (ISSUE 14): `use_mesh` scopes are
@@ -256,6 +180,93 @@ def use_mesh(ctx: ParallelContext):
 
 
 # ---------------------------------------------------------------------------
+# Pallas kernels under a mesh
+# ---------------------------------------------------------------------------
+
+
+def _spec_axes(entry) -> tuple:
+    if entry is None:
+        return ()
+    return entry if isinstance(entry, tuple) else (entry,)
+
+
+def shard_kernel(fn, in_specs, out_specs, check_vma=True):
+    """`fn` (a Pallas kernel call) run once per shard of the installed
+    mesh. Mosaic kernels cannot be partitioned by GSPMD ("wrap the call
+    in a shard_map"), and their lowering insists on EVERY axis of a
+    multi-device mesh being manual, size-1 axes included. So under such
+    a mesh the call sits in a shard_map over all the axes an enclosing
+    shard_map has not already made manual: operands split as `in_specs`
+    say (batch over `data`, groups/heads over `model`, ...) and are
+    replicated over the axes the specs do not name. An axis whose size
+    does not divide every dim assigned to it is dropped from the specs:
+    each shard along it computes the whole dim — correct, axis-size-fold
+    redundant (MQA's one group under tp), and reported next to the kernel
+    fallbacks (ops/dispatch.py). Spec entries naming an already-manual
+    axis are vacuous. With no mesh, one device, or nothing left to make
+    manual, `fn` is called as is.
+
+    `check_vma=False` is for kernels with scalar-prefetch operands (the
+    paged and dense decode kernels): the Pallas interpreter evaluates
+    their index maps against operands whose varying-axes types it never
+    saw at trace time, which the checker rejects. Kernels that are
+    differentiated (flash) need the default: a custom_vjp's cotangents
+    must carry the primals' varying-axes types."""
+    def call(*args):
+        ctx = _effective_context()
+        if ctx is None or ctx.mesh.size == 1:
+            return fn(*args)
+        mesh = ctx.mesh
+        outer = set(jax.sharding.get_abstract_mesh().manual_axes)
+        axes = set(mesh.axis_names) - outer
+        if not axes:
+            return fn(*args)
+        keep = set(axes)
+        for spec, x in zip(in_specs, args):
+            for entry, n in zip(spec, x.shape):
+                size = 1
+                for a in _spec_axes(entry):
+                    size *= mesh.shape[a] if a in axes else 1
+                if n % size:
+                    keep -= set(_spec_axes(entry))
+        idle = sorted(a for a in axes - keep if mesh.shape[a] > 1)
+        if idle:
+            from megatron_llm_tpu.ops.dispatch import report_fallback
+
+            report_fallback(
+                getattr(fn, "func", fn).__name__, "shard_kernel",
+                repeated_over=",".join(idle),
+                shapes=" ".join("x".join(map(str, x.shape)) for x in args))
+
+        def strip(spec):
+            parts = []
+            for entry in spec:
+                names = tuple(a for a in _spec_axes(entry) if a in keep)
+                parts.append(names if len(names) > 1
+                             else names[0] if names else None)
+            return P(*parts)
+
+        # nested in another shard_map the mesh comes from its context
+        # (whose axis types already say which axes are manual), and the
+        # call is remat'd: jax 0.9.0 mis-names a nested region's
+        # residuals under AD, so its only residuals must be its inputs —
+        # one extra kernel forward in the backward (KNOWN_FAILURES.md
+        # "Nested shard_map + AD"; owner: whoever next raises the jax
+        # pin — delete `jax.checkpoint` here when
+        # tests/test_tpu_lowering.py's pp2tp2 layout compiles without it)
+        return jax.shard_map(
+            jax.checkpoint(fn) if outer else fn,
+            mesh=None if outer else mesh,
+            in_specs=tuple(strip(s) for s in in_specs),
+            out_specs=jax.tree.map(strip, out_specs,
+                                   is_leaf=lambda x: isinstance(x, P)),
+            axis_names=axes, check_vma=check_vma,
+        )(*args)
+
+    return call
+
+
+# ---------------------------------------------------------------------------
 # Activation sharding constraints
 # ---------------------------------------------------------------------------
 # Model code calls `shard_activation(x, kind)` at the few load-bearing points;
@@ -297,9 +308,12 @@ _BARRIER_DEPTH = 0
 @contextlib.contextmanager
 def manual_region(constraint_barriers: bool = False):
     """Mark a shard_map(manual-axes) body: activation constraints are
-    skipped inside (this JAX rejects with_sharding_constraint mixing auto
-    axes into a manual region; GSPMD propagation from the param shardings
-    covers the body instead).
+    skipped inside and GSPMD propagation from the param shardings covers
+    the auto axes of the body instead. (The old build rejected a
+    constraint inside a manual region outright; jax 0.9.0 accepts one
+    over the auto axes — tests/test_pipeline.py passes with them applied
+    — but which program is better on the chip is unmeasured, so the
+    behaviour stays. KNOWN_FAILURES.md, "Open".)
 
     `constraint_barriers=True` (the explicit ZeRO-1 path,
     optimizer/zero1.py): each skipped constraint site emits a

@@ -64,8 +64,6 @@ from megatron_llm_tpu.parallel.mesh import (
     CONTEXT_AXIS,
     STAGE_AXIS,
     ParallelContext,
-    shard_map as _shard_map,
-    pcast as _pcast,
 )
 
 
@@ -108,14 +106,15 @@ def _mark_varying(cp, aux, rope, batch_ops, layers_local):
     varying) — only the stage axis still needs marking on those; stage-
     sharded layer weights are the mirror case (context-invariant)."""
     manual_axes = (STAGE_AXIS, CONTEXT_AXIS) if cp > 1 else (STAGE_AXIS,)
-    pv = lambda x: _pcast(x, manual_axes, to="varying")  # noqa: E731
-    pv_s = lambda x: _pcast(x, (STAGE_AXIS,), to="varying")  # noqa: E731
+    pcast = jax.lax.pcast
+    pv = lambda x: pcast(x, manual_axes, to="varying")  # noqa: E731
+    pv_s = lambda x: pcast(x, (STAGE_AXIS,), to="varying")  # noqa: E731
     aux = jax.tree.map(pv, aux)
     rope = pv(rope)
     batch_ops = tuple(map(pv_s if cp > 1 else pv, batch_ops))
     if cp > 1:
         layers_local = jax.tree.map(
-            lambda x: _pcast(x, (CONTEXT_AXIS,), to="varying"),
+            lambda x: pcast(x, (CONTEXT_AXIS,), to="varying"),
             layers_local,
         )
     return manual_axes, aux, rope, batch_ops, layers_local
@@ -188,8 +187,9 @@ def make_pipelined_loss_fn(model, pcfg, ctx: ParallelContext):
     # manual axis of the SAME shard_map (Shardy rejects a nested manual
     # region whose operands mix free `stage` with manual `context`), the
     # seq dim of every batch operand is context-sharded, and attention runs
-    # the ring directly over the manual axis (models/attention.py routes
-    # there via in_manual_region()).
+    # the ring over the manual axis (models/attention._ring_dispatch: as
+    # is for XLA hops, with the remaining axes made manual round it when
+    # the hop is the Mosaic flash kernel).
     cp = ctx.cp
     if cp > 1:
         assert cfg.attention_dropout == 0.0, (
@@ -313,7 +313,7 @@ def make_pipelined_loss_fn(model, pcfg, ctx: ParallelContext):
                     (t >= hop * (num_stages - 1))
                 lbl_t = jax.lax.dynamic_index_in_dim(lbls, m_out, 0, False)
                 lm_t = jax.lax.dynamic_index_in_dim(lmask, m_out, 0, False)
-                zero = _pcast(
+                zero = jax.lax.pcast(
                     jnp.float32(0.0), manual_axes, to="varying"
                 )
                 sum_t, den_t = jax.lax.cond(
@@ -378,20 +378,20 @@ def make_pipelined_loss_fn(model, pcfg, ctx: ParallelContext):
 
             # carries become stage-varying inside the loop; mark the zero
             # initials as varying so the scan carry types are stable
-            state = _pcast(
+            state = jax.lax.pcast(
                 jnp.zeros((b, s // cp, cfg.hidden_size), boundary_dtype),
                 manual_axes, to="varying",
             )
-            sums0 = _pcast(
+            sums0 = jax.lax.pcast(
                 jnp.zeros((num_micro,), jnp.float32), (STAGE_AXIS,),
                 to="varying",
             )
-            denoms0 = _pcast(
+            denoms0 = jax.lax.pcast(
                 jnp.zeros((num_micro,), jnp.float32), (STAGE_AXIS,),
                 to="varying",
             )
             if async_dispatch:
-                fly0 = _pcast(
+                fly0 = jax.lax.pcast(
                     jnp.zeros((b, s // cp, cfg.hidden_size),
                               boundary_dtype),
                     manual_axes, to="varying",
@@ -412,7 +412,7 @@ def make_pipelined_loss_fn(model, pcfg, ctx: ParallelContext):
 
         # (num_micro, b, s) batch operands: seq context-sharded when cp>1
         bspec = P(None, None, CONTEXT_AXIS) if cp > 1 else P()
-        stack_mapped = _shard_map(
+        stack_mapped = jax.shard_map(
             stack_shard,
             mesh=mesh,
             in_specs=(P(STAGE_AXIS), P(), bspec, bspec, bspec, bspec, P()),
@@ -538,7 +538,7 @@ def make_pipelined_score_fn(model, pcfg, ctx: ParallelContext):
                 m_out = jnp.clip(t - (num_stages - 1), 0, num_micro - 1)
                 valid = (stage == num_stages - 1) & (t >= num_stages - 1)
                 tgt_t = jax.lax.dynamic_index_in_dim(tgts, m_out, 0, False)
-                zero = _pcast(
+                zero = jax.lax.pcast(
                     jnp.zeros((b, s_loc), jnp.float32), manual_axes,
                     to="varying",
                 )
@@ -563,11 +563,11 @@ def make_pipelined_score_fn(model, pcfg, ctx: ParallelContext):
                 )
                 return (state, banked), None
 
-            state = _pcast(
+            state = jax.lax.pcast(
                 jnp.zeros((b, s_loc, cfg.hidden_size), boundary_dtype),
                 manual_axes, to="varying",
             )
-            banked0 = _pcast(
+            banked0 = jax.lax.pcast(
                 jnp.zeros((num_micro, b, s_loc), jnp.float32), manual_axes,
                 to="varying",
             )
@@ -579,7 +579,7 @@ def make_pipelined_score_fn(model, pcfg, ctx: ParallelContext):
         bspec = P(None, None, CONTEXT_AXIS) if cp > 1 else P()
         out_bspec = P(STAGE_AXIS, None, None, CONTEXT_AXIS) if cp > 1 \
             else P(STAGE_AXIS)
-        stack_mapped = _shard_map(
+        stack_mapped = jax.shard_map(
             stack_shard,
             mesh=mesh,
             in_specs=(P(STAGE_AXIS), P(), bspec, bspec, P()),
@@ -694,7 +694,7 @@ def make_pipelined_decode_fn(model, pcfg, ctx: ParallelContext, *,
             )
             rope_t = rope if has_rope else None
             base_rng = jax.random.wrap_key_data(rng_u)
-            pv = lambda x: _pcast(  # noqa: E731
+            pv = lambda x: jax.lax.pcast(  # noqa: E731
                 x, (STAGE_AXIS,), to="varying"
             )
 
@@ -1030,7 +1030,7 @@ def make_pipelined_decode_fn(model, pcfg, ctx: ParallelContext, *,
             toks_b, lps, glens = carry[5], carry[6], carry[8]
             return toks_b[None], lps[None], glens[None]
 
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             shard,
             mesh=mesh,
             in_specs=(P(STAGE_AXIS), P(), P(), P(), P()),

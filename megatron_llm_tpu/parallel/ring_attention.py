@@ -33,15 +33,8 @@ from __future__ import annotations
 import functools
 
 import jax
-
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-from megatron_llm_tpu.parallel.mesh import (
-    axis_size as _axis_size,
-    pcast as _pcast,
-    shard_map as _shard_map,
-)
 
 NEG_INF = -1e30
 
@@ -94,7 +87,7 @@ def ring_self_attention(q, k, v, axis_name: str, causal: bool = True,
         flash_attention_with_lse,
     )
 
-    cp = _axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s, g, qpk, d = q.shape
     if doc_start is not None:
@@ -150,7 +143,7 @@ def ring_self_attention(q, k, v, axis_name: str, causal: bool = True,
 
     step = jax.checkpoint(step, prevent_cse=False)
     # mark the zero initials device-varying so scan carry types are stable
-    pv = lambda x: _pcast(x, (axis_name,), to="varying")  # noqa: E731
+    pv = lambda x: jax.lax.pcast(x, (axis_name,), to="varying")  # noqa: E731
     m0 = pv(jnp.full((b, s, g, qpk), NEG_INF, jnp.float32))
     l0 = pv(jnp.zeros((b, s, g, qpk), jnp.float32))
     o0 = pv(jnp.zeros((b, s, g, qpk, d), jnp.float32))
@@ -179,7 +172,7 @@ def make_ring_attention(mesh, cp_axis: str, causal: bool = True,
     kspec = P(batch_axis, cp_axis, None, None)
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(qspec, kspec, kspec),
         out_specs=qspec,

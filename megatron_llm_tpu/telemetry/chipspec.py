@@ -17,7 +17,9 @@ device_kind strings drift across libtpu releases ("TPU v5 lite" vs
 "TPU v5e"), matching is substring-based and an explicit `override`
 (CLI `--chip_spec`, engine `chip_spec=`, env `MEGATRON_TPU_CHIPSPEC`)
 always wins — on the CPU test harness the override is the only way to
-get deterministic MFU/roofline numbers at all.
+get deterministic MFU/roofline numbers at all. A TPU whose kind is not
+in the table is an error, never a default: a utilization against a
+guessed peak is worse than none.
 
 Peak numbers are the published per-chip figures:
 - v5e: 197 TFLOP/s bf16, 394 TOP/s int8, 819 GB/s HBM, 16 GiB
@@ -44,11 +46,10 @@ __all__ = [
 class ChipSpec:
     """Per-chip peaks for one TPU generation.
 
-    `source` records how this spec was chosen ("detected", "override",
-    or "assumed") so every gauge/bench row that cites it can state
-    whether the denominator was measured-at-runtime or asserted by the
-    operator — an MFU number against an assumed chip is a different
-    claim than one against the detected chip.
+    `source` records how this spec was chosen ("detected" or
+    "override") so every gauge/bench row that cites it can state
+    whether the denominator was read from the device or asserted by the
+    operator.
     """
 
     name: str
@@ -92,32 +93,28 @@ CHIP_SPECS: Mapping[str, ChipSpec] = {
     ),
 }
 
-# device_kind substring -> table key, first match wins (order matters:
-# "v5 lite"/"v5e" must be tried before the bare "v5" of v5p kinds)
+# device_kind substring -> table key, first match wins
 _KIND_PATTERNS: Tuple[Tuple[str, str], ...] = (
     ("v5 lite", "v5e"),
     ("v5litepod", "v5e"),
     ("v5e", "v5e"),
     ("v5p", "v5p"),
-    ("v5", "v5p"),
     ("v4", "v4"),
 )
 
 _ENV_OVERRIDE = "MEGATRON_TPU_CHIPSPEC"
 
 
-def detect_chip(devices=None, override: Optional[str] = None,
-                default: Optional[str] = None) -> Optional[ChipSpec]:
+def detect_chip(devices=None,
+                override: Optional[str] = None) -> Optional[ChipSpec]:
     """Resolve the chip spec: explicit `override` (or the
     MEGATRON_TPU_CHIPSPEC env var) wins, then detection from the device
-    kind, then `default` (source marked "assumed"), then None — a None
-    return means "no credible denominator": callers must drop their
-    MFU/roofline gauges rather than report against a guessed peak.
+    kind. Off the TPU (CPU harness, no jax) the answer is None — "no
+    credible denominator": callers drop their MFU/roofline gauges. A TPU
+    kind that is not in the table raises.
 
     `devices`: the device subset the caller actually computes on (an
-    engine pinned to a replica's devices); None = jax.devices(). jax is
-    imported lazily and a CPU/import failure falls through to
-    `default`."""
+    engine pinned to a replica's devices); None = jax.devices()."""
     override = override or os.environ.get(_ENV_OVERRIDE) or None
     if override:
         key = str(override).lower()
@@ -127,23 +124,22 @@ def detect_chip(devices=None, override: Optional[str] = None,
                 f"(known: {sorted(CHIP_SPECS)}) — extend the table in "
                 f"telemetry/chipspec.py for a new generation")
         return replace(CHIP_SPECS[key], source="override")
-    kind = ""
-    try:
-        if devices is None:
+    if devices is None:
+        try:
             import jax
-
-            devices = jax.devices()
-        if devices:
-            kind = str(getattr(devices[0], "device_kind", "")).lower()
-    except Exception:  # noqa: BLE001 — no jax / no devices: fall through
-        kind = ""
-    if "tpu" in kind or kind.startswith("v"):
-        for pat, key in _KIND_PATTERNS:
-            if pat in kind:
-                return replace(CHIP_SPECS[key], source="detected")
-    if default is not None:
-        return replace(CHIP_SPECS[str(default).lower()], source="assumed")
-    return None
+        except ImportError:
+            return None
+        devices = jax.devices()
+    if not devices or devices[0].platform != "tpu":
+        return None
+    kind = str(devices[0].device_kind).lower()
+    for pat, key in _KIND_PATTERNS:
+        if pat in kind:
+            return replace(CHIP_SPECS[key], source="detected")
+    raise ValueError(
+        f"TPU device_kind {devices[0].device_kind!r} is not in the chip "
+        f"spec table (known: {sorted(CHIP_SPECS)}); add its published "
+        f"peaks to telemetry/chipspec.py or pass an explicit override")
 
 
 def train_flops_per_token(n_params: int, num_layers: int,

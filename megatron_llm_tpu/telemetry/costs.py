@@ -30,11 +30,12 @@ listed in graft-check GR006 HOT_PATHS.
 
 Capture cost: `fn.lower(*args)` is an abstract trace (no XLA compile)
 and yields cost_analysis; `capture_memory=True` additionally compiles
-the lowering for memory_analysis — on this JAX line that compile does
-NOT populate the jit call cache, so it is one EXTRA full compile per
-minted executable. That is why the registry is opt-in
-(`--device_cost_registry`, engine `cost_registry=True`), exactly like
-the trainer's --log_memory_to_tensorboard relower.
+the lowering for memory_analysis. On jax 0.9.0 that compile and the jit
+call share one executable cache (checked: a jit call after
+`.lower().compile()` does not compile again), so capture costs a
+retrace per minted executable, not a second XLA compile. The registry
+stays opt-in (`--device_cost_registry`, engine `cost_registry=True`),
+like the trainer's --log_memory_to_tensorboard relower.
 """
 
 from __future__ import annotations
@@ -97,10 +98,7 @@ class CostRecord:
 
 
 def _analysis_dict(analysis) -> dict:
-    """cost_analysis() returns a dict (Lowered) or a 1-list of dicts
-    (Compiled) depending on the stage/backend — normalize."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
+    """cost_analysis() is a dict, or None where the backend has none."""
     return dict(analysis or {})
 
 

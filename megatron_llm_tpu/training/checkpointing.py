@@ -470,10 +470,11 @@ def _load_candidates(load_dir: str):
 
 def _abstract_leaf(x):
     """Template leaf -> restore target. Sharding-less abstract leaves
-    (jax.eval_shape output) get an explicit default-device sharding —
-    this orbax line's to_shape_dtype_struct chokes on sharding=None, and
-    letting orbax read the sharding file instead would resurrect the
-    SAVED topology, which is exactly wrong for cross-mesh restore."""
+    (jax.eval_shape output) get an explicit default-device sharding:
+    with sharding=None orbax reads the sharding file instead and
+    resurrects the SAVED topology, which is exactly wrong for cross-mesh
+    restore. Callers that care where the bytes land (the trainer, the
+    server tool) pass templates that carry their own shardings."""
     if (isinstance(x, jax.ShapeDtypeStruct)
             and getattr(x, "sharding", None) is None):
         return jax.ShapeDtypeStruct(

@@ -40,11 +40,12 @@ from megatron_llm_tpu.optimizer.optimizer import OptimizerState, optimizer_step
     collectives={
         "single": frozenset(),
         # pinned on the audit reference config (analysis/audit.py):
-        # the TP activation/logit reductions lower to all-reduce, the
-        # GSPMD param/embedding gathers to all-gather; dp grad
-        # reduction folds into the same all-reduce family.
-        "tp2": frozenset({"all-reduce", "all-gather"}),
-        "dp2tp2": frozenset({"all-reduce", "all-gather"}),
+        # the TP activation/logit reductions lower to all-reduce and
+        # the dp grad reduction folds into the same family. jax 0.9.0's
+        # partitioner also serves the vocab-parallel embedding lookup
+        # by all-reduce (the old build emitted an all-gather there).
+        "tp2": frozenset({"all-reduce"}),
+        "dp2tp2": frozenset({"all-reduce"}),
         # pure-dp replicated adam: the dp grad reduction + scalar
         # reductions are the only collectives
         "dp2": frozenset({"all-reduce"}),

@@ -40,6 +40,10 @@ def get_batch(raw: dict) -> dict:
 
 
 def main(argv=None):
+    from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from megatron_llm_tpu.data.data_samplers import (
         build_pretraining_data_loader,
     )

@@ -317,6 +317,10 @@ def _retriever_finetune_main(args):
 
 
 def main(argv=None):
+    from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from megatron_llm_tpu.arguments import args_to_configs, build_base_parser
     from megatron_llm_tpu.parallel import initialize_parallel
     from megatron_llm_tpu.tokenizer import build_tokenizer

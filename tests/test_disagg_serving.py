@@ -427,11 +427,15 @@ class TestRetryAfterClamp:
 
 
 # ---------------------------------------------------------------------------
-# bench plumbing (non-slow: tier-1 exercises the full hand-off path)
+# bench plumbing (slow since PR 21: TestTwoStageRouting and
+# TestHandoffEnginesEndToEnd keep the hand-off path in tier-1; this row
+# only adds bench.py's harness around it, at the suite's second-highest
+# cost, and tier-1 had to pay for tests/test_tpu_lowering.py)
 # ---------------------------------------------------------------------------
 
 
 class TestBenchPlumbing:
+    @pytest.mark.slow
     def test_bench_disagg_stats_plumbing(self):
         """The extra.serving.disagg harness runs on CPU and emits its
         headline keys (the artifact run uses the bench model on TPU

@@ -98,12 +98,22 @@ class TestChipSpec:
         with pytest.raises(ValueError, match="unknown chip spec"):
             detect_chip(override="v99")
 
-    def test_cpu_detection_falls_to_default_or_none(self):
-        # the CPU harness: no TPU device kind -> None without a
-        # default, the assumed spec with one
+    def test_detection_never_assumes(self):
+        # the CPU harness has no chip: None, so callers drop their
+        # MFU/roofline gauges
         assert detect_chip() is None
-        c = detect_chip(default="v5e")
-        assert c.name == "v5e" and c.source == "assumed"
+
+        class Dev:
+            platform = "tpu"
+
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        assert detect_chip(devices=[Dev("TPU v5 lite")]).label() \
+            == "v5e:detected"
+        # a TPU that is not in the table is an error, not a default
+        with pytest.raises(ValueError, match="not in the chip spec table"):
+            detect_chip(devices=[Dev("TPU v5")])
 
     def test_table_sanity(self):
         for name, spec in CHIP_SPECS.items():
@@ -698,7 +708,7 @@ def test_bench_goodput_harness_cpu():
     from bench import goodput_stats
 
     out = goodput_stats(slots=2, n_reqs=4, gen=8, prompt_len=10,
-                        train_steps=4, seq=16)
+                        train_steps=4, seq=16, chip_spec="v5e")
     assert out["streams_bitwise_on_vs_off"]
     assert out["train_losses_bitwise_on_vs_off"]
     assert out["goodput_sum_to_wall_ok"]

@@ -281,11 +281,14 @@ class TestEngineWeightQuant:
 
 # ---------------------------------------------------------------------------
 # Bench plumbing (the extra.quant row harness, CPU-tested like the
-# serving/interference/prefix harnesses)
+# serving/interference/prefix harnesses; slow since PR 21 — TestEngineInt8
+# pins the behaviour in tier-1, this row only adds bench.py's harness,
+# and tier-1 had to pay for tests/test_entry_points.py)
 # ---------------------------------------------------------------------------
 
 
 class TestBenchQuantRow:
+    @pytest.mark.slow
     def test_quant_serving_stats_harness(self, tiny_model):
         import importlib
         import sys

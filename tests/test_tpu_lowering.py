@@ -1,0 +1,382 @@
+"""Ask the TPU compiler from the sandbox.
+
+The installed libtpu builds a COMPILE-ONLY client for a topology with no
+chip attached (`jax.experimental.topologies`), so every Pallas entry
+point can be lowered through Mosaic and XLA:TPU with interpret=False
+here. The interpreter the rest of the CPU suite runs kernels under takes
+any block shape and partitions freely, so it cannot see the two failures
+this suite exists for: a block spec Mosaic refuses, and a Mosaic call
+outside a shard_map under a multi-device mesh. These are compiler
+verdicts only — nothing runs; numbers come from `chip_smoke.py`.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from megatron_llm_tpu.ops import dispatch
+from megatron_llm_tpu.ops.decode_attention import decode_attention
+from megatron_llm_tpu.ops.flash_attention import flash_attention
+from megatron_llm_tpu.ops.prefill_attention import ragged_paged_attention
+from megatron_llm_tpu.ops.rmsnorm import fused_rms_norm
+
+BF16 = jnp.bfloat16
+D = 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        from jax.experimental import topologies
+
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or one that
+        # cannot build the client: nothing to ask
+        pytest.skip(f"compile-only TPU topology unavailable: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def kernels_on(monkeypatch):
+    """The dispatch sites ask `on_tpu()`; the lowering target here is
+    the TPU although the default backend is the CPU. conftest.py pins
+    matmul precision to "highest" for the CPU numerics suites; no entry
+    point does, and Mosaic refuses a bf16 matmul at fp32 precision."""
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def compile_on(topo, fn, *shapes):
+    """Compile fn for one v5e chip; returns the Mosaic call count."""
+    sh = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sh) for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    lowered.compile()
+    return lowered.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("g,qpk", [(4, 1), (2, 4)])
+def test_flash_fwd_bwd(topo, g, qpk):
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    n = compile_on(topo, jax.grad(loss, argnums=(0, 1, 2)),
+                   ((1, 1024, g, qpk, D), BF16), ((1, 1024, g, D), BF16),
+                   ((1, 1024, g, D), BF16))
+    assert n == 3  # forward, dq, dk/dv
+
+
+@pytest.mark.parametrize("g,qpk", [(32, 1), (8, 4), (1, 8)])
+@pytest.mark.parametrize("variant", ["row", "chunk", "int8-row",
+                                     "int8-chunk-window", "window-row"])
+def test_paged(topo, g, qpk, variant):
+    C = 256 if "chunk" in variant else 1
+    int8 = "int8" in variant
+    page, slots, max_pages = (32 if int8 else 16), 4, 16
+    pools = ((slots * max_pages + 1, page, g, D), jnp.int8 if int8 else BF16)
+    shapes = [((slots, C, g, qpk, D), BF16), ((slots, C, g, D), BF16),
+              ((slots, C, g, D), BF16), pools, pools,
+              ((slots, max_pages), jnp.int32), ((slots,), jnp.int32),
+              ((slots,), jnp.int32)]
+    if int8:
+        shapes += [(pools[0][:-1], jnp.float32)] * 2
+
+    def fn(q, kn, vn, kp, vp, pt, starts, lens, *scales):
+        kw = dict(k_scales=scales[0], v_scales=scales[1]) if scales else {}
+        return ragged_paged_attention(
+            q, kn, vn, kp, vp, pt, starts, lens,
+            window_size=100 if "window" in variant else None, **kw)
+
+    assert compile_on(topo, fn, *shapes) == 1
+
+
+@pytest.mark.parametrize("layout", ["gtd", "tgd"])
+@pytest.mark.parametrize("g,qpk", [(32, 1), (8, 4)])
+def test_dense_decode(topo, layout, g, qpk):
+    cache = (2, g, 512, D) if layout == "gtd" else (2, 512, g, D)
+    n = compile_on(
+        topo, functools.partial(decode_attention, layout=layout),
+        ((2, 1, g, qpk, D), BF16), (cache, BF16), (cache, BF16),
+        ((), jnp.int32))
+    assert n == 1
+
+
+def test_fused_rmsnorm_fwd_bwd(topo):
+    def loss(x, scale):
+        return fused_rms_norm(x, scale).astype(jnp.float32).sum()
+
+    n = compile_on(topo, jax.grad(loss, argnums=(0, 1)),
+                   ((1024, 512), BF16), ((512,), jnp.float32))
+    assert n == 2
+
+
+# ---------------------------------------------------------------------------
+# Under a multi-device mesh: Mosaic calls must sit in a shard_map
+# (parallel/mesh.shard_kernel)
+# ---------------------------------------------------------------------------
+
+
+def _abstract(tree, shardings):
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, shardings)
+
+
+def compile_train_step(topo, dp=1, pp=1, cp=1, tp=1, sp=False,
+                       zero1=False, **cfg_over):
+    """The production train step (flash on, full remat, bf16) compiled
+    for a mesh over the topology's chips; returns (Mosaic calls, bytes
+    per device)."""
+    from megatron_llm_tpu.config import (
+        ParallelConfig,
+        TrainConfig,
+        llama_config,
+    )
+    from megatron_llm_tpu.models import LlamaModel
+    from megatron_llm_tpu.optimizer.optimizer import (
+        OptimizerState,
+        init_optimizer_state,
+    )
+    from megatron_llm_tpu.parallel.mesh import (
+        destroy_parallel,
+        initialize_parallel,
+    )
+    from megatron_llm_tpu.parallel.sharding import (
+        optimizer_state_specs,
+        param_specs,
+    )
+
+    cfg = dict(num_layers=2, hidden_size=512, num_attention_heads=4,
+               num_attention_heads_kv=4, ffn_hidden_size=1024,
+               seq_length=1024, vocab_size=1024,
+               recompute_granularity="full")
+    cfg.update(cfg_over)
+    model = LlamaModel(llama_config(7, **cfg))
+    cfg = model.cfg
+    ctx = initialize_parallel(
+        dp=dp, pp=pp, tp=tp, cp=cp, sequence_parallel=sp,
+        devices=topo.devices[:dp * pp * cp * tp])
+    try:
+        mesh = ctx.mesh
+        tmpl = jax.eval_shape(model.init, jax.random.key(0))
+        if pp > 1:
+            from megatron_llm_tpu.parallel.pipeline import (
+                make_pipelined_train_step,
+                pipeline_param_specs,
+            )
+
+            pspecs = pipeline_param_specs(cfg, tmpl)
+        else:
+            from megatron_llm_tpu.training.train_step import make_train_step
+
+            pspecs = param_specs(cfg, tmpl)
+
+        def named(specs):
+            return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                                is_leaf=lambda x: isinstance(x, P))
+
+        rep = NamedSharding(mesh, P())
+        num_micro = 2 if pp > 1 else 1
+        tcfg = TrainConfig(micro_batch_size=1,
+                           global_batch_size=num_micro * dp, lr=1e-4)
+        pcfg = ParallelConfig(
+            num_microbatches=num_micro, data_parallel_size=dp,
+            pipeline_parallel_size=pp, context_parallel_size=cp,
+            tensor_parallel_size=tp, sequence_parallel=sp,
+            use_distributed_optimizer=zero1)
+        osh = named(optimizer_state_specs(cfg, tmpl, dp, zero1,
+                                          base_specs=pspecs))
+        opt = _abstract(
+            jax.eval_shape(lambda p: init_optimizer_state(p, tcfg), tmpl),
+            OptimizerState(step=rep, m=osh, v=osh, scaler=None))
+        key = ("tpu-lowering", dp, pp, cp, tp, sp, zero1,
+               tuple(sorted(cfg_over.items())))
+        if pp > 1:
+            fn = make_pipelined_train_step(model, tcfg, pcfg, ctx,
+                                           contract_key=key,
+                                           contract_owner=None)
+        else:
+            fn = make_train_step(model, tcfg, pcfg, contract_key=key,
+                                 contract_owner=None)
+        tok = jax.ShapeDtypeStruct(
+            (num_micro, dp, cfg.seq_length), jnp.int32,
+            sharding=NamedSharding(mesh, P(None, "data", None)))
+
+        def scalar():
+            return jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+
+        # graft-contract: train.step
+        lowered = jax.jit(fn, donate_argnums=(0, 1)).lower(
+            _abstract(tmpl, named(pspecs)), opt,
+            {"tokens": tok, "labels": tok}, scalar(), scalar(), None,
+            scalar())
+        mem = lowered.compile().memory_analysis()
+        return (lowered.as_text().count("tpu_custom_call"),
+                mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+    finally:
+        destroy_parallel()
+
+
+def test_train_step_tp4_sp_flash(topo):
+    """The layout in finetune.py's docstring, with the flash kernel in
+    the step: GSPMD refuses a bare Mosaic call under this mesh."""
+    n, _ = compile_train_step(topo, tp=4, sp=True)
+    assert n >= 3
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("layout", [
+    dict(dp=4), dict(dp=2, tp=2, zero1=True), dict(pp=2, tp=2),
+    dict(cp=2, tp=2), dict(pp=2, cp=2)],
+    ids=["dp4", "dp2tp2zero1", "pp2tp2", "cp2tp2", "pp2cp2"])
+def test_train_step_other_layouts(topo, layout):
+    """pp2cp2: the ring's per-hop flash kernel inside the pipeline's
+    stage+context-manual region needs the remaining axes manual too
+    (models/attention._ring_dispatch)."""
+    n, _ = compile_train_step(topo, **layout)
+    assert n >= 3
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("layout,depth", [(dict(), 2),
+                                          (dict(tp=4, sp=True), 12)],
+                         ids=["one-chip", "tp4sp"])
+def test_train_step_llama2_7b_widths_fit(topo, layout, depth):
+    """chip_smoke.py's two training shapes at the full Llama-2-7B widths
+    fit one v5e chip's 16 GB by the compiler's own accounting."""
+    n, per_device = compile_train_step(
+        topo, num_layers=depth, hidden_size=4096, num_attention_heads=32,
+        num_attention_heads_kv=32, ffn_hidden_size=11008, seq_length=4096,
+        vocab_size=32000, **layout)
+    assert n >= 3
+    assert per_device < 15 * 2**30, per_device
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_block_tp4(topo, int8):
+    """The serving attention sublayer on a tp4 mesh with group-sharded
+    pools (the --serving_tp 4 program's kernel call)."""
+    from megatron_llm_tpu.config import llama_config
+    from megatron_llm_tpu.models.attention import attention_block
+    from megatron_llm_tpu.models.rope import precompute_rope
+    from megatron_llm_tpu.parallel.mesh import (
+        ParallelContext,
+        build_mesh,
+        use_mesh,
+    )
+    from megatron_llm_tpu.parallel.sharding import kv_pool_spec
+
+    cfg = llama_config(7, num_layers=1, hidden_size=1024,
+                       num_attention_heads=8, num_attention_heads_kv=8,
+                       vocab_size=1024)
+    ctx = ParallelContext(build_mesh(tp=4, devices=topo.devices))
+    slots, C, page, max_pages, g = 4, 16, 32, 8, 8
+    pool = (slots * max_pages + 1, page, g, D)
+
+    def arg(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=ctx.sharding(*spec))
+
+    cache = {
+        "k_pages": arg(pool, jnp.int8 if int8 else BF16,
+                       kv_pool_spec(pool, 4)),
+        "v_pages": arg(pool, jnp.int8 if int8 else BF16,
+                       kv_pool_spec(pool, 4)),
+        "page_table": arg((slots, max_pages), jnp.int32),
+        "lengths": arg((slots,), jnp.int32),
+        "chunk_lens": arg((slots,), jnp.int32),
+    }
+    if int8:
+        cache["k_scales"] = arg(pool[:-1], jnp.float32,
+                                kv_pool_spec(pool[:-1], 4))
+        cache["v_scales"] = cache["k_scales"]
+    params = {"wqkv": arg((1024, 3 * 1024), jnp.float32, P(None, "model")),
+              "wo": arg((1024, 1024), jnp.float32, P("model", None))}
+    rope = np.asarray(precompute_rope(D, 4096, 10000.0, 1.0))
+
+    def fn(params, hidden, cache):
+        return attention_block(params, cfg, hidden, jnp.asarray(rope), None,
+                               None, kv_cache=cache)
+
+    with use_mesh(ctx):
+        lowered = jax.jit(fn).lower(params, arg((slots, C, 1024), BF16),
+                                    cache)
+    lowered.compile()
+    assert lowered.as_text().count("tpu_custom_call") == 1
+
+
+# ---------------------------------------------------------------------------
+# What is reported as a fallback on a TPU backend (ops/dispatch.py): trace-
+# time decisions, no compiler needed
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def reported(monkeypatch):
+    monkeypatch.setattr(dispatch, "_FALLBACKS", {})
+    return dispatch.fallbacks
+
+
+def test_min_cache_routing_is_not_a_fallback(reported):
+    """A decode cache below `decode_attn_min_cache` is the config's own
+    routing; a cache in kernel territory the block gate refuses is a
+    fallback."""
+    from megatron_llm_tpu.config import llama_config
+    from megatron_llm_tpu.models.attention import _decode_kernel_block
+
+    cfg = llama_config(7, num_layers=1)
+    assert cfg.decode_attn_min_cache == 128
+    assert _decode_kernel_block(cfg, 1, 49, "gtd") is None
+    assert _decode_kernel_block(cfg, 1, 384, "gtd") == 128
+    assert not reported()
+    assert _decode_kernel_block(cfg, 1, 312, "gtd") is None  # 8 * 39
+    assert list(reported()) == [
+        "decode_attention[qpk=1, d=128, T=312, layout=gtd] "
+        "gate=decode_attn_block"]
+
+
+def test_paged_refusals(reported):
+    def paged(page, min_cache):
+        pools = jax.ShapeDtypeStruct((9, page, 2, D), BF16)
+        new = jax.ShapeDtypeStruct((2, 1, 2, D), BF16)
+        i32 = jax.ShapeDtypeStruct((2,), jnp.int32)
+        jax.eval_shape(
+            functools.partial(ragged_paged_attention, min_cache=min_cache),
+            jax.ShapeDtypeStruct((2, 1, 2, 1, D), BF16), new, new, pools,
+            pools, jax.ShapeDtypeStruct((2, 4), jnp.int32), i32, i32)
+
+    paged(8, 128)  # reach 4 x 8 = 32 < min_cache: routed, silent
+    assert not reported()
+    paged(8, 0)  # in territory; a page of 8 does not tile bf16 sublanes
+    assert len(reported()) == 1 and "page_size=8" in list(reported())[0]
+
+
+def test_shard_kernel_reports_an_axis_it_cannot_split(reported):
+    """MQA's single KV group under tp: every model shard runs the whole
+    kernel call, and says so."""
+    from megatron_llm_tpu.parallel.mesh import (
+        ParallelContext,
+        build_mesh,
+        shard_kernel,
+        use_mesh,
+    )
+
+    def attend(q):
+        return q
+
+    spec = P("data", None, "model", None, None)
+    ctx = ParallelContext(build_mesh(tp=4, devices=jax.devices()[:4]))
+    with use_mesh(ctx):
+        jax.eval_shape(shard_kernel(attend, (spec,), spec),
+                       jax.ShapeDtypeStruct((2, 8, 4, 1, D), BF16))
+        assert not reported()
+        jax.eval_shape(shard_kernel(attend, (spec,), spec),
+                       jax.ShapeDtypeStruct((2, 8, 1, 8, D), BF16))
+    assert list(reported()) == [
+        "attend[repeated_over=model, shapes=2x8x1x8x128] gate=shard_kernel"]

@@ -3,12 +3,13 @@ decomposition (ISSUE 10, optimizer/zero1.py + training/train_step.py).
 
 The claims pinned here:
 - zero1 ON is BITWISE identical to replicated adam on the same dp mesh —
-  per-step losses, grad norms, final params AND moments — at dp2/dp4 in
-  fp32, and with the fp16 dynamic scaler (losses/params/moments bitwise;
-  the grad-norm SCALAR may differ in its last ulp: it is reduced
-  shard-wise + psum vs whole-leaf, and under fp16-scaled gradients the
-  two groupings can round differently — the clip coefficient and skip
-  decisions still agree, which is what the assert covers).
+  per-step losses, final params AND moments — at dp2/dp4 in fp32, and
+  with the fp16 dynamic scaler. The grad-norm SCALAR is pinned to its
+  last ulp, not bitwise: it is reduced shard-wise + psum vs whole-leaf,
+  and the two groupings can round differently (on jax 0.9.0's CPU
+  compiler they do at dp2 fp32, one step in three) — the clip
+  coefficient and skip decisions still agree, which bitwise params and
+  moments prove.
 - bf16 compute: the same contract to a last-ulps tolerance. The local
   shard_map program and the GSPMD program compile the bf16 softmax
   BACKWARD with different elementwise fusions (measured: the forward
@@ -47,7 +48,6 @@ from megatron_llm_tpu.optimizer.zero1 import (
 from megatron_llm_tpu.parallel.mesh import (
     destroy_parallel,
     initialize_parallel,
-    shard_map,
 )
 from megatron_llm_tpu.training.trainer import Trainer
 
@@ -141,7 +141,8 @@ class TestZero1BitwiseParity:
         (l_r, g_r, p_r, m_r, v_r, _), (l_z, g_z, p_z, m_z, v_z, _) = \
             dp2_fp32
         assert l_r == l_z, (l_r, l_z)
-        assert g_r == g_z, (g_r, g_z)
+        np.testing.assert_array_max_ulp(
+            np.float32(g_r), np.float32(g_z), maxulp=1)
         assert _trees_equal(p_r, p_z)
         assert _trees_equal(m_r, m_z)
         assert _trees_equal(v_r, v_z)
@@ -346,11 +347,11 @@ def _reduce_on_mesh(tree, dp, quantized, bucket_mb=0.001):
             local = jax.tree.map(lambda x: x[0], t)
             return reduce_scatter_grads(local, plan, quantized=quantized)
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(
                 lambda x: P(*(["data"] + [None] * (x.ndim - 1))), tree),),
-            out_specs=g_specs, check_rep=False))
+            out_specs=g_specs, check_vma=False))
         out = fn(stacked)
         txt = fn.lower(stacked).compile().as_text()
         return jax.tree.map(np.asarray, out), plan, txt

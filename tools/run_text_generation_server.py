@@ -7,7 +7,9 @@ serve PUT /api.
 
     python tools/run_text_generation_server.py --load /path/ckpt \
         --model llama --tokenizer_type SentencePieceTokenizer \
-        --vocab_file tok.model --port 5000
+        --tokenizer_model tok.model --port 5000
+
+SIGTERM / Ctrl-C stop accepting requests, drain the engine and exit 0.
 """
 
 from __future__ import annotations
@@ -15,19 +17,88 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import signal
 import sys
+import threading
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def main():
+def load_served_model(load: str, family: str = "llama", tp: int = 1,
+                      **overrides):
+    """(model, params, checkpoint dir) of a native checkpoint: the
+    architecture comes from its meta.json (plus config `overrides`),
+    and the weights are restored straight into the serving layout on
+    the first `tp` devices — tp-sharded when tp > 1. The template's
+    explicit shardings keep orbax from resurrecting the SAVING
+    topology, so a checkpoint trained on four chips loads on one and
+    the reverse."""
+    import jax
+
+    from megatron_llm_tpu.config import (
+        falcon_config,
+        gpt_config,
+        llama_config,
+    )
+    from megatron_llm_tpu.models import FalconModel, GPTModel, LlamaModel
+    from megatron_llm_tpu.parallel.mesh import ParallelContext, build_mesh
+    from megatron_llm_tpu.parallel.sharding import param_shardings
+    from megatron_llm_tpu.training.checkpointing import (
+        checkpoint_dir,
+        load_checkpoint,
+        read_tracker,
+    )
+
+    iteration, release = read_tracker(load)
+    path = checkpoint_dir(load, iteration or 0, release=release)
+    with open(os.path.join(path, "meta.json")) as f:
+        saved = json.load(f)["config"]
+
+    common = {k: saved[k] for k in (
+        "num_layers", "hidden_size", "num_attention_heads",
+        "num_attention_heads_kv", "ffn_hidden_size", "seq_length",
+        "max_position_embeddings", "padded_vocab_size", "rope_theta",
+        "rope_scaling_factor", "layernorm_epsilon",
+    ) if k in saved}
+    common.update(overrides)
+    if family == "llama":
+        cfg = llama_config(7, vocab_size=saved["padded_vocab_size"], **common)
+        model = LlamaModel(cfg)
+    elif family == "falcon":
+        cfg = falcon_config(
+            7, vocab_size=saved["padded_vocab_size"],
+            parallel_layernorm=saved.get("parallel_layernorm", False),
+            **common,
+        )
+        model = FalconModel(cfg)
+    else:
+        cfg = gpt_config(vocab_size=saved["padded_vocab_size"], **common)
+        model = GPTModel(cfg)
+
+    load_ctx = ParallelContext(build_mesh(tp=tp, devices=jax.devices()[:tp]))
+    tmpl = jax.eval_shape(model.init, jax.random.key(0))
+    tmpl = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        tmpl, param_shardings(load_ctx, cfg, tmpl))
+    loaded = load_checkpoint(load, tmpl)
+    if loaded is None:
+        raise SystemExit(f"no loadable checkpoint under {load}")
+    return model, loaded[0], path
+
+
+def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--load", required=True)
     p.add_argument("--model", choices=["llama", "falcon", "gpt"],
                    default="llama")
     p.add_argument("--tokenizer_type", default="SentencePieceTokenizer")
     p.add_argument("--vocab_file", default=None)
-    p.add_argument("--merge_file", default=None)
+    p.add_argument("--merges_file", "--merge_file", default=None)
+    p.add_argument("--tokenizer_model", default=None)
+    p.add_argument("--null_vocab_size", type=int, default=None,
+                   help="NullTokenizer: vocabulary size without the eod "
+                        "id (prompts and answers are space-separated "
+                        "token ids)")
     p.add_argument("--host", default="0.0.0.0")
     p.add_argument("--port", type=int, default=5000)
     # continuous-batching engine knobs (inference/engine.py; docs/GUIDE.md
@@ -262,66 +333,35 @@ def main():
     p.add_argument("--scale_patience", type=int, default=3,
                    help="consecutive identical scale verdicts before "
                         "the controller acts (flap hysteresis)")
-    args = p.parse_args()
+    args = p.parse_args(argv)
+
+    from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
-    import orbax.checkpoint as ocp
 
-    from megatron_llm_tpu.config import (
-        falcon_config,
-        gpt_config,
-        llama_config,
-    )
     from megatron_llm_tpu.inference.server import MegatronServer
-    from megatron_llm_tpu.models import FalconModel, GPTModel, LlamaModel
     from megatron_llm_tpu.tokenizer import build_tokenizer
-    from megatron_llm_tpu.training.checkpointing import (
-        checkpoint_dir,
-        read_tracker,
-    )
 
-    iteration, release = read_tracker(args.load)
-    path = checkpoint_dir(args.load, iteration or 0, release=release)
-    with open(os.path.join(path, "meta.json")) as f:
-        saved = json.load(f)["config"]
-
-    common = {k: saved[k] for k in (
-        "num_layers", "hidden_size", "num_attention_heads",
-        "num_attention_heads_kv", "ffn_hidden_size", "seq_length",
-        "max_position_embeddings", "padded_vocab_size", "rope_theta",
-        "rope_scaling_factor", "layernorm_epsilon",
-    ) if k in saved}
+    n_rep, tp = max(args.router_replicas, 1), max(args.serving_tp, 1)
+    if n_rep * tp > len(jax.devices()):
+        raise SystemExit(
+            f"--router_replicas {n_rep} x --serving_tp {tp} needs "
+            f"{n_rep * tp} devices, have {len(jax.devices())}")
     # serve-time RoPE overrides (ISSUE 19): the rotary tables are
     # computed from the config, not the checkpoint, so retargeting
     # theta / linear interpolation at load time is sound.
-    if args.rope_theta is not None:
-        common["rope_theta"] = args.rope_theta
-    if args.rope_scaling_factor is not None:
-        common["rope_scaling_factor"] = args.rope_scaling_factor
-    if args.attention_window_size is not None:
-        common["attention_window_size"] = args.attention_window_size
-    if args.model == "llama":
-        cfg = llama_config(7, vocab_size=saved["padded_vocab_size"], **common)
-        model = LlamaModel(cfg)
-    elif args.model == "falcon":
-        cfg = falcon_config(
-            7, vocab_size=saved["padded_vocab_size"],
-            parallel_layernorm=saved.get("parallel_layernorm", False),
-            **common,
-        )
-        model = FalconModel(cfg)
-    else:
-        cfg = gpt_config(vocab_size=saved["padded_vocab_size"], **common)
-        model = GPTModel(cfg)
-
-    tmpl = jax.eval_shape(model.init, jax.random.key(0))
-    params = ocp.StandardCheckpointer().restore(
-        os.path.join(path, "model"),
-        jax.tree.map(ocp.utils.to_shape_dtype_struct, tmpl),
-    )
+    overrides = {k: getattr(args, k) for k in (
+        "rope_theta", "rope_scaling_factor", "attention_window_size",
+    ) if getattr(args, k) is not None}
+    model, params, path = load_served_model(
+        args.load, args.model, tp=tp, **overrides)
     tokenizer = build_tokenizer(
         args.tokenizer_type, vocab_file=args.vocab_file,
-        merge_file=args.merge_file,
+        merges_file=args.merges_file,
+        tokenizer_model=args.tokenizer_model,
+        null_vocab_size=args.null_vocab_size,
     )
     engine = None
     if args.serving_slots > 0:
@@ -332,11 +372,6 @@ def main():
         # reaches the engine ctor's loud incompatibility error.
         prefix_cache = (args.prefix_cache if args.prefix_cache is not None
                         else args.prefill_chunk_tokens > 0)
-        n_rep, tp = max(args.router_replicas, 1), max(args.serving_tp, 1)
-        if n_rep * tp > len(jax.devices()):
-            raise SystemExit(
-                f"--router_replicas {n_rep} x --serving_tp {tp} needs "
-                f"{n_rep * tp} devices, have {len(jax.devices())}")
 
         def build_engine(replica_id=None, devices=None):
             return DecodeEngine(
@@ -488,10 +523,17 @@ def main():
                "/health, flight record at /flight_record, profiler at "
                "POST /profile)"
              if engine else " (whole-batch, no engine)"), flush=True)
-    MegatronServer(model, params, tokenizer, engine=serve_target,
-                   request_deadline_s=args.request_deadline_s,
-                   stream_enabled=args.stream).run(
-        args.host, args.port)
+    server = MegatronServer(model, params, tokenizer, engine=serve_target,
+                            request_deadline_s=args.request_deadline_s,
+                            stream_enabled=args.stream)
+    if threading.current_thread() is threading.main_thread():
+        signal.signal(signal.SIGTERM, signal.default_int_handler)
+    try:
+        server.run(args.host, args.port)
+    except KeyboardInterrupt:
+        pass
+    finally:
+        server.stop()
 
 
 if __name__ == "__main__":

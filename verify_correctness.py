@@ -45,6 +45,10 @@ def main():
                         "introduces it (release-gate debugging aid)")
     args = p.parse_args()
 
+    from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import torch
     from transformers import AutoModelForCausalLM, LlamaConfig, LlamaForCausalLM
 
