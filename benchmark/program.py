@@ -1,0 +1,382 @@
+"""The one file of the benchmark that touches the program under test.
+
+It builds the program's own objects from a configuration file (the model
+configuration, `Trainer`, `DecodeEngine`), lays the benchmark's seeded
+weights out the way the program wants them, and reads the program's
+counters. Nothing here measures anything. Faults for `prove.py` and the
+tests are planted here, underneath the timed path.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from . import weights
+
+_DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+
+# neutral leaf name -> path in the program's parameter tree
+LAYER_PATHS = {
+    "ln1_scale": ("input_norm", "scale"), "ln1_bias": ("input_norm", "bias"),
+    "ln2_scale": ("mlp_norm", "scale"), "ln2_bias": ("mlp_norm", "bias"),
+    "wqkv": ("attention", "wqkv"), "wo": ("attention", "wo"),
+    "w1": ("mlp", "w1"), "w2": ("mlp", "w2"),
+}
+GLOBAL_PATHS = {
+    "embedding": ("embedding", "word_embeddings"),
+    "lnf_scale": ("final_norm", "scale"), "lnf_bias": ("final_norm", "bias"),
+}
+
+
+def enable_compile_cache():
+    """The program's own persistent cache (`<checkout>/.jax_cache`, or
+    where JAX_COMPILATION_CACHE_DIR points), with every program kept,
+    however small or quick to compile."""
+    from megatron_llm_tpu.utils.compile_cache import enable_compile_cache
+
+    path = enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
+
+
+def model_config(cfg: dict, use: dict, tp: int = 1):
+    from megatron_llm_tpu.config import ModelConfig
+
+    if cfg["vocab_size"] % (128 * tp):
+        raise ValueError("vocabulary does not divide over the tp ranks")
+    seq = use.get("seq_length", use.get("max_context",
+                                        cfg["max_position_embeddings"]))
+    return ModelConfig(
+        num_layers=use["num_hidden_layers"],
+        hidden_size=cfg["hidden_size"],
+        ffn_hidden_size=cfg["ffn_hidden_size"],
+        num_attention_heads=cfg["num_attention_heads"],
+        num_attention_heads_kv=cfg["num_kv_heads"],
+        kv_channels=cfg["head_dim"],
+        max_position_embeddings=cfg["max_position_embeddings"],
+        seq_length=seq,
+        padded_vocab_size=cfg["vocab_size"],
+        layernorm_epsilon=cfg["layer_norm_epsilon"],
+        use_rms_norm=False, use_bias=cfg["bias"], glu_activation=None,
+        hidden_act=cfg["hidden_act"], position_embedding_type="rotary",
+        rope_theta=cfg["rope_theta"], parallel_attn=cfg["parallel_attn"],
+        parallel_layernorm=cfg["new_decoder_architecture"],
+        tie_embed_logits=cfg["tie_word_embeddings"],
+        hidden_dropout=0.0, attention_dropout=0.0,
+        params_dtype=_DTYPES[use.get("params_dtype",
+                                     use.get("weights_dtype", "float32"))],
+        compute_dtype=_DTYPES[use["compute_dtype"]],
+        init_method_std=cfg["initializer_range"],
+        remat_policy=use.get("remat_policy"),
+        use_flash_attn=use.get("use_flash_attn", False),
+    )
+
+
+def _set(tree: dict, path, value):
+    for k in path[:-1]:
+        tree = tree.setdefault(k, {})
+    tree[path[-1]] = value
+
+
+def _get(tree: dict, path):
+    for k in path:
+        tree = tree[k]
+    return tree
+
+
+def program_tree(layers: dict, glob: dict) -> dict:
+    """Neutral leaves -> the program's parameter tree (same arrays)."""
+    out = {}
+    for name, leaf in layers.items():
+        _set(out, ("layers",) + LAYER_PATHS[name], leaf)
+    for name, leaf in glob.items():
+        _set(out, GLOBAL_PATHS[name], leaf)
+    return out
+
+
+def neutral_leaves(tree: dict, has_ln2: bool) -> dict:
+    """The program's tree -> {neutral name: leaf}; block leaves keep the
+    leading layer axis."""
+    out = {}
+    for name, path in LAYER_PATHS.items():
+        if name.startswith("ln2") and not has_ln2:
+            continue
+        out[name] = _get(tree, ("layers",) + path)
+    for name, path in GLOBAL_PATHS.items():
+        out[name] = _get(tree, path)
+    return out
+
+
+# ---------------------------------------------------------------- training
+
+
+class TrainProgram:
+    """`Trainer` + its state, built once and handed to the window."""
+
+    def __init__(self, cfg: dict, seed: int, chips: int,
+                 mark=lambda name: None):
+        from megatron_llm_tpu.config import ParallelConfig, TrainConfig
+        from megatron_llm_tpu.models import FalconModel
+        from megatron_llm_tpu.parallel import initialize_parallel
+        from megatron_llm_tpu.training.trainer import Trainer
+
+        use = cfg["train"]
+        tp = use["tensor_parallel"]
+        if tp > chips:
+            raise ValueError("configuration needs more chips than the cell")
+        self.cfg, self.use, self.seed = cfg, use, seed
+        self.layers = use["num_hidden_layers"]
+        if tp > 1:
+            initialize_parallel(tp=tp,
+                                sequence_parallel=use["sequence_parallel"])
+        pcfg = ParallelConfig(tensor_parallel_size=tp,
+                              sequence_parallel=use["sequence_parallel"],
+                              num_microbatches=1)
+        tcfg = TrainConfig(
+            micro_batch_size=use["micro_batch_size"],
+            global_batch_size=use["global_batch_size"],
+            train_iters=10**9, optimizer=use["optimizer"], lr=use["lr"],
+            min_lr=use["lr"], lr_decay_style=use["lr_decay_style"],
+            lr_warmup_iters=0, weight_decay=use["weight_decay"],
+            clip_grad=use["clip_grad"], adam_beta1=use["adam_beta1"],
+            adam_beta2=use["adam_beta2"], adam_eps=use["adam_eps"],
+            bf16=use["compute_dtype"] == "bfloat16", seed=seed % (2**31))
+        self.model = FalconModel(model_config(cfg, use, tp))
+        self.trainer = Trainer(self.model, tcfg, pcfg)
+        mark("trainer_made")
+        self.state = self.trainer.setup()
+        jax.block_until_ready(self.state.params)
+        mark("trainer_setup")
+        # the benchmark's own seeded weights take the place of model.init's
+        shardings = jax.tree.map(lambda x: x.sharding, self.state.params)
+        self.state.params = None
+        self._make_params = jax.jit(self._seeded_params,
+                                    out_shardings=shardings)
+        self._words = weights.seed_words(seed)
+        self.state.params = jax.block_until_ready(
+            self._make_params(self._words))
+        mark("seeded_weights")
+        self.fault = None
+
+    def _seeded_params(self, words):
+        tree = program_tree(
+            weights.make_stacked(self.cfg, words, self.layers),
+            weights.make_globals(self.cfg, words))
+        dt = _DTYPES[self.use["params_dtype"]]
+        return jax.tree.map(lambda x: x.astype(dt), tree)
+
+    def step(self, text: np.ndarray) -> float:
+        """One optimizer step through `Trainer.train_step`, fenced on the
+        loss the way `Trainer.train` fences."""
+        if self.fault == "half_batch":
+            # half of the rows left out, the mean taken over the rest
+            half = text.shape[1] // 2
+            text = np.concatenate([text[:, :half], text[:, :half]], axis=1)
+        if self.fault == "no_exchange":
+            # one rank's partial products, never summed with the others'
+            self.state.params = self._one_ranks_rows(self.state.params)
+        if self.fault == "state_unchanged":
+            keep = jax.tree.map(jnp.copy, (self.state.params,
+                                           self.state.opt_state))
+        stats = self.trainer.train_step(self.state, text)
+        loss = float(stats["loss"])
+        if self.fault == "state_unchanged":
+            self.state.params, self.state.opt_state = keep
+        return loss
+
+    def _one_ranks_rows(self, params):
+        from .check import one_ranks_share
+
+        tp = self.use["tensor_parallel"]
+
+        def cut(p):
+            layers = p["layers"]
+            att = dict(layers["attention"],
+                       wo=one_ranks_share(layers["attention"]["wo"], tp, 1))
+            mlp = dict(layers["mlp"],
+                       w2=one_ranks_share(layers["mlp"]["w2"], tp, 1))
+            return dict(p, layers=dict(layers, attention=att, mlp=mlp))
+
+        shardings = jax.tree.map(lambda x: x.sharding, params)
+        return jax.jit(cut, donate_argnums=0, out_shardings=shardings)(params)
+
+    def first_moment_norms(self) -> dict:
+        """Per-leaf (and per-block) L2 norms of Adam's first moment."""
+        return _leaf_norms(self.state.opt_state.m,
+                           self.cfg["new_decoder_architecture"])
+
+    def change_norms(self) -> dict:
+        """Per-leaf norms of (parameters now - the seeded parameters), in
+        one program, so the difference itself is never held."""
+        has_ln2 = self.cfg["new_decoder_architecture"]
+
+        def norms(p, words):
+            diff = jax.tree.map(
+                lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
+                p, self._seeded_params(words))
+            return _norms_of(neutral_leaves(diff, has_ln2))
+
+        got = jax.jit(norms)(self.state.params, self._words)
+        return {k: np.asarray(v) for k, v in got.items()}
+
+    def kernel_fallbacks(self) -> int:
+        return kernel_fallbacks()
+
+    def free(self):
+        from megatron_llm_tpu.parallel.mesh import destroy_parallel
+
+        for leaf in jax.tree.leaves((self.state.params,
+                                     self.state.opt_state)):
+            leaf.delete()
+        self.state = self.trainer = None
+        destroy_parallel()
+
+
+def _norms_of(leaves: dict) -> dict:
+    """{neutral name: norms}: one per block for block leaves (leading
+    layer axis), one for a global leaf. Traced."""
+    out = {}
+    for name, x in leaves.items():
+        x = jnp.square(x.astype(jnp.float32))
+        if name in LAYER_PATHS:
+            out[name] = jnp.sqrt(jnp.sum(x.reshape(x.shape[0], -1), axis=1))
+        else:
+            out[name] = jnp.sqrt(jnp.sum(x))[None]
+    return out
+
+
+def _leaf_norms(tree: dict, has_ln2: bool) -> dict:
+    return {k: np.asarray(v) for k, v in
+            jax.jit(_norms_of)(neutral_leaves(tree, has_ln2)).items()}
+
+
+def kernel_fallbacks() -> int:
+    from megatron_llm_tpu.ops import dispatch
+
+    return len(dispatch.fallbacks())
+
+
+# ----------------------------------------------------------------- serving
+
+
+class _StackView:
+    """A 'stacked' leaf that is really one standalone buffer per block:
+    `view[i]` is block i's array. The program's `prepare_decode_params`
+    slices `x[i]` out of a stacked tree, which for a model that fills the
+    chip would hold every block twice; this hands it the per-block
+    buffers it is about to make."""
+
+    def __init__(self, per_layer):
+        self._per_layer = per_layer
+
+    def __getitem__(self, i):
+        return self._per_layer[i]
+
+
+class ServeProgram:
+    """A started `DecodeEngine` over seeded weights."""
+
+    def __init__(self, cfg: dict, seed: int, use: dict | None = None,
+                 made=None, mark=lambda name: None):
+        from megatron_llm_tpu.inference.engine import DecodeEngine
+        from megatron_llm_tpu.models import FalconModel
+
+        use = use or cfg["serve"]
+        self.cfg, self.use, self.seed = cfg, use, seed
+        self.layers = use["num_hidden_layers"]
+        self.model = FalconModel(model_config(cfg, use))
+        # `made`: weights another engine of this process already holds
+        # (the sweep builds several engines over one set of weights)
+        self.made = made or self.make_weights(cfg, seed, use)
+        per_layer, glob = self.made
+        jax.block_until_ready(per_layer)
+        mark("seeded_weights")
+        stacked = {name: _StackView([pl[name] for pl in per_layer])
+                   for name in per_layer[0]}
+        params = program_tree(stacked, glob)
+        del per_layer, stacked
+        self.engine = DecodeEngine(
+            self.model, params, slots=use["slots"],
+            page_size=use["page_size"], max_context=use["max_context"],
+            max_queue=use["max_queue"], step_horizon=use["step_horizon"],
+            prefill_chunk_tokens=use["prefill_chunk_tokens"],
+            prefix_cache=use["prefix_cache"], kv_dtype=use["kv_dtype"],
+            warmup_compile=False, termination_id=None,
+            vocab_size=cfg["vocab_size"])
+        del params
+        mark("engine_built")
+        self.engine.warmup()  # exactly this engine's own buckets
+        mark("engine_warmed")
+        self.engine.start()
+        self.fault = None
+        self._booked = 0
+
+    @staticmethod
+    def make_weights(cfg: dict, seed: int, use: dict):
+        """(per-block leaves, global leaves) in the served type: one
+        compiled program, run once per block, on the device."""
+        dt = _DTYPES[use["weights_dtype"]]
+        words = weights.seed_words(seed)
+        make_layer = jax.jit(lambda w, i: jax.tree.map(
+            lambda x: x.astype(dt), weights.make_layer(cfg, w, i)))
+        per_layer = [make_layer(words, jnp.int32(i))
+                     for i in range(use["num_hidden_layers"])]
+        glob = jax.jit(lambda w: jax.tree.map(
+            lambda x: x.astype(dt), weights.make_globals(cfg, w)))(words)
+        return per_layer, glob
+
+    def plant(self, fault: str):
+        """A token altered where it is produced: every 7th booked token
+        is replaced by its neighbour in the vocabulary."""
+        assert fault == "token_altered", fault
+        self.fault = fault
+        book = self.engine._book_token
+        vocab = self.cfg["vocab_size"]
+
+        def altered(i, tok, now=None):
+            self._booked += 1
+            if self._booked % 7 == 0:
+                tok = (tok + 1) % vocab
+            return book(i, tok, now)
+
+        self.engine._book_token = altered
+
+    def submit(self, prompt, n_out: int):
+        """What `server.py` does for one streamed request."""
+        return self.engine.submit(
+            list(prompt), n_out, top_k=1,
+            use_eod_for_early_termination=False, stream=True)
+
+    def counters(self) -> dict:
+        c = self.engine.counters()
+        return {
+            "steps": c["serve_steps"],
+            "prefill_tokens": c["serve_prefill_tokens"],
+            "admitted": c["serve_admitted"],
+            "retired": c["serve_retired"],
+            "occupancy": c["serve_slot_occupancy"],
+            "queue_depth": c["serve_queue_depth"],
+        }
+
+    def kernel_fallbacks(self) -> int:
+        return kernel_fallbacks()
+
+    def stop(self):
+        """Ends the serve loop; what is still running or queued fails."""
+        self.engine.stop(drain=False)
+
+    def free(self, keep_weights: bool = False):
+        eng = self.engine
+        eng.stop(drain=False)
+        trees = [eng._pools_k, eng._pools_v, eng._last_logits]
+        if not keep_weights:
+            trees.append(eng._dec_params)
+            self.made = None
+        self.engine = eng = None
+        for leaf in jax.tree.leaves(trees):
+            if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+                leaf.delete()
