@@ -84,6 +84,9 @@ HOT_PATHS: Dict[str, Set[str]] = {
     "megatron_llm_tpu/inference/engine.py": {
         "DecodeEngine.step",
         "DecodeEngine._step_inner",
+        # ISSUE 26: the one emission point of a round (audit trail,
+        # latency window, histogram, counters, flight recorder)
+        "DecodeEngine._emit_round",
         "DecodeEngine._decode_round",
         "DecodeEngine._mixed_round",
         "DecodeEngine._spec_round",
@@ -101,6 +104,7 @@ HOT_PATHS: Dict[str, Set[str]] = {
     },
     "megatron_llm_tpu/training/trainer.py": {
         "Trainer.train_step",
+        "Trainer._train_step",
         "Trainer.train",
     },
     # telemetry emit sites (ISSUE 13): called once or more per engine
@@ -109,6 +113,8 @@ HOT_PATHS: Dict[str, Set[str]] = {
     # gr006_span_{good,bad}.py pin the pattern.
     "megatron_llm_tpu/telemetry/trace.py": {
         "SpanTracer.span",
+        "SpanTracer.step_span",
+        "SpanTracer._live",
         "SpanTracer.instant",
         "SpanTracer.complete",
         "SpanTracer.set_context",
@@ -117,6 +123,7 @@ HOT_PATHS: Dict[str, Set[str]] = {
         "SpanTracer._tid",
         "_Span.__enter__",
         "_Span.__exit__",
+        "_Span.note",
     },
     "megatron_llm_tpu/telemetry/recorder.py": {
         "FlightRecorder.record",

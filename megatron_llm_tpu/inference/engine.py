@@ -133,7 +133,6 @@ from megatron_llm_tpu.inference.sampling import (
     modify_logits_for_top_p,
 )
 from megatron_llm_tpu.telemetry import (
-    NULL_TRACER,
     FlightRecorder,
     Histogram,
     SpanTracer,
@@ -141,6 +140,33 @@ from megatron_llm_tpu.telemetry import (
 )
 
 _logger = logging.getLogger(__name__)
+
+SERVE_THREAD = "engine-serve"
+# a round's kind by the program it dispatched, and the phases of the
+# serve loop each timed by one span (`engine.<phase>`; `wait` is
+# `engine.wait_for_work`): the keys of the serve_rounds_* /
+# serve_round_ms_* and serve_host_ms_* counters, in their order
+ROUND_KINDS = ("mixed", "decode", "spec")
+# kind -> (the program's compile-contract name, which the cost registry
+# keys its record by; the flight recorder's event kind)
+ROUND_NAMES = {"mixed": ("engine.mixed_step", "round.mixed"),
+               "decode": ("engine.decode_scan", "round.decode_scan"),
+               "spec": ("engine.spec_verify", "round.spec_verify")}
+HOST_PHASES = ("schedule", "build_inputs", "dispatch", "fetch", "book",
+               "wait")
+
+
+def _name_os_thread(name: str) -> None:
+    """Give the calling thread its OS name: the profiler names a host
+    thread's line by it (Python before 3.14 names only its own Thread
+    object), and a trace reducer finds the serve loop's spans by that
+    line. Linux only; elsewhere the line keeps the process's name."""
+    try:
+        import ctypes
+
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):
+        pass
 
 
 def horizon_buckets(step_horizon: int) -> list:
@@ -177,6 +203,7 @@ class QueueFull(RuntimeError):
     HTTP layer maps it to 503 + Retry-After."""
 
 
+@jax.named_scope("sample")
 def _greedy_pick(last_logits, vocab_size):
     """The greedy-specialized token decision — argmax on the
     vocab-clamped logits, no per-row sort machinery. ONE definition
@@ -190,6 +217,7 @@ def _greedy_pick(last_logits, vocab_size):
     return jnp.argmax(l, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("sample")
 def _per_slot_sample(logits, greedy, temperature, top_k, top_p, seeds,
                      steps, vocab_size):
     """One sampling decision per SLOT with per-slot knobs as traced
@@ -1269,10 +1297,11 @@ class DecodeEngine:
         self._round_log: collections.deque = collections.deque(
             maxlen=4096)
 
-        # -- telemetry (ISSUE 13) -----------------------------------------
-        # Span tracer: enabled only with a trace_dir (the off path is
-        # one attribute check per emit site); exported as Chrome trace
-        # JSON at stop(). Flight recorder: ALWAYS on — a bounded ring
+        # -- telemetry (ISSUE 13, ISSUE 26) -------------------------------
+        # Span tracer: every span is a profiler annotation (it lands in
+        # any jax.profiler capture, on the device's clock); with a
+        # trace_dir the spans also fill the ring exported as Chrome
+        # trace JSON at stop(). Flight recorder: ALWAYS on — a bounded ring
         # of per-round/lifecycle events auto-dumped on serve-loop
         # poison (record_dir; falls back to trace_dir) and served on
         # demand at GET /flight_record. Histograms: the distributional
@@ -1282,8 +1311,7 @@ class DecodeEngine:
         # the graft-check audit pin it).
         self.trace_dir = trace_dir
         self.record_dir = record_dir if record_dir is not None else trace_dir
-        self.tracer: SpanTracer = (SpanTracer(enabled=True)
-                                   if trace_dir else NULL_TRACER)
+        self.tracer = SpanTracer(enabled=bool(trace_dir))
         if replica_id is not None:
             # replica correlation (ISSUE 14): every span and flight-
             # recorder event from this engine names its replica, so
@@ -1311,6 +1339,15 @@ class DecodeEngine:
                 "admission, per request"),
         }
         self._rounds = 0  # did-work scheduler rounds (telemetry clock)
+        # cumulative counts taken where the work is done (_emit_round):
+        # token positions a round's program laid out against those that
+        # carried a real token, rounds and summed wall ms by kind, and
+        # the summed durations of the round's phase spans
+        self._rows_computed = 0
+        self._rows_useful = 0
+        self._kind_rounds = dict.fromkeys(ROUND_KINDS, 0)
+        self._kind_ms = dict.fromkeys(ROUND_KINDS, 0.0)
+        self._host_ms = dict.fromkeys(HOST_PHASES, 0.0)
         # fault-injection hook (ISSUE 20, inference/chaos.py): called at
         # the top of every scheduler round INSIDE the round's timed
         # window, so an injected stall rides the round wall the perf
@@ -1992,10 +2029,10 @@ class DecodeEngine:
         contract). This wrapper owns the telemetry clock (ISSUE 13):
         the jax.profiler capture hook (POST /profile) starts before /
         stops after the requested number of did-work rounds, the
-        did-work round counter feeds span correlation, and every 256
-        rounds the flight recorder takes a counters() snapshot. All of
-        it is host bookkeeping — the jitted dispatches inside are
-        telemetry-blind."""
+        did-work round counter feeds span correlation (`round`), and
+        every 256 rounds the flight recorder takes a counters()
+        snapshot. All of it is host bookkeeping — the jitted dispatches
+        inside are telemetry-blind."""
         if self._profile_pending is not None:
             self._start_profile()
         with self.mesh_scope():
@@ -2005,10 +2042,6 @@ class DecodeEngine:
             # scoped (a no-op null scope on tp=1 engines)
             did = self._step_inner()
         if did:
-            # out-of-window pages died as the round advanced lengths;
-            # return them before the next round's admission/top-up
-            # prices the pool (no-op for non-window engines)
-            self._reclaim_window_pages()
             self._rounds += 1
             if self._rounds % 256 == 0:
                 self.recorder.note_counters(self.counters())
@@ -2089,62 +2122,83 @@ class DecodeEngine:
         bounded ragged chunk of the oldest admitting prompt plus one
         decode token for every other live slot, one jitted dispatch —
         otherwise one jitted scan of up to `step_horizon` decode steps.
-        Each round's prefill/decode token split and wall time land in
-        `_round_log` (the budget audit trail) and the decode-latency
-        window behind `serve_decode_p95_ms`. Returns False when there
-        was nothing to do (idle)."""
-        t0 = time.perf_counter()
-        if self._fault_hook is not None:
-            self._fault_hook(self)
-        self._expire_deadlines()
-        did_xfer = self._apply_transfers()
-        admitted_before = self._admitted
-        t_adm = time.perf_counter()
-        admit_prefilled = self._admit()
-        if self._admitted != admitted_before:
-            self.tracer.complete(
-                "admit", t_adm, time.perf_counter(),
-                admitted=self._admitted - admitted_before,
-                prefilled_tokens=admit_prefilled)
-        if self.prefill_chunk_tokens and any(
-                s.prefilling for s in self._slots):
-            dec_steps, pf_tokens, chunk_rid, mixed_key = \
-                self._mixed_round()
-            t1 = time.perf_counter()
-            dt_ms = (t1 - t0) * 1e3
-            with self._lock:  # counters() reads these windows concurrently
-                self._round_log.append({
-                    "prefill_tokens": pf_tokens, "decode_steps": 1,
-                    "decode_slots": dec_steps, "ms": dt_ms})
-                if dec_steps:
-                    self._decode_ms.append(dt_ms)
-            if dec_steps:
-                self._hists["serve_decode_round_ms"].observe(dt_ms)
-            # the sentinel deliberately does NOT eat mixed rounds:
-            # their wall includes a prefill chunk, so a long-prompt
-            # admission would look like `patience` consecutive
-            # "regressions" against the per-token-advance baseline the
-            # decode/spec rounds feed — interference is the
-            # serve_decode_round_ms HISTOGRAM's job (bounded by
-            # design), a sustained decode slowdown is the sentinel's
-            self._note_dispatch("engine.mixed_step", mixed_key, dt_ms)
-            # chunk-prefill span: rid-correlated — a streaming client's
-            # stalled `id:` greps straight to these rounds
-            self.tracer.complete(
-                "round.mixed", t0, t1, round=self._rounds,
-                rid=chunk_rid, prefill_tokens=pf_tokens,
-                decode_slots=dec_steps)
-            self.recorder.record(
-                "round.mixed", round=self._rounds, rid=chunk_rid,
-                prefill_tokens=pf_tokens, decode_slots=dec_steps,
-                ms=round(dt_ms, 3))
-            return True
-        if self.spec_decode_k:
-            drafts = self._collect_drafts()
-            if drafts:
-                self._spec_round(drafts, t0, admit_prefilled)
-                return True
-        return self._decode_round(t0, admit_prefilled) or did_xfer
+        A round with anything to do runs under the `engine.round` span
+        with one child span a phase (schedule / build_inputs / dispatch
+        / fetch / book: docs/GUIDE.md "Observability"); what it did is
+        emitted once, by `_emit_round`, after the span closed. Returns
+        False when there was nothing to do (idle)."""
+        if not (self._queue or self._xfers
+                or any(s.req is not None for s in self._slots)):
+            if self._fault_hook is not None:
+                self._fault_hook(self)
+            return False
+        facts = None
+        with self.tracer.span("engine.round", round=self._rounds) as rnd:
+            if self._fault_hook is not None:
+                self._fault_hook(self)
+            with self.tracer.span("engine.schedule",
+                                  queue_depth=len(self._queue)) as sched:
+                self._expire_deadlines()
+                did = self._apply_transfers()
+                admitted_before = self._admitted
+                admit_prefilled = self._admit()
+                sched.note(admitted=self._admitted - admitted_before,
+                           prefilled_tokens=admit_prefilled)
+            if self.prefill_chunk_tokens and any(
+                    s.prefilling for s in self._slots):
+                facts = self._mixed_round()
+            else:
+                drafts = (self._collect_drafts() if self.spec_decode_k
+                          else None)
+                if drafts:
+                    facts = self._spec_round(drafts, admit_prefilled)
+                else:
+                    facts = self._decode_round(admit_prefilled)
+        if facts is None:
+            return did
+        facts["phases"]["schedule"] = sched
+        self._emit_round(rnd, facts)
+        return True
+
+    def _emit_round(self, rnd, facts: dict) -> None:
+        """The one emission point of a round that dispatched: the budget
+        audit trail (`_round_log`), the decode-latency window and its
+        histogram, the modeled-vs-measured note, the perf sentinel, the
+        cumulative counters and the flight recorder all read the same
+        facts and the spans' own clock reads (the ring and the profiler
+        took the spans as they closed). GR006 HOT_PATHS: host floats and
+        dict writes only."""
+        kind = facts["kind"]
+        cost_name, event = ROUND_NAMES[kind]
+        dt_ms = rnd.seconds * 1e3
+        # wall ms per decode-token advance; None for a round that
+        # advanced no decoding slot (a mixed round of prefill alone)
+        div = facts["advance_div"]
+        advance_ms = dt_ms / div if div else None
+        with self._lock:  # counters() reads these windows concurrently
+            self._round_log.append({**facts["log"], "ms": dt_ms})
+            if advance_ms is not None:
+                self._decode_ms.append(advance_ms)
+            self._rows_computed += facts["rows_computed"]
+            self._rows_useful += facts["rows_useful"]
+            self._kind_rounds[kind] += 1
+            self._kind_ms[kind] += dt_ms
+            for phase, span in facts["phases"].items():
+                self._host_ms[phase] += span.seconds * 1e3
+        if advance_ms is not None:
+            self._hists["serve_decode_round_ms"].observe(advance_ms)
+        self._note_dispatch(cost_name, facts["cost_key"], dt_ms)
+        self.recorder.record(event, round=self._rounds,
+                             **facts["event_args"], ms=round(dt_ms, 3))
+        if kind == "decode":
+            # the sentinel eats decode-scan rounds only — the one
+            # homogeneous per-token-advance series. A mixed round's wall
+            # includes a prefill chunk (a long-prompt admission would
+            # read as `patience` consecutive regressions; interference
+            # is the serve_decode_round_ms HISTOGRAM's job) and a spec
+            # round's per-advance latency moves with the ACCEPT RATE
+            # (serve_spec_accept_rate's job), not with the hardware.
+            self._sentinel_observe(advance_ms)
 
     def _note_dispatch(self, name: str, key, dt_ms: float) -> None:
         """Round-granularity modeled-vs-measured accounting behind the
@@ -2184,61 +2238,61 @@ class DecodeEngine:
                            self._sentinel.last_threshold, 3),
                        "round": self._rounds})
 
-    def _decode_round(self, t0: float, prefill_tokens: int = 0) -> bool:
+    def _decode_round(self, prefill_tokens: int = 0) -> Optional[dict]:
         """One jitted scan of up to `step_horizon` decode steps over
         every live slot (the decode-only round). The horizon is clamped
         to the nearest slot completion (so no request overruns its
         budget mid-scan) and bucketed to a power of two (bounded trace
         count). `prefill_tokens` is the device prefill _admit() ran
         inside this round (whole-prompt mode) — its stall is inside
-        this round's wall time, so the audit entry must carry it."""
+        this round's wall time, so the audit entry must carry it.
+        Returns the round's facts for `_emit_round`, None when no slot
+        is live."""
         live = [i for i, s in enumerate(self._slots) if s.req is not None]
         if not live:
-            return False
-        # nearest completion: forced tokens still owed + sampling budget
-        remaining = min(
-            len(self._slots[i].forced) + self._slots[i].req
-            .tokens_to_generate - self._slots[i].generated
-            for i in live)
-        hor = min(self.step_horizon, max(remaining, 1))
-        hor = 1 << (hor.bit_length() - 1)  # pow2 bucket
-        # windowed lazy allocation (ISSUE 19): the scan writes hor
-        # tokens past each live length — the frontier must hold real
-        # pages BEFORE dispatch (no-op for non-window engines)
-        for i in live:
-            self._ensure_pages(i, self._lengths[i] + hor)
+            return None
+        with self.tracer.span("engine.build_inputs",
+                              transfers=11) as sp_build:
+            # nearest completion: forced tokens still owed + sampling
+            # budget
+            remaining = min(
+                len(self._slots[i].forced) + self._slots[i].req
+                .tokens_to_generate - self._slots[i].generated
+                for i in live)
+            hor = min(self.step_horizon, max(remaining, 1))
+            hor = 1 << (hor.bit_length() - 1)  # pow2 bucket
+            # windowed lazy allocation (ISSUE 19): the scan writes hor
+            # tokens past each live length — the frontier must hold real
+            # pages BEFORE dispatch (no-op for non-window engines)
+            for i in live:
+                self._ensure_pages(i, self._lengths[i] + hor)
 
-        n = self.slots
-        active = np.zeros(n, bool)
-        forced = np.zeros((n, hor), np.int32)
-        use_forced = np.zeros((n, hor), bool)
-        greedy = np.ones(n, bool)
-        temperature = np.ones(n, np.float32)
-        top_k = np.zeros(n, np.int32)
-        top_p = np.zeros(n, np.float32)
-        seeds = np.zeros(n, np.uint32)
-        sample_steps = np.zeros(n, np.int32)
-        for i in live:
-            s = self._slots[i]
-            r = s.req
-            active[i] = True
-            nf = min(len(s.forced), hor)
-            if nf:
-                forced[i, :nf] = [s.forced[t] for t in range(nf)]
-                use_forced[i, :nf] = True
-            greedy[i] = r.greedy
-            temperature[i] = r.temperature
-            top_k[i] = r.top_k
-            top_p[i] = r.top_p
-            seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
-            sample_steps[i] = s.sample_step
-
-        all_greedy = all(self._slots[i].req.greedy for i in live)
-        (chosen, chosen_lp, new_logits, self._pools_k, self._pools_v,
-         self._pools_ks, self._pools_vs) = \
-            self._step_fn(hor, all_greedy)(
-                self._dec_params, self._pools_k, self._pools_v,
-                self._pools_ks, self._pools_vs,
+            n = self.slots
+            active = np.zeros(n, bool)
+            forced = np.zeros((n, hor), np.int32)
+            use_forced = np.zeros((n, hor), bool)
+            greedy = np.ones(n, bool)
+            temperature = np.ones(n, np.float32)
+            top_k = np.zeros(n, np.int32)
+            top_p = np.zeros(n, np.float32)
+            seeds = np.zeros(n, np.uint32)
+            sample_steps = np.zeros(n, np.int32)
+            for i in live:
+                s = self._slots[i]
+                r = s.req
+                active[i] = True
+                nf = min(len(s.forced), hor)
+                if nf:
+                    forced[i, :nf] = [s.forced[t] for t in range(nf)]
+                    use_forced[i, :nf] = True
+                greedy[i] = r.greedy
+                temperature[i] = r.temperature
+                top_k[i] = r.top_k
+                top_p[i] = r.top_p
+                seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
+                sample_steps[i] = s.sample_step
+            all_greedy = all(self._slots[i].req.greedy for i in live)
+            operands = (
                 self._dev(self._pt), self._dev(self._lengths),
                 self._last_logits, self._dev(active),
                 self._dev(forced), self._dev(use_forced),
@@ -2246,161 +2300,207 @@ class DecodeEngine:
                 self._dev(top_k), self._dev(top_p),
                 self._dev(seeds), self._dev(sample_steps),
             )
-        self._last_logits = new_logits
-        chosen = np.asarray(chosen)  # (slots, hor) — the scheduler's
-        # own data dependency: the next round cannot be built without it
-        # P0 (graft-check GR006 dogfood): the logprob matrix is an EXTRA
-        # per-round device->host transfer that most serving traffic
-        # (return_log_probs=False) never reads — fetch it only when some
-        # live request actually asked
-        want_lp = any(self._slots[i].req.return_log_probs for i in live)
-        chosen_lp = np.asarray(chosen_lp) if want_lp else None
+        with self.tracer.span("engine.dispatch", fn="decode_scan",
+                              kind="decode", width=hor,
+                              greedy=all_greedy,
+                              prefill_tokens=prefill_tokens,
+                              decode_slots=len(live)) as sp_disp:
+            (chosen, chosen_lp, new_logits, self._pools_k, self._pools_v,
+             self._pools_ks, self._pools_vs) = \
+                self._step_fn(hor, all_greedy)(
+                    self._dec_params, self._pools_k, self._pools_v,
+                    self._pools_ks, self._pools_vs, *operands)
+            self._last_logits = new_logits
+        with self.tracer.span("engine.fetch") as sp_fetch:
+            chosen = np.asarray(chosen)  # (slots, hor) — the scheduler's
+            # own data dependency: the next round cannot be built
+            # without it
+            # P0 (graft-check GR006 dogfood): the logprob matrix is an
+            # EXTRA per-round device->host transfer that most serving
+            # traffic (return_log_probs=False) never reads — fetch it
+            # only when some live request actually asked
+            want_lp = any(self._slots[i].req.return_log_probs
+                          for i in live)
+            chosen_lp = np.asarray(chosen_lp) if want_lp else None
         self._steps += hor
 
-        now = time.perf_counter()
-        for t in range(hor):
-            for i in live:
-                s = self._slots[i]
-                r = s.req
-                if r is None:
-                    continue  # retired earlier in this horizon (eod)
-                self._lengths[i] += 1
-                if r.return_log_probs:
-                    r.log_probs.append(float(chosen_lp[i, t]))
-                if s.forced:
-                    s.forced.popleft()  # prompt token, already in tokens
-                    continue
-                self._book_token(i, int(chosen[i, t]), now)
-        t1 = time.perf_counter()
-        dt_ms = (t1 - t0) * 1e3
-        with self._lock:  # counters() reads these windows concurrently
-            self._round_log.append({
-                "prefill_tokens": prefill_tokens, "decode_steps": hor,
-                "decode_slots": len(live), "ms": dt_ms})
-            # per decode-token-advance latency: the scan amortizes hor
-            # steps (the whole-prompt admission stall, when any, rides
-            # this round's wall time — that IS the interference)
-            self._decode_ms.append(dt_ms / hor)
-        self._hists["serve_decode_round_ms"].observe(dt_ms / hor)
-        self._note_dispatch("engine.decode_scan", (hor, all_greedy),
-                            dt_ms)
-        self._sentinel_observe(dt_ms / hor)
-        self.tracer.complete("round.decode_scan", t0, t1,
-                             round=self._rounds, horizon=hor,
-                             decode_slots=len(live),
-                             prefill_tokens=prefill_tokens)
-        self.recorder.record("round.decode_scan", round=self._rounds,
-                             horizon=hor, decode_slots=len(live),
-                             prefill_tokens=prefill_tokens,
-                             ms=round(dt_ms, 3))
-        return True
+        with self.tracer.span("engine.book") as sp_book:
+            booked_before, retired_before = self._tokens_out, self._retired
+            now = sp_book.t0
+            for t in range(hor):
+                for i in live:
+                    s = self._slots[i]
+                    r = s.req
+                    if r is None:
+                        continue  # retired earlier in this horizon (eod)
+                    self._lengths[i] += 1
+                    if r.return_log_probs:
+                        r.log_probs.append(float(chosen_lp[i, t]))
+                    if s.forced:
+                        s.forced.popleft()  # prompt token, already in
+                        continue            # tokens
+                    self._book_token(i, int(chosen[i, t]), now)
+            # out-of-window pages died as the round advanced lengths;
+            # return them before the next round's admission/top-up
+            # prices the pool (no-op for non-window engines)
+            self._reclaim_window_pages()
+            sp_book.note(booked=self._tokens_out - booked_before,
+                         retired=self._retired - retired_before)
+        return {
+            "kind": "decode",
+            "log": {"prefill_tokens": prefill_tokens, "decode_steps": hor,
+                    "decode_slots": len(live)},
+            # the scan amortizes hor steps (the whole-prompt admission
+            # stall, when any, rides this round's wall time — that IS
+            # the interference)
+            "advance_div": hor,
+            "rows_computed": self.slots * hor,
+            "rows_useful": len(live) * hor,
+            "phases": {"build_inputs": sp_build, "dispatch": sp_disp,
+                       "fetch": sp_fetch, "book": sp_book},
+            "cost_key": (hor, all_greedy),
+            "event_args": {"horizon": hor, "decode_slots": len(live),
+                           "prefill_tokens": prefill_tokens},
+        }
 
-    def _mixed_round(self):
+    def _mixed_round(self) -> dict:
         """One mixed prefill+decode round (chunked admission): the
         OLDEST admitting slot (FIFO by rid — bounds per-round prefill
         tokens to ONE chunk <= the budget) contributes a ragged prompt
         span resumed at its saved offset; every fully-prefilled live
         slot contributes one decode token; other admitting slots sit
         idle (chunk_lens 0). One jitted dispatch serves all of it.
-        Returns (decode slots advanced, prefill tokens consumed, the
-        chunk request's rid — the round's trace-span correlation
-        key — and the (width, greedy) executable key the round's
-        dispatch-overhead accounting reads)."""
-        n = self.slots
-        pref = [i for i, s in enumerate(self._slots) if s.prefilling]
-        ci = min(pref, key=lambda i: self._slots[i].req.rid)
-        s_c = self._slots[ci]
-        remaining = len(s_c.req.prompt) - s_c.prefill_pos
-        width = self._chunk_width(remaining)
-        ln = min(remaining, width)
-        dec = [i for i, s in enumerate(self._slots)
-               if s.req is not None and not s.prefilling]
-        # windowed lazy allocation (ISSUE 19): this round scatters the
-        # chunk's ln tokens (and one decode token per live slot) past
-        # the frontiers — top them up before dispatch
-        self._ensure_pages(ci, self._lengths[ci] + ln)
-        for i in dec:
-            self._ensure_pages(i, self._lengths[i] + 1)
+        Returns the round's facts for `_emit_round`: decode slots
+        advanced, prefill tokens consumed, the chunk request's rid (the
+        round's correlation key: a streaming client's stalled `id:`
+        greps straight to these rounds) and the (width, greedy)
+        executable key the dispatch-overhead accounting reads."""
+        with self.tracer.span("engine.build_inputs",
+                              transfers=12) as sp_build:
+            n = self.slots
+            pref = [i for i, s in enumerate(self._slots) if s.prefilling]
+            ci = min(pref, key=lambda i: self._slots[i].req.rid)
+            s_c = self._slots[ci]
+            remaining = len(s_c.req.prompt) - s_c.prefill_pos
+            width = self._chunk_width(remaining)
+            ln = min(remaining, width)
+            dec = [i for i, s in enumerate(self._slots)
+                   if s.req is not None and not s.prefilling]
+            # windowed lazy allocation (ISSUE 19): this round scatters
+            # the chunk's ln tokens (and one decode token per live slot)
+            # past the frontiers — top them up before dispatch
+            self._ensure_pages(ci, self._lengths[ci] + ln)
+            for i in dec:
+                self._ensure_pages(i, self._lengths[i] + 1)
 
-        chunk_tokens = np.zeros((n, width), np.int32)
-        chunk_lens = np.zeros((n,), np.int32)
-        is_prefill = np.zeros((n,), bool)
-        greedy = np.ones(n, bool)
-        temperature = np.ones(n, np.float32)
-        top_k = np.zeros(n, np.int32)
-        top_p = np.zeros(n, np.float32)
-        seeds = np.zeros(n, np.uint32)
-        sample_steps = np.zeros(n, np.int32)
-        chunk_tokens[ci, :ln] = s_c.req.prompt[
-            s_c.prefill_pos:s_c.prefill_pos + ln]
-        chunk_lens[ci] = ln
-        is_prefill[ci] = True
-        for i in dec:
-            r = self._slots[i].req
-            chunk_lens[i] = 1
-            greedy[i] = r.greedy
-            temperature[i] = r.temperature
-            top_k[i] = r.top_k
-            top_p[i] = r.top_p
-            seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
-            sample_steps[i] = self._slots[i].sample_step
-        all_greedy = all(self._slots[i].req.greedy for i in dec)
-
-        (first, first_lp, chunk_lps, new_last, self._pools_k,
-         self._pools_v, self._pools_ks, self._pools_vs) = \
-            self._mixed_fn(width, all_greedy)(
-            self._dec_params, self._pools_k, self._pools_v,
-            self._pools_ks, self._pools_vs,
-            self._dev(self._pt), self._dev(self._lengths),
-            self._last_logits, self._dev(chunk_tokens),
-            self._dev(chunk_lens), self._dev(is_prefill),
-            self._dev(ci, np.int32),
-            self._dev(greedy), self._dev(temperature),
-            self._dev(top_k), self._dev(top_p),
-            self._dev(seeds), self._dev(sample_steps),
-        )
-        self._last_logits = new_last
-        first = np.asarray(first)
-        # P0 (graft-check GR006 dogfood): logprob outputs transfer only
-        # when a live request asked for them — the mixed round is the
-        # chunked-prefill interference path the decode-p95 gauge
-        # watches, so every needless per-round transfer counts
-        want_lp = (s_c.req.return_log_probs
-                   or any(self._slots[i].req.return_log_probs
-                          for i in dec))
-        first_lp = np.asarray(first_lp) if want_lp else None
-        chunk_lps = (np.asarray(chunk_lps)
-                     if s_c.req.return_log_probs else None)
+            chunk_tokens = np.zeros((n, width), np.int32)
+            chunk_lens = np.zeros((n,), np.int32)
+            is_prefill = np.zeros((n,), bool)
+            greedy = np.ones(n, bool)
+            temperature = np.ones(n, np.float32)
+            top_k = np.zeros(n, np.int32)
+            top_p = np.zeros(n, np.float32)
+            seeds = np.zeros(n, np.uint32)
+            sample_steps = np.zeros(n, np.int32)
+            chunk_tokens[ci, :ln] = s_c.req.prompt[
+                s_c.prefill_pos:s_c.prefill_pos + ln]
+            chunk_lens[ci] = ln
+            is_prefill[ci] = True
+            for i in dec:
+                r = self._slots[i].req
+                chunk_lens[i] = 1
+                greedy[i] = r.greedy
+                temperature[i] = r.temperature
+                top_k[i] = r.top_k
+                top_p[i] = r.top_p
+                seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
+                sample_steps[i] = self._slots[i].sample_step
+            all_greedy = all(self._slots[i].req.greedy for i in dec)
+            operands = (
+                self._dev(self._pt), self._dev(self._lengths),
+                self._last_logits, self._dev(chunk_tokens),
+                self._dev(chunk_lens), self._dev(is_prefill),
+                self._dev(ci, np.int32),
+                self._dev(greedy), self._dev(temperature),
+                self._dev(top_k), self._dev(top_p),
+                self._dev(seeds), self._dev(sample_steps),
+            )
+        chunk_rid = s_c.req.rid
+        with self.tracer.span("engine.dispatch", fn="mixed_step",
+                              kind="mixed", width=width,
+                              greedy=all_greedy, rid=chunk_rid,
+                              prefill_tokens=ln,
+                              decode_slots=len(dec)) as sp_disp:
+            (first, first_lp, chunk_lps, new_last, self._pools_k,
+             self._pools_v, self._pools_ks, self._pools_vs) = \
+                self._mixed_fn(width, all_greedy)(
+                    self._dec_params, self._pools_k, self._pools_v,
+                    self._pools_ks, self._pools_vs, *operands)
+            self._last_logits = new_last
+        with self.tracer.span("engine.fetch") as sp_fetch:
+            first = np.asarray(first)
+            # P0 (graft-check GR006 dogfood): logprob outputs transfer
+            # only when a live request asked for them — the mixed round
+            # is the chunked-prefill interference path the decode-p95
+            # gauge watches, so every needless per-round transfer counts
+            want_lp = (s_c.req.return_log_probs
+                       or any(self._slots[i].req.return_log_probs
+                              for i in dec))
+            first_lp = np.asarray(first_lp) if want_lp else None
+            chunk_lps = (np.asarray(chunk_lps)
+                         if s_c.req.return_log_probs else None)
         self._steps += 1
         self._prefill_tokens += ln
 
-        # prefill slot: advance the saved offset, book prompt logprobs
-        # (position p predicts prompt token p+1; the chunk's first token
-        # was predicted by last round's final logits = first_lp)
-        r = s_c.req
-        if r.return_log_probs:
-            if s_c.prefill_pos > 0:
-                r.log_probs.append(float(first_lp[ci]))
-            if ln > 1:
-                r.log_probs.extend(
-                    float(x) for x in chunk_lps[:ln - 1])
-        s_c.prefill_pos += ln
-        s_c.prefilled += ln
-        self._lengths[ci] += ln
-        # every prompt page this chunk completed becomes a shareable
-        # cache entry (no-op without the prefix cache)
-        self._register_prefix(ci)
-
-        # decode slots: one token each, the scan-path bookkeeping at
-        # horizon 1
-        now = time.perf_counter()
-        for i in dec:
-            r = self._slots[i].req
-            self._lengths[i] += 1
+        with self.tracer.span("engine.book") as sp_book:
+            booked_before, retired_before = self._tokens_out, self._retired
+            # prefill slot: advance the saved offset, book prompt
+            # logprobs (position p predicts prompt token p+1; the
+            # chunk's first token was predicted by last round's final
+            # logits = first_lp)
+            r = s_c.req
             if r.return_log_probs:
-                r.log_probs.append(float(first_lp[i]))
-            self._book_token(i, int(first[i]), now)
-        return len(dec), ln, s_c.req.rid, (width, all_greedy)
+                if s_c.prefill_pos > 0:
+                    r.log_probs.append(float(first_lp[ci]))
+                if ln > 1:
+                    r.log_probs.extend(
+                        float(x) for x in chunk_lps[:ln - 1])
+            s_c.prefill_pos += ln
+            s_c.prefilled += ln
+            self._lengths[ci] += ln
+            # every prompt page this chunk completed becomes a shareable
+            # cache entry (no-op without the prefix cache)
+            self._register_prefix(ci)
+
+            # decode slots: one token each, the scan-path bookkeeping at
+            # horizon 1
+            now = sp_book.t0
+            for i in dec:
+                r = self._slots[i].req
+                self._lengths[i] += 1
+                if r.return_log_probs:
+                    r.log_probs.append(float(first_lp[i]))
+                self._book_token(i, int(first[i]), now)
+            # out-of-window pages died as the round advanced lengths
+            # (no-op for non-window engines)
+            self._reclaim_window_pages()
+            sp_book.note(booked=self._tokens_out - booked_before,
+                         retired=self._retired - retired_before)
+        return {
+            "kind": "mixed",
+            "log": {"prefill_tokens": ln, "decode_steps": 1,
+                    "decode_slots": len(dec)},
+            "advance_div": 1 if dec else None,
+            # every slot is laid out at the chunk's width; the chunk's
+            # tokens and one token a decoding slot are real
+            "rows_computed": n * width,
+            "rows_useful": ln + len(dec),
+            "phases": {"build_inputs": sp_build, "dispatch": sp_disp,
+                       "fetch": sp_fetch, "book": sp_book},
+            "cost_key": (width, all_greedy),
+            "event_args": {"rid": chunk_rid, "prefill_tokens": ln,
+                           "decode_slots": len(dec)},
+        }
 
     # -- prefix sharing ----------------------------------------------------
 
@@ -2513,8 +2613,7 @@ class DecodeEngine:
                 drafts[i] = d
         return drafts
 
-    def _spec_round(self, drafts: dict, t0: float,
-                    prefill_tokens: int = 0) -> None:
+    def _spec_round(self, drafts: dict, prefill_tokens: int = 0) -> dict:
         """One speculative round: every live slot contributes a ragged
         chunk — spec slots [next token + draft run], the rest plain
         width-1 decode rows — through ONE jitted width-(k+1) dispatch.
@@ -2522,135 +2621,144 @@ class DecodeEngine:
         (_make_spec_step_fn); the host books the first token plus the
         accepted run and rolls the slot's length mirror forward by
         exactly the booked count, which IS the rejection rollback (the
-        next round's writes overwrite stale K/V past it)."""
-        width = self.spec_decode_k + 1
-        n = self.slots
-        live = [i for i, s in enumerate(self._slots) if s.req is not None]
-        # windowed lazy allocation (ISSUE 19): the verify chunk writes
-        # up to 1 + len(draft) tokens past each live frontier
-        for i in live:
-            self._ensure_pages(
-                i, self._lengths[i] + 1 + len(drafts.get(i, [])))
-        chunk_tokens = np.zeros((n, width), np.int32)
-        chunk_lens = np.zeros((n,), np.int32)
-        is_spec = np.zeros((n,), bool)
-        greedy = np.ones(n, bool)
-        temperature = np.ones(n, np.float32)
-        top_k = np.zeros(n, np.int32)
-        top_p = np.zeros(n, np.float32)
-        seeds = np.zeros(n, np.uint32)
-        sample_steps = np.zeros(n, np.int32)
-        for i in live:
-            s = self._slots[i]
-            r = s.req
-            d = drafts.get(i, [])
-            if d:
-                chunk_tokens[i, 1:1 + len(d)] = d
-            chunk_lens[i] = 1 + len(d)
-            is_spec[i] = bool(d)
-            greedy[i] = r.greedy
-            temperature[i] = r.temperature
-            top_k[i] = r.top_k
-            top_p[i] = r.top_p
-            seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
-            sample_steps[i] = s.sample_step
-        all_greedy = all(self._slots[i].req.greedy for i in live)
-        (first, first_lp, gt, gt_lp, acc, new_last, self._pools_k,
-         self._pools_v, self._pools_ks, self._pools_vs) = \
-            self._spec_fn(width, all_greedy)(
-            self._dec_params, self._pools_k, self._pools_v,
-            self._pools_ks, self._pools_vs,
-            self._dev(self._pt), self._dev(self._lengths),
-            self._last_logits, self._dev(chunk_tokens),
-            self._dev(chunk_lens), self._dev(is_spec),
-            self._dev(greedy), self._dev(temperature),
-            self._dev(top_k), self._dev(top_p),
-            self._dev(seeds), self._dev(sample_steps),
-        )
-        self._last_logits = new_last
-        first = np.asarray(first)
-        gt = np.asarray(gt)
-        acc = np.asarray(acc)
-        # P0 (graft-check GR006 dogfood): the two logprob matrices are
-        # EXTRA per-round device->host transfers that logprob-less
-        # traffic (the common case) never reads — fetch them only when
-        # some live request actually asked
-        want_lp = any(self._slots[i].req.return_log_probs for i in live)
-        first_lp = np.asarray(first_lp) if want_lp else None
-        gt_lp = np.asarray(gt_lp) if want_lp else None
+        next round's writes overwrite stale K/V past it). Returns the
+        round's facts for `_emit_round`."""
+        with self.tracer.span("engine.build_inputs",
+                              transfers=11) as sp_build:
+            width = self.spec_decode_k + 1
+            n = self.slots
+            live = [i for i, s in enumerate(self._slots)
+                    if s.req is not None]
+            # windowed lazy allocation (ISSUE 19): the verify chunk
+            # writes up to 1 + len(draft) tokens past each live frontier
+            for i in live:
+                self._ensure_pages(
+                    i, self._lengths[i] + 1 + len(drafts.get(i, [])))
+            chunk_tokens = np.zeros((n, width), np.int32)
+            chunk_lens = np.zeros((n,), np.int32)
+            is_spec = np.zeros((n,), bool)
+            greedy = np.ones(n, bool)
+            temperature = np.ones(n, np.float32)
+            top_k = np.zeros(n, np.int32)
+            top_p = np.zeros(n, np.float32)
+            seeds = np.zeros(n, np.uint32)
+            sample_steps = np.zeros(n, np.int32)
+            for i in live:
+                s = self._slots[i]
+                r = s.req
+                d = drafts.get(i, [])
+                if d:
+                    chunk_tokens[i, 1:1 + len(d)] = d
+                chunk_lens[i] = 1 + len(d)
+                is_spec[i] = bool(d)
+                greedy[i] = r.greedy
+                temperature[i] = r.temperature
+                top_k[i] = r.top_k
+                top_p[i] = r.top_p
+                seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
+                sample_steps[i] = s.sample_step
+            all_greedy = all(self._slots[i].req.greedy for i in live)
+            operands = (
+                self._dev(self._pt), self._dev(self._lengths),
+                self._last_logits, self._dev(chunk_tokens),
+                self._dev(chunk_lens), self._dev(is_spec),
+                self._dev(greedy), self._dev(temperature),
+                self._dev(top_k), self._dev(top_p),
+                self._dev(seeds), self._dev(sample_steps),
+            )
+        with self.tracer.span("engine.dispatch", fn="spec_step",
+                              kind="spec", width=width, greedy=all_greedy,
+                              prefill_tokens=prefill_tokens,
+                              decode_slots=len(live)) as sp_disp:
+            (first, first_lp, gt, gt_lp, acc, new_last, self._pools_k,
+             self._pools_v, self._pools_ks, self._pools_vs) = \
+                self._spec_fn(width, all_greedy)(
+                    self._dec_params, self._pools_k, self._pools_v,
+                    self._pools_ks, self._pools_vs, *operands)
+            self._last_logits = new_last
+        with self.tracer.span("engine.fetch") as sp_fetch:
+            first = np.asarray(first)
+            gt = np.asarray(gt)
+            acc = np.asarray(acc)
+            # P0 (graft-check GR006 dogfood): the two logprob matrices
+            # are EXTRA per-round device->host transfers that
+            # logprob-less traffic (the common case) never reads — fetch
+            # them only when some live request actually asked
+            want_lp = any(self._slots[i].req.return_log_probs
+                          for i in live)
+            first_lp = np.asarray(first_lp) if want_lp else None
+            gt_lp = np.asarray(gt_lp) if want_lp else None
         self._steps += 1
         self._spec_rounds += 1
 
-        now = time.perf_counter()
-        emitted_total = 0
-        for i in live:
-            s = self._slots[i]
-            r = s.req
-            d_n = int(chunk_lens[i]) - 1
-            a = int(acc[i]) if d_n else 0
-            self._spec_proposed += d_n
-            # the round's first token (decided from the carried logits,
-            # exactly a decode row), then the accepted draft run — each
-            # accepted token IS the greedy target the decode scan would
-            # have produced at that position
-            emit = [(int(first[i]),
-                     float(first_lp[i]) if want_lp else 0.0)]
-            emit += [(int(gt[i, j]),
-                      float(gt_lp[i, j]) if want_lp else 0.0)
-                     for j in range(a)]
-            booked = 0
-            for j, (tok, lp) in enumerate(emit):
-                self._lengths[i] += 1
-                if r.return_log_probs:
-                    r.log_probs.append(lp)
-                if j > 0:
-                    # per-request spec accounting for the retire cost
-                    # record: BEFORE _book_token, which may retire the
-                    # slot (resetting its counters) on eod/budget
-                    s.spec_accepted += 1
-                booked += 1
-                if self._book_token(i, tok, now):
-                    break  # eod/budget: stale chunk tail never books
-            emitted_total += booked
-            # acceptance gauge counts only draft tokens actually BOOKED
-            # (booked minus the first decode-row token): eod/budget can
-            # retire the slot mid-run, and the unbooked accepted tail
-            # must not inflate serve_spec_accept_rate — operators read
-            # that gauge to decide whether spec decode pays for itself
-            self._spec_accepted += booked - 1
-
-        t1 = time.perf_counter()
-        dt_ms = (t1 - t0) * 1e3
-        per_advance = dt_ms * len(live) / max(emitted_total, 1)
-        with self._lock:  # counters() reads these windows concurrently
+        with self.tracer.span("engine.book") as sp_book:
+            retired_before = self._retired
+            now = sp_book.t0
+            emitted_total = 0
+            for i in live:
+                s = self._slots[i]
+                r = s.req
+                d_n = int(chunk_lens[i]) - 1
+                a = int(acc[i]) if d_n else 0
+                self._spec_proposed += d_n
+                # the round's first token (decided from the carried
+                # logits, exactly a decode row), then the accepted draft
+                # run — each accepted token IS the greedy target the
+                # decode scan would have produced at that position
+                emit = [(int(first[i]),
+                         float(first_lp[i]) if want_lp else 0.0)]
+                emit += [(int(gt[i, j]),
+                          float(gt_lp[i, j]) if want_lp else 0.0)
+                         for j in range(a)]
+                booked = 0
+                for j, (tok, lp) in enumerate(emit):
+                    self._lengths[i] += 1
+                    if r.return_log_probs:
+                        r.log_probs.append(lp)
+                    if j > 0:
+                        # per-request spec accounting for the retire
+                        # cost record: BEFORE _book_token, which may
+                        # retire the slot (resetting its counters) on
+                        # eod/budget
+                        s.spec_accepted += 1
+                    booked += 1
+                    if self._book_token(i, tok, now):
+                        break  # eod/budget: stale chunk tail never books
+                emitted_total += booked
+                # acceptance gauge counts only draft tokens actually
+                # BOOKED (booked minus the first decode-row token):
+                # eod/budget can retire the slot mid-run, and the
+                # unbooked accepted tail must not inflate
+                # serve_spec_accept_rate — operators read that gauge to
+                # decide whether spec decode pays for itself
+                self._spec_accepted += booked - 1
+            # out-of-window pages died as the round advanced lengths
+            # (no-op for non-window engines)
+            self._reclaim_window_pages()
+            sp_book.note(booked=emitted_total,
+                         retired=self._retired - retired_before)
+        return {
+            "kind": "spec",
             # prefill_tokens: whole-prompt-mode _admit() ran its device
             # prefill inside this round's wall time (the _decode_round
             # contract) — the audit trail must carry it here too
-            self._round_log.append({
-                "prefill_tokens": prefill_tokens, "decode_steps": 1,
-                "decode_slots": len(live), "ms": dt_ms,
-                "spec_emitted": emitted_total})
+            "log": {"prefill_tokens": prefill_tokens, "decode_steps": 1,
+                    "decode_slots": len(live),
+                    "spec_emitted": emitted_total},
             # per decode-token advance: one spec round advances
             # emitted/live tokens per slot
-            self._decode_ms.append(per_advance)
-        self._hists["serve_decode_round_ms"].observe(per_advance)
-        self._note_dispatch("engine.spec_verify", (width, all_greedy),
-                            dt_ms)
-        # NOT fed to the sentinel (same reasoning as mixed rounds): a
-        # spec round's per-advance latency moves with the ACCEPT RATE
-        # — adversarial prompts dropping acceptance would read as a
-        # hardware regression against a decode-scan baseline. The
-        # sentinel watches the one homogeneous series (decode-scan
-        # per-token-advance); acceptance drift is serve_spec_accept_
-        # rate's job.
-        self.tracer.complete("round.spec_verify", t0, t1,
-                             round=self._rounds, decode_slots=len(live),
-                             emitted=emitted_total,
-                             drafted=len(drafts))
-        self.recorder.record("round.spec_verify", round=self._rounds,
-                             decode_slots=len(live),
-                             emitted=emitted_total, drafted=len(drafts),
-                             ms=round(dt_ms, 3))
+            "advance_div": max(emitted_total, 1) / len(live),
+            # every slot is laid out k+1 wide; the booked tokens (first
+            # + accepted drafts) are what the round was for
+            "rows_computed": n * width,
+            "rows_useful": emitted_total,
+            "phases": {"build_inputs": sp_build, "dispatch": sp_disp,
+                       "fetch": sp_fetch, "book": sp_book},
+            "cost_key": (width, all_greedy),
+            "event_args": {"decode_slots": len(live),
+                           "emitted": emitted_total,
+                           "drafted": len(drafts)},
+        }
 
     def drain(self):
         """Run until the queue and every slot are empty."""
@@ -3194,6 +3302,7 @@ class DecodeEngine:
         self._running = True
 
         def loop():
+            _name_os_thread(SERVE_THREAD)
             while self._running:
                 try:
                     did = self.step()
@@ -3226,9 +3335,20 @@ class DecodeEngine:
                 if not did:
                     with self._work:
                         if self._running:
-                            self._work.wait(timeout=0.05)
+                            # idle because no request, not because of
+                            # the host: the device gap under this span
+                            # is nobody's fault
+                            with self.tracer.span(
+                                    "engine.wait_for_work",
+                                    live_slots=sum(
+                                        s.req is not None
+                                        for s in self._slots),
+                                    queue_depth=len(self._queue)) as sp:
+                                self._work.wait(timeout=0.05)
+                            self._host_ms["wait"] += sp.seconds * 1e3
 
-        self._thread = threading.Thread(target=loop, daemon=True)
+        self._thread = threading.Thread(target=loop, daemon=True,
+                                        name=SERVE_THREAD)
         self._thread.start()
 
     def stop(self, drain: bool = True):
@@ -3430,6 +3550,22 @@ class DecodeEngine:
             # Pallas kernel that gave way to its XLA reference is named
             # here, so "served" can never quietly mean "fell back"
             out["serve_kernel_fallbacks"] = "; ".join(sorted(gave_way))
+        # ISSUE 26, always on and LAST, so every schema pinned before
+        # them stays a byte-compatible prefix: padding share = 1 -
+        # useful / computed rows; rounds and their summed wall by kind
+        # (chunk round against decode round); the summed durations of
+        # the serve loop's phase spans
+        with self._lock:
+            out["serve_rows_computed"] = self._rows_computed
+            out["serve_rows_useful"] = self._rows_useful
+            for kind in ROUND_KINDS:
+                out["serve_rounds_" + kind] = self._kind_rounds[kind]
+            for kind in ROUND_KINDS:
+                out["serve_round_ms_" + kind] = round(
+                    self._kind_ms[kind], 3)
+            for phase in HOST_PHASES:
+                out["serve_host_ms_" + phase] = round(
+                    self._host_ms[phase], 3)
         return out
 
     def export_gauges(self, timers=None):

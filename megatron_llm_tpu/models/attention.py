@@ -223,6 +223,21 @@ def causal_mask(s: int, t: Optional[int] = None, offset: int = 0) -> jnp.ndarray
     return cols > rows
 
 
+def _out_proj(attn_params: dict, ctx: jnp.ndarray, compute_dtype):
+    """(b, s, g, q_per_kv * d) attention output -> (b, s, h): the heads
+    constraint, the row-parallel `wo` product and its bias. Under tp the
+    product's partial sums are exchanged here (all-reduce, or the
+    reduce-scatter of sequence parallelism)."""
+    with jax.named_scope("out_proj"):
+        b, s = ctx.shape[:2]
+        ctx = shard_activation(ctx, "heads").reshape(b, s, -1)
+        out = qdot(ctx, attn_params["wo"], compute_dtype)
+        if "bo" in attn_params:
+            out = out + attn_params["bo"].astype(compute_dtype)
+    return out
+
+
+@jax.named_scope("attention")
 def attention_block(
     attn_params: dict,
     cfg,
@@ -274,15 +289,16 @@ def attention_block(
     # qdot: `hidden @ wqkv.astype(dt)` for fp weights (bitwise the old
     # call), int8 GEMV + per-channel scale for weight-only quantized
     # decode trees (prepare_decode_params(quantize_int8=True))
-    mixed = qdot(hidden, attn_params["wqkv"], compute_dtype)
-    if "bqkv" in attn_params:
-        mixed = mixed + attn_params["bqkv"].astype(compute_dtype)
-    # named save point: under remat_policy selective/offload the fused QKV
-    # projection is kept for backward; q/k/v (incl. RoPE) rebuild from it
-    # with elementwise ops only (models/remat.py)
-    mixed = _savepoint(mixed, "qkv_proj")
-    q, k, v = split_qkv(mixed, cfg)
-    q = shard_activation(q, "groups")
+    with jax.named_scope("qkv_proj"):
+        mixed = qdot(hidden, attn_params["wqkv"], compute_dtype)
+        if "bqkv" in attn_params:
+            mixed = mixed + attn_params["bqkv"].astype(compute_dtype)
+        # named save point: under remat_policy selective/offload the
+        # fused QKV projection is kept for backward; q/k/v (incl. RoPE)
+        # rebuild from it with elementwise ops only (models/remat.py)
+        mixed = _savepoint(mixed, "qkv_proj")
+        q, k, v = split_qkv(mixed, cfg)
+        q = shard_activation(q, "groups")
 
     if kv_cache is not None and "k_pages" in kv_cache:
         # THE paged branch (ISSUE 18 — the engine's one attention path):
@@ -361,8 +377,10 @@ def attention_block(
         if doc_starts is not None:
             operands.append(doc_starts)
             in_specs.append(P())
-        res = shard_kernel(paged, in_specs, tuple(out_specs),
-                           check_vma=False)(*operands)
+        # kv_write and page_gather open inside (ops/prefill_attention.py)
+        with jax.named_scope("attn_core"):
+            res = shard_kernel(paged, in_specs, tuple(out_specs),
+                               check_vma=False)(*operands)
         # cache pytree layout is carry-stable: "chunk_lens" stays a key
         # only in the chunked form (the decode scan's carry never grows)
         new_cache = {"page_table": page_table,
@@ -376,12 +394,8 @@ def attention_block(
              new_cache["k_scales"], new_cache["v_scales"]) = res
         else:
             ctx, new_cache["k_pages"], new_cache["v_pages"] = res
-        ctx = shard_activation(ctx.reshape(b, s, g, qpk * d), "heads") \
-            .reshape(b, s, -1)
-        out = qdot(ctx, attn_params["wo"], compute_dtype)
-        if "bo" in attn_params:
-            out = out + attn_params["bo"].astype(compute_dtype)
-        return out, new_cache
+        return _out_proj(attn_params, ctx.reshape(b, s, g, qpk * d),
+                         compute_dtype), new_cache
     if kv_cache is not None:
         offset = kv_cache["offset"]
         if position_ids is None:
@@ -397,12 +411,13 @@ def attention_block(
             # measured: the minor-axis column scatter cost more than the
             # sublane-reduce saved.)
             g, qpk, d = cfg.num_query_groups, cfg.q_per_kv, cfg.head_dim
-            kc = jax.lax.dynamic_update_slice(
-                kv_cache["k_gtd"], k.transpose(0, 2, 1, 3), (0, 0, offset, 0)
-            )
-            vc = jax.lax.dynamic_update_slice(
-                kv_cache["v_gtd"], v.transpose(0, 2, 1, 3), (0, 0, offset, 0)
-            )
+            with jax.named_scope("kv_write"):
+                kc = jax.lax.dynamic_update_slice(
+                    kv_cache["k_gtd"], k.transpose(0, 2, 1, 3),
+                    (0, 0, offset, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    kv_cache["v_gtd"], v.transpose(0, 2, 1, 3),
+                    (0, 0, offset, 0))
             new_cache = {"k_gtd": kc, "v_gtd": vc, "offset": offset + s}
             t = kc.shape[2]
             bt = _decode_kernel_block(cfg, s, t, "gtd")
@@ -414,13 +429,15 @@ def attention_block(
                     decode_attention,
                 )
 
-                ctx = shard_kernel(
-                    functools.partial(
-                        decode_attention, layout="gtd", use_pallas=True,
-                        block_t=bt, interpret=cfg.decode_attn_interpret),
-                    (_Q_SPEC, _KV_GTD_SPEC, _KV_GTD_SPEC, P()), _Q_SPEC,
-                    check_vma=False,
-                )(q, kc, vc, offset + s)
+                with jax.named_scope("attn_core"):
+                    ctx = shard_kernel(
+                        functools.partial(
+                            decode_attention, layout="gtd",
+                            use_pallas=True, block_t=bt,
+                            interpret=cfg.decode_attn_interpret),
+                        (_Q_SPEC, _KV_GTD_SPEC, _KV_GTD_SPEC, P()),
+                        _Q_SPEC, check_vma=False,
+                    )(q, kc, vc, offset + s)
             else:
                 from megatron_llm_tpu.ops.decode_attention import (
                     _xla_decode,
@@ -430,13 +447,10 @@ def attention_block(
                 # O(s*t) iota mask) — ONE definition so the exact-match
                 # tests pin the kernel against the code that actually
                 # serves the fallback
-                ctx = _xla_decode(q, kc, vc, offset + s, "gtd")
-            ctx = shard_activation(ctx.reshape(b, s, g, qpk * d), "heads") \
-                .reshape(b, s, -1)
-            out = qdot(ctx, attn_params["wo"], compute_dtype)
-            if "bo" in attn_params:
-                out = out + attn_params["bo"].astype(compute_dtype)
-            return out, new_cache
+                with jax.named_scope("attn_core"):
+                    ctx = _xla_decode(q, kc, vc, offset + s, "gtd")
+            return _out_proj(attn_params, ctx.reshape(b, s, g, qpk * d),
+                             compute_dtype), new_cache
         if "layer" in kv_cache:
             # stacked-cache form (decode hot path): update THIS layer's
             # token column in place inside the full (L, b, T, g, d) stack
@@ -445,21 +459,23 @@ def attention_block(
             # buffer and re-stacking it through scan ys) measured 2.2x
             # faster per decode step at b=8/T=576 on v5e.
             lidx = kv_cache["layer"]
-            kc = jax.lax.dynamic_update_slice(
-                kv_cache["k"], k[None], (lidx, 0, offset, 0, 0)
-            )
-            vc = jax.lax.dynamic_update_slice(
-                kv_cache["v"], v[None], (lidx, 0, offset, 0, 0)
-            )
-            k_full = jax.lax.dynamic_index_in_dim(kc, lidx, 0, False)
-            v_full = jax.lax.dynamic_index_in_dim(vc, lidx, 0, False)
+            with jax.named_scope("kv_write"):
+                kc = jax.lax.dynamic_update_slice(
+                    kv_cache["k"], k[None], (lidx, 0, offset, 0, 0)
+                )
+                vc = jax.lax.dynamic_update_slice(
+                    kv_cache["v"], v[None], (lidx, 0, offset, 0, 0)
+                )
+                k_full = jax.lax.dynamic_index_in_dim(kc, lidx, 0, False)
+                v_full = jax.lax.dynamic_index_in_dim(vc, lidx, 0, False)
             new_cache = {"k": kc, "v": vc, "offset": offset + s,
                          "layer": lidx}
         else:
-            k_full = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["k"], k, offset, axis=1)
-            v_full = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["v"], v, offset, axis=1)
+            with jax.named_scope("kv_write"):
+                k_full = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache["k"], k, offset, axis=1)
+                v_full = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache["v"], v, offset, axis=1)
             new_cache = {"k": k_full, "v": v_full, "offset": offset + s}
         t = k_full.shape[1]
         bt = _decode_kernel_block(cfg, s, t, "tgd")
@@ -472,20 +488,22 @@ def attention_block(
                 decode_attention,
             )
 
-            ctx = shard_kernel(
-                functools.partial(
-                    decode_attention, layout="tgd", use_pallas=True,
-                    block_t=bt, interpret=cfg.decode_attn_interpret),
-                (_Q_SPEC, _KV_SPEC, _KV_SPEC, P()), _Q_SPEC,
-                check_vma=False,
-            )(q, k_full, v_full, offset + s).reshape(b, s, -1)
+            with jax.named_scope("attn_core"):
+                ctx = shard_kernel(
+                    functools.partial(
+                        decode_attention, layout="tgd", use_pallas=True,
+                        block_t=bt, interpret=cfg.decode_attn_interpret),
+                    (_Q_SPEC, _KV_SPEC, _KV_SPEC, P()), _Q_SPEC,
+                    check_vma=False,
+                )(q, k_full, v_full, offset + s).reshape(b, s, -1)
         else:
             # rows attend to cols <= offset+row
             rows = offset + jnp.arange(s)[:, None]
             cols = jnp.arange(t)[None, :]
             dec_mask = cols > rows  # (s, t)
-            ctx = grouped_attention(q, k_full, v_full, dec_mask, cfg,
-                                    dropout_rng, deterministic=True)
+            with jax.named_scope("attn_core"):
+                ctx = grouped_attention(q, k_full, v_full, dec_mask, cfg,
+                                        dropout_rng, deterministic=True)
     else:
         if rope_table is not None:
             q = apply_rope(q, rope_table, position_ids)
@@ -547,7 +565,8 @@ def attention_block(
         flash_ok = cfg.use_flash_attn and mask is None and no_dropout \
             and doc_start is None
         if ring_ok:
-            ctx = _ring_dispatch(q, k, v, doc_start=doc_start)
+            with jax.named_scope("attn_core"):
+                ctx = _ring_dispatch(q, k, v, doc_start=doc_start)
             ctx = _savepoint(ctx, "attn_ctx").reshape(b, s, -1)
         elif flash_ok:
             from megatron_llm_tpu.ops.flash_attention import flash_attention
@@ -556,10 +575,11 @@ def attention_block(
             # ("attn_ctx"/"flash_lse", ops/flash_attention.py) so the
             # selective policy can keep both and the backward never
             # re-runs the forward kernel
-            ctx = shard_kernel(
-                functools.partial(flash_attention, causal=True),
-                (_Q_SPEC, _KV_SPEC, _KV_SPEC), _Q_SPEC,
-            )(q, k, v)
+            with jax.named_scope("attn_core"):
+                ctx = shard_kernel(
+                    functools.partial(flash_attention, causal=True),
+                    (_Q_SPEC, _KV_SPEC, _KV_SPEC), _Q_SPEC,
+                )(q, k, v)
             ctx = ctx.reshape(b, s, -1)
         else:
             if mask is None:
@@ -570,17 +590,15 @@ def attention_block(
             # behavior, ref: transformer.py:357-401, now expressed by the
             # name policy in models/remat.py rather than a nested
             # jax.checkpoint around the core).
-            ctx = grouped_attention(q, k, v, mask, cfg, dropout_rng,
-                                    deterministic)
+            with jax.named_scope("attn_core"):
+                ctx = grouped_attention(q, k, v, mask, cfg, dropout_rng,
+                                        deterministic)
             ctx = _savepoint(ctx, "attn_ctx")
         new_cache = None
 
-    ctx = shard_activation(
+    out = _out_proj(
+        attn_params,
         ctx.reshape(b, s, cfg.num_query_groups, cfg.q_per_kv * cfg.head_dim),
-        "heads",
-    ).reshape(b, s, -1)
-    out = qdot(ctx, attn_params["wo"], compute_dtype)
-    if "bo" in attn_params:
-        out = out + attn_params["bo"].astype(compute_dtype)
+        compute_dtype)
     out = _savepoint(out, "attn_dense")
     return out, new_cache

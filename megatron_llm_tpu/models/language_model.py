@@ -66,6 +66,7 @@ def init_language_model_params(cfg, key) -> dict:
     return params
 
 
+@jax.named_scope("embed")
 def embed_tokens(params, cfg, tokens, position_ids=None, dropout_rng=None,
                  deterministic=True, tokentype_ids=None):
     """(b, s) int32 -> (b, s, h) compute-dtype (ref: Embedding.forward
@@ -91,6 +92,7 @@ def embed_tokens(params, cfg, tokens, position_ids=None, dropout_rng=None,
     return hidden
 
 
+@jax.named_scope("loss")
 def chunked_head_cross_entropy(params, cfg, hidden, labels,
                                chunk_size: int = 1024) -> jnp.ndarray:
     """Per-token CE computed chunk-by-chunk over the sequence.
@@ -147,6 +149,7 @@ def chunked_head_cross_entropy(params, cfg, hidden, labels,
     return jnp.moveaxis(losses, 0, 1).reshape(b, s)
 
 
+@jax.named_scope("head")
 def lm_logits(params, cfg, hidden) -> jnp.ndarray:
     """Project to (padded) vocab (ref: parallel_lm_logits language_model.py:24-53).
 

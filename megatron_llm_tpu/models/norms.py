@@ -9,6 +9,7 @@ versions are the always-correct XLA-fused reference implementations.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -32,6 +33,7 @@ def layer_norm(
     return normed * scale.astype(x.dtype) + bias.astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def apply_norm(x: jnp.ndarray, norm_params: dict, cfg) -> jnp.ndarray:
     """Dispatch on config (ref: transformer.py chooses RMSNorm vs LayerNorm).
 

@@ -9,6 +9,7 @@ instead of complex64 (XLA on TPU prefers real arithmetic), computed in fp32.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -31,6 +32,7 @@ def precompute_rope(
     return jnp.stack([jnp.cos(freqs), jnp.sin(freqs)], axis=-1)
 
 
+@jax.named_scope("rope")
 def apply_rope(
     x: jnp.ndarray,
     rope: jnp.ndarray,

@@ -112,6 +112,7 @@ def init_layer_params(cfg, key, num_layers: Optional[int] = None) -> dict:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("mlp")
 def mlp_block(mlp_params, cfg, hidden, dropout_rng, deterministic):
     """ParallelMLP (ref: transformer.py:77-142): h -> [2x]ffn -> act -> h.
 
@@ -122,6 +123,26 @@ def mlp_block(mlp_params, cfg, hidden, dropout_rng, deterministic):
     scale); fp weights take the bitwise-unchanged matmuls."""
     dt = cfg.compute_dtype
     w1 = mlp_params["w1"]
+    with jax.named_scope("up"):
+        x = _mlp_up(mlp_params, cfg, hidden, w1, dt)
+    with jax.named_scope("act"):
+        if cfg.glu_activation:
+            x = shard_activation(x, "glu_ffn")
+            act = GLU_ACTIVATIONS[cfg.glu_activation]
+            x = act(x[..., 0, :], x[..., 1, :])
+        else:
+            x = ACTIVATIONS[cfg.hidden_act](x)
+        x = shard_activation(x, "ffn")
+    with jax.named_scope("down"):
+        x = qdot(x, mlp_params["w2"], dt)
+        if "b2" in mlp_params:
+            x = x + mlp_params["b2"].astype(dt)
+        x = _savepoint(x, "mlp_out")
+    return x
+
+
+def _mlp_up(mlp_params, cfg, hidden, w1, dt):
+    """h -> [2x]ffn, bias and the named save point (the `mlp/up` scope)."""
     if cfg.glu_activation:
         if is_quantized_weight(w1) or w1.ndim == 2:
             # Pre-flattened (h, 2f) decode layout (see
@@ -145,21 +166,11 @@ def mlp_block(mlp_params, cfg, hidden, dropout_rng, deterministic):
         # named save point: the pre-GLU up-projection — what the selective
         # policy keeps so the gate/up GEMM never re-runs in backward (the
         # GLU combine itself is the unnamed-elementwise part it recomputes)
-        x = _savepoint(x, "mlp_pre_act")
-        x = shard_activation(x, "glu_ffn")
-        act = GLU_ACTIVATIONS[cfg.glu_activation]
-        x = act(x[..., 0, :], x[..., 1, :])
-    else:
-        x = qdot(hidden, w1, dt)
-        if "b1" in mlp_params:
-            x = x + mlp_params["b1"].astype(dt)
-        x = _savepoint(x, "mlp_pre_act")
-        x = ACTIVATIONS[cfg.hidden_act](x)
-    x = shard_activation(x, "ffn")
-    x = qdot(x, mlp_params["w2"], dt)
-    if "b2" in mlp_params:
-        x = x + mlp_params["b2"].astype(dt)
-    return _savepoint(x, "mlp_out")
+        return _savepoint(x, "mlp_pre_act")
+    x = qdot(hidden, w1, dt)
+    if "b1" in mlp_params:
+        x = x + mlp_params["b1"].astype(dt)
+    return _savepoint(x, "mlp_pre_act")
 
 
 def _dropout(x, rate, rng, deterministic):
@@ -169,6 +180,7 @@ def _dropout(x, rate, rng, deterministic):
     return x * keep / (1.0 - rate)
 
 
+@jax.named_scope("block")
 def transformer_layer(
     layer_params: dict,
     cfg,
@@ -232,6 +244,7 @@ def transformer_layer(
     return out, new_cache
 
 
+@jax.named_scope("layers")
 def transformer_stack(
     layer_params: dict,
     cfg,

@@ -472,8 +472,9 @@ def _xla_paged_reference(q, k_pages, v_pages, page_table, starts,
     page_size = k_pages.shape[1]
     max_pages = page_table.shape[1]
     T = max_pages * page_size
-    k = k_pages[page_table].reshape(nc, T, g, d).transpose(0, 2, 1, 3)
-    v = v_pages[page_table].reshape(nc, T, g, d).transpose(0, 2, 1, 3)
+    with jax.named_scope("page_gather"):
+        k = k_pages[page_table].reshape(nc, T, g, d).transpose(0, 2, 1, 3)
+        v = v_pages[page_table].reshape(nc, T, g, d).transpose(0, 2, 1, 3)
     tok = jnp.arange(C * qpk) // qpk  # (rows,)
     row_pos = starts[:, None] + tok[None, :]  # (nc, rows)
     row_valid = tok[None, :] < chunk_lens[:, None]  # (nc, rows)
@@ -488,6 +489,7 @@ def _xla_paged_reference(q, k_pages, v_pages, page_table, starts,
                        row_lo=row_lo)
 
 
+@jax.named_scope("kv_write")
 def scatter_chunk_kv(k_new, v_new, k_pages, v_pages, page_table, starts,
                      chunk_lens, k_scales=None, v_scales=None):
     """Write a chunk's K/V rows into its slot's pages: token t (valid,

@@ -97,6 +97,7 @@ def init_optimizer_state(params, tcfg: TrainConfig) -> OptimizerState:
     raise ValueError(f"unknown optimizer {tcfg.optimizer}")
 
 
+@jax.named_scope("optimizer")
 def optimizer_step(
     params,
     grads,
@@ -121,79 +122,81 @@ def optimizer_step(
     scale; stats gains "loss_scale".
     """
     wd = tcfg.weight_decay if weight_decay is None else weight_decay
-    grads = _tree_cast(grads, jnp.float32)
+    with jax.named_scope("clip"):
+        grads = _tree_cast(grads, jnp.float32)
 
-    grad_norm = global_grad_norm(grads)
-    finite = jnp.isfinite(grad_norm)
-    if found_inf is not None:
-        # external skip gate (the loss watchdog's spike/NaN flag): skips
-        # the UPDATE only. It must not feed the scaler below — a
-        # finite-gradient loss spike is not an fp16 overflow, and
-        # backing the scale off for it would ratchet toward underflow.
-        finite = finite & ~found_inf
+        grad_norm = global_grad_norm(grads)
+        finite = jnp.isfinite(grad_norm)
+        if found_inf is not None:
+            # external skip gate (the loss watchdog's spike/NaN flag): skips
+            # the UPDATE only. It must not feed the scaler below — a
+            # finite-gradient loss spike is not an fp16 overflow, and
+            # backing the scale off for it would ratchet toward underflow.
+            finite = finite & ~found_inf
 
-    new_scaler_state = state.scaler
-    if scaler is not None:
-        # the scaler reacts to GENUINE overflow (non-finite grads) only
-        new_scaler_state = scaler.update(state.scaler,
-                                         ~jnp.isfinite(grad_norm))
+        new_scaler_state = state.scaler
+        if scaler is not None:
+            # the scaler reacts to GENUINE overflow (non-finite grads) only
+            new_scaler_state = scaler.update(state.scaler,
+                                             ~jnp.isfinite(grad_norm))
 
-    # clip (ref: clip_grads.py:83-107)
-    if tcfg.clip_grad > 0.0:
-        clip_coeff = jnp.minimum(tcfg.clip_grad / (grad_norm + 1e-6), 1.0)
-        grads = jax.tree.map(lambda g: g * clip_coeff, grads)
+        # clip (ref: clip_grads.py:83-107)
+        if tcfg.clip_grad > 0.0:
+            clip_coeff = jnp.minimum(tcfg.clip_grad / (grad_norm + 1e-6), 1.0)
+            grads = jax.tree.map(lambda g: g * clip_coeff, grads)
 
     step = state.step + 1
+    # `optimizer/adam`, or `optimizer/sgd`
+    with jax.named_scope(tcfg.optimizer):
+        if tcfg.optimizer == "adam":
+            b1, b2, eps = tcfg.adam_beta1, tcfg.adam_beta2, tcfg.adam_eps
+            bc1 = 1.0 - b1 ** step.astype(jnp.float32)
+            bc2 = 1.0 - b2 ** step.astype(jnp.float32)
 
-    if tcfg.optimizer == "adam":
-        b1, b2, eps = tcfg.adam_beta1, tcfg.adam_beta2, tcfg.adam_eps
-        bc1 = 1.0 - b1 ** step.astype(jnp.float32)
-        bc2 = 1.0 - b2 ** step.astype(jnp.float32)
+            new_m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, state.m, grads)
+            new_v = jax.tree.map(
+                lambda v, g: b2 * v + (1 - b2) * jnp.square(g), state.v, grads
+            )
 
-        new_m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, state.m, grads)
-        new_v = jax.tree.map(
-            lambda v, g: b2 * v + (1 - b2) * jnp.square(g), state.v, grads
+            def upd(p, m, v):
+                # adamw: decoupled weight decay (apex FusedAdam adam_w_mode);
+                # 1D params (norm scales, biases) are never decayed
+                # (ref: get_param_groups optimizer/__init__.py:28-53)
+                u = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
+                p32 = p.astype(jnp.float32)
+                wd_p = wd if p.ndim >= 2 else 0.0
+                return (p32 - lr * (u + wd_p * p32)).astype(p.dtype)
+
+            new_params = jax.tree.map(upd, params, new_m, new_v)
+            new_state = OptimizerState(step=step, m=new_m, v=new_v,
+                                       scaler=state.scaler)
+        else:  # sgd with momentum
+            mom = tcfg.sgd_momentum
+
+            def upd_buf(b, g, p):
+                wd_p = wd if p.ndim >= 2 else 0.0
+                return mom * b + g + wd_p * p.astype(jnp.float32)
+
+            new_m = jax.tree.map(upd_buf, state.m, grads, params)
+            new_params = jax.tree.map(
+                lambda p, b: (p.astype(jnp.float32) - lr * b).astype(p.dtype),
+                params,
+                new_m,
+            )
+            new_state = OptimizerState(step=step, m=new_m, v=state.v,
+                                       scaler=state.scaler)
+
+        # skipped iteration on inf/nan (ref: optimizer.py:418-432)
+        select = lambda new, old: jax.tree.map(
+            lambda n, o: jnp.where(finite, n, o), new, old
         )
-
-        def upd(p, m, v):
-            # adamw: decoupled weight decay (apex FusedAdam adam_w_mode);
-            # 1D params (norm scales, biases) are never decayed
-            # (ref: get_param_groups optimizer/__init__.py:28-53)
-            u = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
-            p32 = p.astype(jnp.float32)
-            wd_p = wd if p.ndim >= 2 else 0.0
-            return (p32 - lr * (u + wd_p * p32)).astype(p.dtype)
-
-        new_params = jax.tree.map(upd, params, new_m, new_v)
-        new_state = OptimizerState(step=step, m=new_m, v=new_v,
-                                   scaler=state.scaler)
-    else:  # sgd with momentum
-        mom = tcfg.sgd_momentum
-
-        def upd_buf(b, g, p):
-            wd_p = wd if p.ndim >= 2 else 0.0
-            return mom * b + g + wd_p * p.astype(jnp.float32)
-
-        new_m = jax.tree.map(upd_buf, state.m, grads, params)
-        new_params = jax.tree.map(
-            lambda p, b: (p.astype(jnp.float32) - lr * b).astype(p.dtype),
-            params,
-            new_m,
+        new_params = select(new_params, params)
+        new_state = OptimizerState(
+            step=jnp.where(finite, step, state.step),
+            m=select(new_state.m, state.m),
+            v=select(new_state.v, state.v) if state.v is not None else None,
+            scaler=new_scaler_state,
         )
-        new_state = OptimizerState(step=step, m=new_m, v=state.v,
-                                   scaler=state.scaler)
-
-    # skipped iteration on inf/nan (ref: optimizer.py:418-432)
-    select = lambda new, old: jax.tree.map(
-        lambda n, o: jnp.where(finite, n, o), new, old
-    )
-    new_params = select(new_params, params)
-    new_state = OptimizerState(
-        step=jnp.where(finite, step, state.step),
-        m=select(new_state.m, state.m),
-        v=select(new_state.v, state.v) if state.v is not None else None,
-        scaler=new_scaler_state,
-    )
 
     stats = {
         "grad_norm": grad_norm,

@@ -80,6 +80,7 @@ def _ce_shard(logits, targets, vocab_per_shard, label_smoothing):
     return loss
 
 
+@jax.named_scope("vocab_parallel")
 def vocab_parallel_cross_entropy(
     logits: jnp.ndarray,
     targets: jnp.ndarray,

@@ -4,8 +4,9 @@ metrics, and postmortem recording across the train and serve hot paths.
 Three pieces, one contract:
 
 - `trace.SpanTracer` — nestable host-side spans with request-id /
-  train-step correlation, exported as Chrome trace-event JSON
-  (Perfetto);
+  train-step correlation: always a `jax.profiler` annotation (the
+  span lands in any profiler capture, on the device's clock), and with
+  a --trace_dir also Chrome trace-event JSON (Perfetto);
 - `recorder.FlightRecorder` — a bounded ring of structured events +
   counter snapshots, auto-dumped to a JSON artifact on engine poison,
   watchdog rollback and SIGTERM emergency save;
@@ -52,11 +53,10 @@ from megatron_llm_tpu.telemetry.prometheus import (
 )
 from megatron_llm_tpu.telemetry.recorder import FlightRecorder
 from megatron_llm_tpu.telemetry.sentinel import PerfSentinel, RobustWindow
-from megatron_llm_tpu.telemetry.trace import NULL_TRACER, SpanTracer
+from megatron_llm_tpu.telemetry.trace import SpanTracer
 
 __all__ = [
     "SpanTracer",
-    "NULL_TRACER",
     "FlightRecorder",
     "Histogram",
     "DEFAULT_LATENCY_BUCKETS_MS",

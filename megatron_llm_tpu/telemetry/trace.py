@@ -1,13 +1,26 @@
-"""Host-side span tracer: Chrome trace-event JSON for Perfetto (ISSUE 13).
+"""Host-side span tracer: one emitter, two sinks (ISSUE 13, ISSUE 26).
 
 The serving engine and the trainer are host-driven schedulers around
 jitted dispatches; diagnosing a stall ("why did request 41's TTFT blow
 up at 02:13?") needs the host timeline — queue wait, admission, chunk
-prefill, decode-scan dispatch, COW copies, checkpoint stalls — not the
-device profile (that is what `jax.profiler` and the POST /profile hook
-capture). This tracer records nestable wall-clock spans into a bounded
-ring and exports them as Chrome trace-event JSON (the `{"traceEvents":
-[...]}` form), loadable in Perfetto / chrome://tracing.
+prefill, decode-scan dispatch, COW copies, checkpoint stalls — laid
+against the device's. Every live span (`span`, `step_span`) therefore
+goes to two sinks:
+
+- ALWAYS a `jax.profiler.TraceAnnotation`: while a profiler capture
+  runs (`benchmark/run.py --trace 1`, POST /profile,
+  --profile_step_range) the span lands on the host plane of the
+  `.xplane.pb`, on its thread's line, on the same clock as the device
+  operations, its args as the event's stats. With no capture running
+  the annotation is one TraceMe activity check.
+- with `enabled=True` (a --trace_dir): also a bounded ring of Chrome
+  trace events, exported as the `{"traceEvents": [...]}` JSON Perfetto
+  and chrome://tracing load.
+
+`complete` (a span only known after the fact, such as `queue_wait`) and
+`instant` go to the ring only: a profiler annotation cannot be written
+retroactively. A span keeps its own two clock reads (`t0`, `t1`,
+`seconds`), so the emitter's counters are summed from the same reads.
 
 Correlation model (docs/GUIDE.md "Observability"): every span carries
 its emitter's args — engine spans the request id (`rid`) and round
@@ -22,9 +35,9 @@ value, so telemetry-on jitted steps are bitwise-identical to
 telemetry-off by construction, and `analysis/lint.py` lists the emit
 methods in GR006 HOT_PATHS so a device sync can never creep in.
 
-A disabled tracer (`enabled=False`, the default everywhere no
---trace_dir is given) short-circuits every emitter to a shared no-op
-span: the off cost is one attribute check per site.
+A disabled tracer (`enabled=False`, what every component builds when no
+--trace_dir is given) keeps no ring: a span then costs its annotation
+and its two clock reads (measured on the chip's host: PERF.md).
 """
 
 from __future__ import annotations
@@ -36,44 +49,47 @@ import time
 from collections import deque
 from typing import Optional
 
-__all__ = ["SpanTracer", "NULL_TRACER"]
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-
-class _NullSpan:
-    """Shared no-op context manager: the telemetry-off fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+__all__ = ["SpanTracer"]
 
 
 class _Span:
-    """One live span: records a complete ("ph": "X") event on exit."""
+    """One live span: a profiler annotation for its lifetime and, with
+    the ring on, a complete ("ph": "X") event on exit."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_ann", "t0", "t1")
 
-    def __init__(self, tracer: "SpanTracer", name: str, args: dict):
+    def __init__(self, tracer: "SpanTracer", name: str, args: dict, ann):
         self._tracer = tracer
         self._name = name
         self._args = args
-        self._t0 = time.perf_counter()
+        self._ann = ann
 
     def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        # pure host bookkeeping (GR006 HOT_PATHS): one clock read and
-        # one ring append — never a device value
-        self._tracer.complete(self._name, self._t0, time.perf_counter(),
-                              **self._args)
+        # pure host bookkeeping (GR006 HOT_PATHS): one clock read, the
+        # annotation's end and one ring append — never a device value
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._tracer.enabled:
+            self._tracer.complete(self._name, self.t0, self.t1,
+                                  **self._args)
         return False
+
+    def note(self, **kv) -> None:
+        """Args only known once the span is open (how many requests an
+        admission took, how many tokens a round booked). They reach the
+        ring; the annotation took its arguments when it opened."""
+        self._args.update(kv)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
 
 class SpanTracer:
@@ -110,12 +126,19 @@ class SpanTracer:
     # -- emitters (GR006 HOT_PATHS: host bookkeeping only) -----------------
 
     def span(self, name: str, **args):
-        """Context manager measuring one complete span."""
-        if not self.enabled:
-            return _NULL_SPAN
+        """Context manager around one phase of a round or a step."""
+        return self._live(TraceAnnotation, name, args)
+
+    def step_span(self, name: str, step_num: int, **args):
+        """`span` for one whole training step: the profiler's step
+        marker (`StepTraceAnnotation`), which its tools group by."""
+        args["step_num"] = step_num
+        return self._live(StepTraceAnnotation, name, args)
+
+    def _live(self, annotation, name: str, args: dict) -> _Span:
         if self._context:
             args = {**self._context, **args}
-        return _Span(self, name, args)
+        return _Span(self, name, args, annotation(name, **args))
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration marker event (ph "i")."""
@@ -141,13 +164,9 @@ class SpanTracer:
 
     def set_context(self, **kv) -> None:
         """Merge ambient correlation keys into subsequent events' args
-        (e.g. `set_context(step=it)` each trainer iteration). No-op
-        when disabled: NULL_TRACER is a shared module singleton, and
-        every telemetry-off component calls this per step — mutating
-        one global dict from all of them would be cross-component
-        state for nothing."""
-        if not self.enabled:
-            return
+        (e.g. `set_context(step=it)` each trainer iteration, the
+        engine's `replica`). Every component owns its tracer, ring or
+        no ring, so the keys reach its annotations either way."""
         self._context.update(kv)
 
     # -- internals ---------------------------------------------------------
@@ -209,8 +228,3 @@ class SpanTracer:
             json.dump(self.to_chrome_trace(), fh)
         os.replace(tmp, path)
         return path
-
-
-# the shared disabled tracer: every component's default when no
-# --trace_dir is configured (one attribute check per emit site)
-NULL_TRACER = SpanTracer(capacity=1, enabled=False)

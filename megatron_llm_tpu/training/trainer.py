@@ -141,9 +141,11 @@ class Trainer:
         self.reset_position_ids = reset_position_ids
         self.reset_attention_mask = reset_attention_mask
         self.eod_mask_loss = eod_mask_loss
-        # flight-recorder telemetry (ISSUE 13): the tracer is enabled
-        # only with --trace_dir (Chrome trace JSON exported at the end
-        # of train(); the named timers double as its spans); the flight
+        # flight-recorder telemetry (ISSUE 13, 26): every span is a
+        # profiler annotation (it lands in any jax.profiler capture, on
+        # the device's clock); with --trace_dir the spans also fill the
+        # ring (Chrome trace JSON exported at the end of train(); the
+        # named timers ride it as complete events); the flight
         # recorder is ALWAYS on — a bounded ring of per-step events +
         # watchdog/checkpoint lifecycle, auto-dumped on watchdog
         # rollback and the SIGTERM emergency save (into
@@ -151,7 +153,6 @@ class Trainer:
         # host bookkeeping only: telemetry-on steps are bitwise
         # telemetry-off (tests/test_telemetry.py pins it).
         from megatron_llm_tpu.telemetry import (
-            NULL_TRACER,
             FlightRecorder,
             GoodputLedger,
             Histogram,
@@ -160,8 +161,8 @@ class Trainer:
             detect_chip,
         )
 
-        self.tracer = (SpanTracer(enabled=True) if tcfg.trace_dir
-                       else NULL_TRACER)
+        self.tracer = SpanTracer(enabled=bool(tcfg.trace_dir))
+        self._step_t0 = 0.0  # when the last train_step's span opened
         self.recorder = FlightRecorder(tcfg.flight_recorder_size)
         self._step_ms_hist = Histogram(
             "train_step_ms", help_text="wall ms per optimizer step "
@@ -527,34 +528,48 @@ class Trainer:
     def train_step(self, state: TrainState, text: np.ndarray, dropout_rng=None):
         """One optimizer step over a global batch 'text'
         (num_micro, mbs*dp, seq+1) array, or a dict of such arrays when a
-        batch_builder is installed (ref: train_step training.py:391-450)."""
-        if self.batch_builder is not None:
-            batch = self.batch_builder(text)
-            num_micro = jax.tree.leaves(batch)[0].shape[0]
-        else:
-            num_micro = text.shape[0]
-            batch = get_batch(
-                text, self.eod_token, self.reset_position_ids,
-                self.reset_attention_mask, self.eod_mask_loss,
-                # under cp the dense mask would gather the full sequence;
-                # ship the O(s) doc-start form through ring attention
-                packed_doc_starts=self.ctx is not None and self.ctx.cp > 1,
-            )
-            if (self.pcfg.pipeline_parallel_size > 1
-                    and "attention_mask" in batch):
-                raise ValueError(
-                    "pp>1 training does not support "
-                    "--reset_attention_mask (the pipelined loss has no "
-                    "attention-mask path); drop the flag or train with "
-                    "pp=1"
-                )
-        lr, wd = self.scheduler.get_lr(), self.scheduler.get_wd()
-        if self.ctx is not None and jax.process_count() > 1:
-            # per-process rows -> global arrays sharded over `data`
-            # (ref analogue: each rank's sampler loads only its chunk)
-            from megatron_llm_tpu.parallel.multihost import globalize_batch
+        batch_builder is installed (ref: train_step training.py:391-450).
+        Runs under the profiler's step marker `train`, with the children
+        `train.get_batch` and `train.dispatch`."""
+        with self.tracer.step_span("train",
+                                   step_num=state.iteration + 1) as sp:
+            self._step_t0 = sp.t0
+            return self._train_step(state, text, dropout_rng)
 
-            batch = globalize_batch(batch, self.ctx)
+    def _train_step(self, state: TrainState, text, dropout_rng):
+        """`train_step`'s body, inside the step's span."""
+        with self.tracer.span("train.get_batch"):
+            if self.batch_builder is not None:
+                batch = self.batch_builder(text)
+                num_micro = jax.tree.leaves(batch)[0].shape[0]
+            else:
+                num_micro = text.shape[0]
+                batch = get_batch(
+                    text, self.eod_token, self.reset_position_ids,
+                    self.reset_attention_mask, self.eod_mask_loss,
+                    # under cp the dense mask would gather the full
+                    # sequence; ship the O(s) doc-start form through
+                    # ring attention
+                    packed_doc_starts=(self.ctx is not None
+                                       and self.ctx.cp > 1),
+                )
+                if (self.pcfg.pipeline_parallel_size > 1
+                        and "attention_mask" in batch):
+                    raise ValueError(
+                        "pp>1 training does not support "
+                        "--reset_attention_mask (the pipelined loss has no "
+                        "attention-mask path); drop the flag or train with "
+                        "pp=1"
+                    )
+            if self.ctx is not None and jax.process_count() > 1:
+                # per-process rows -> global arrays sharded over `data`
+                # (ref analogue: each rank's sampler loads only its chunk)
+                from megatron_llm_tpu.parallel.multihost import (
+                    globalize_batch,
+                )
+
+                batch = globalize_batch(batch, self.ctx)
+        lr, wd = self.scheduler.get_lr(), self.scheduler.get_wd()
         # a fresh mint means this call pays trace+compile: the goodput
         # ledger books its wall under "compile", and (registry on) the
         # mint's cost is captured right after the call below
@@ -570,10 +585,11 @@ class Trainer:
         # has history (or with spike detection off) — NaN/inf losses
         # still skip. Always passed, so there is ONE trace either way.
         spike_thr = jnp.float32(self.watchdog.threshold())
-        params, opt_state, stats = step_fn(
-            state.params, state.opt_state, batch,
-            jnp.float32(lr), jnp.float32(wd), dropout_rng, spike_thr,
-        )
+        with self.tracer.span("train.dispatch", minted=minted):
+            params, opt_state, stats = step_fn(
+                state.params, state.opt_state, batch,
+                jnp.float32(lr), jnp.float32(wd), dropout_rng, spike_thr,
+            )
         state.params = params
         state.opt_state = opt_state
         if minted and self.costs is not None:
@@ -895,28 +911,30 @@ class Trainer:
         if not self.tcfg.save:
             return
         mgr = self._get_ckpt_manager()
-        t_save = time.perf_counter()
-        self.timers("save-checkpoint").start()
-        mgr.save(
-            state.iteration, state.params,
-            None if self.tcfg.no_save_optim else state.opt_state,
-            self.cfg, self.scheduler.state_dict(),
-            state.consumed_train_samples,
-            rng_key=self._dropout_base_rng,
-        )
-        self.timers("save-checkpoint").stop()
-        self.timers.gauge("ckpt_blocked_ms", round(mgr.last_blocked_ms, 2))
-        # the save's loop stall on the trace timeline, step-correlated
-        # (the save-checkpoint timer span carries the full dispatch)
-        self.tracer.instant("ckpt_blocked",
-                            blocked_ms=round(mgr.last_blocked_ms, 3))
-        if blocking:
-            mgr.wait_until_finished()
+        with self.tracer.span("train.checkpoint",
+                              blocking=blocking) as sp_save:
+            self.timers("save-checkpoint").start()
+            mgr.save(
+                state.iteration, state.params,
+                None if self.tcfg.no_save_optim else state.opt_state,
+                self.cfg, self.scheduler.state_dict(),
+                state.consumed_train_samples,
+                rng_key=self._dropout_base_rng,
+            )
+            self.timers("save-checkpoint").stop()
+            self.timers.gauge("ckpt_blocked_ms",
+                              round(mgr.last_blocked_ms, 2))
+            # the save's loop stall on the trace timeline,
+            # step-correlated (the save-checkpoint timer span carries
+            # the full dispatch)
+            self.tracer.instant("ckpt_blocked",
+                                blocked_ms=round(mgr.last_blocked_ms, 3))
+            if blocking:
+                mgr.wait_until_finished()
         if self.ledger.started:
             # goodput: the loop's whole save-side stall — dispatch,
             # previous-save tail, and (blocking) the commit wait
-            self.ledger.note("checkpoint",
-                             time.perf_counter() - t_save)
+            self.ledger.note("checkpoint", sp_save.seconds)
         print(f"saved checkpoint at iteration {state.iteration} to "
               f"{self.tcfg.save}"
               f"{' (committed)' if blocking else ' (async)'}", flush=True)
@@ -1023,16 +1041,15 @@ class Trainer:
             # the rid/step correlation model (ISSUE 13)
             self.tracer.set_context(step=state.iteration + 1)
             self.timers("batch-generator").start()
-            t_fetch = time.perf_counter()
             try:
-                text = next(data_iter)
+                with self.tracer.span("train.data_wait") as sp_data:
+                    text = next(data_iter)
             except StopIteration:
                 print("data iterator exhausted", flush=True)
                 break
             finally:
                 self.timers("batch-generator").stop()
-                self.ledger.note("data_wait",
-                                 time.perf_counter() - t_fetch)
+                self.ledger.note("data_wait", sp_data.seconds)
             step_rng = None
             if dropout_rng is not None:
                 step_rng = jax.random.fold_in(dropout_rng, state.iteration)
@@ -1045,16 +1062,19 @@ class Trainer:
                     tcfg.profile_dir or tcfg.tensorboard_dir or "./profile"
                 )
                 self._trace_active = True
-            t0 = time.time()
             # the whole fused fwd+bwd+optimizer dispatch — the reference's
             # forward-backward/optimizer timer pair collapses into one
             # jitted call here (training.py:431-448)
             self.timers("train-step").start()
             stats = self.train_step(state, text, step_rng)
-            loss_val = float(stats["loss"])  # host sync: the step barrier
+            with self.tracer.span("train.loss_fetch") as sp_fetch:
+                # host sync: the step barrier
+                loss_val = float(stats["loss"])
             self.timers("train-step").stop()
             stats["loss"] = loss_val
-            elapsed = time.time() - t0
+            # from the `train` span's opening read to the fence's end:
+            # the ledger's bucket and the spans share their clock reads
+            elapsed = sp_fetch.t1 - self._step_t0
             # loss watchdog: a bad step (NaN/inf or >k-sigma spike) was
             # already SKIPPED on device by the spike-threshold gate; the
             # host side counts the streak and escalates to a rollback
@@ -1120,7 +1140,8 @@ class Trainer:
                 and self.valid_data_iterator is not None
                 and state.iteration % tcfg.eval_interval == 0
             ):
-                val = self.evaluate(state)
+                with self.tracer.span("train.eval"):
+                    val = self.evaluate(state)
                 ppl = float(np.exp(min(20.0, val)))
                 print(f"validation loss at iteration {state.iteration}: "
                       f"{val:.6E} | ppl: {ppl:.4f}", flush=True)

@@ -45,7 +45,6 @@ import pytest
 from megatron_llm_tpu.config import ParallelConfig, TrainConfig, tiny_config
 from megatron_llm_tpu.models import LlamaModel
 from megatron_llm_tpu.telemetry import (
-    NULL_TRACER,
     FlightRecorder,
     Histogram,
     SpanTracer,
@@ -102,15 +101,32 @@ class TestSpanTracer:
         # the ring keeps the NEWEST events (a flight record, not a log)
         assert tr.events()[-1]["args"]["i"] == 499
 
-    def test_disabled_tracer_is_shared_noop(self):
-        assert not NULL_TRACER.enabled
-        span = NULL_TRACER.span("x", rid=1)
-        assert span is NULL_TRACER.span("y")  # one shared object
-        with span:
+    def test_disabled_tracer_keeps_no_ring_but_times_its_spans(self):
+        """No --trace_dir: nothing reaches the ring, but a span is still
+        a profiler annotation with its own two clock reads (the
+        emitter's counters are summed from them)."""
+        off = SpanTracer(enabled=False)
+        off.set_context(replica=3)
+        with off.span("x", rid=1) as sp:
+            sp.note(booked=2)
+        assert sp.t1 >= sp.t0 and sp.seconds == sp.t1 - sp.t0
+        with off.step_span("s", step_num=4):
             pass
-        NULL_TRACER.instant("x")
-        NULL_TRACER.complete("x", 0.0, 1.0)
-        assert NULL_TRACER.events() == []
+        off.instant("x")
+        off.complete("x", 0.0, 1.0)
+        assert off.events() == []
+
+    def test_note_and_step_span_reach_the_ring(self):
+        tr = SpanTracer()
+        with tr.step_span("train", step_num=7):
+            with tr.span("train.dispatch", minted=False) as sp:
+                sp.note(late=1)
+        evs = {e["name"]: e for e in tr.events()}
+        assert evs["train"]["args"] == {"step_num": 7}
+        assert evs["train.dispatch"]["args"] == {"minted": False, "late": 1}
+        outer, inner = evs["train"], evs["train.dispatch"]
+        assert outer["ts"] <= inner["ts"]
+        assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
 
     def test_chrome_trace_export_valid(self, tmp_path):
         tr = SpanTracer()
@@ -147,7 +163,8 @@ class TestSpanTracer:
                    for e in evs)
 
     def test_export_disabled_returns_none(self, tmp_path):
-        assert NULL_TRACER.export(str(tmp_path / "x.json")) is None
+        assert SpanTracer(enabled=False).export(
+            str(tmp_path / "x.json")) is None
         assert not (tmp_path / "x.json").exists()
 
 
@@ -297,6 +314,15 @@ LEGACY_METRICS_KEYS = [
     "serve_timed_out", "serve_cancelled", "serve_steps", "serve_tok_s",
     "serve_prefill_tokens", "serve_ttft_p50_ms", "serve_ttft_p95_ms",
     "serve_decode_p95_ms",
+    # ISSUE 26: always on, and LAST in counters() whatever feature
+    # groups come before them, so everything pinned above stays a
+    # byte-compatible prefix
+    "serve_rows_computed", "serve_rows_useful",
+    "serve_rounds_mixed", "serve_rounds_decode", "serve_rounds_spec",
+    "serve_round_ms_mixed", "serve_round_ms_decode", "serve_round_ms_spec",
+    "serve_host_ms_schedule", "serve_host_ms_build_inputs",
+    "serve_host_ms_dispatch", "serve_host_ms_fetch", "serve_host_ms_book",
+    "serve_host_ms_wait",
 ]
 
 
@@ -326,7 +352,7 @@ class TestEngineTelemetry:
         assert toks_a == toks_a2 and toks_b == toks_b2
         assert lp_a == lp_a2  # float-exact
         assert len(on.tracer.events()) > 0
-        assert off.tracer.events() == []  # NULL tracer
+        assert off.tracer.events() == []  # no trace_dir, no ring
 
     def test_spans_and_events_correlate_by_rid(self, engines):
         on, _, _ = engines
@@ -676,6 +702,281 @@ class TestTrainerTelemetry:
         assert ("watchdog_bad", 6) in kinds
         assert ("watchdog_bad", 7) in kinds
         assert ("watchdog_rollback", 7) in kinds
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 26: the spans on the profiler's clock, the scopes on the device,
+# the counters of a round
+# ---------------------------------------------------------------------------
+
+ROUND_PHASES = ["engine.schedule", "engine.build_inputs", "engine.dispatch",
+                "engine.fetch", "engine.book"]
+# scripted run: prompts of 11 and 5 tokens, 5 tokens out each, 2 slots,
+# chunk 8, horizon 4. Mixed rounds: 8 of prompt A (width 8), its last 3
+# (width 4), the 5 of B beside A's first decode token (width 8); then a
+# scan of 4 over both slots and one last step for B alone.
+SCRIPT = dict(prompts=[list(range(5, 16)), list(range(40, 45))], out=5,
+              rows_computed=2 * 8 + 2 * 4 + 2 * 8 + 2 * 4 + 2 * 1,
+              rows_useful=11 + 5 + 5 + 5, mixed=3, decode=2)
+
+
+def _span_reduce():
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import span_reduce
+
+    return span_reduce
+
+
+def _children(spans):
+    kids = {}
+    for i, sp in enumerate(spans):
+        kids.setdefault(sp["parent"], []).append(i)
+    return kids
+
+
+def _ring_as_trace(tracer):
+    """The ring's complete events in the reducer's trace form, so one
+    function reads the nesting of both sinks."""
+    lines = {}
+    for e in tracer.events():
+        if e["ph"] == "X":
+            lines.setdefault(e["tid"], []).append(
+                [e["name"], e["ts"] * 1e3, max(e["dur"], 1) * 1e3,
+                 e["args"]])
+    return {"planes": [{"name": "/host:CPU", "lines": [
+        {"name": f"tid-{tid}", "events": evs}
+        for tid, evs in lines.items()]}]}
+
+
+@pytest.fixture(scope="module")
+def captured(tiny_model, tmp_path_factory):
+    """One CPU `jax.profiler` capture, NO trace_dir anywhere: a started
+    engine serves the scripted run, then a trainer takes three steps."""
+    import glob as _glob
+
+    import jax.profiler as jp
+
+    sr = _span_reduce()
+    tmp = tmp_path_factory.mktemp("capture")
+    eng = _engine(tiny_model, prefill_chunk_tokens=8, step_horizon=4)
+    eng.warmup()
+    cfg = tiny_config(seq_length=16, max_position_embeddings=16,
+                      compute_dtype=jnp.float32, params_dtype=jnp.float32)
+    opts = jp.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jp.start_trace(str(tmp), profiler_options=opts)
+    try:
+        eng.start()
+        reqs = [eng.submit(p, SCRIPT["out"], top_k=1)
+                for p in SCRIPT["prompts"]]
+        tokens = [r.result(60)[0] for r in reqs]
+        import time as _time
+
+        _time.sleep(0.12)  # the idle loop waits for work at least once
+        eng.stop()
+        trainer, _ = _train(cfg, 3)
+    finally:
+        jp.stop_trace()
+    path = _glob.glob(str(tmp / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))[0]
+    spans = sr.program_spans(sr.load(path))
+    return {"spans": spans, "tokens": tokens, "rids": [r.rid for r in reqs],
+            "engine": eng, "trainer": trainer, "cfg": cfg}
+
+
+class TestSpansInTheProfilersTrace:
+    def test_engine_rounds_nest_as_the_table_says(self, captured):
+        spans, kids = captured["spans"], _children(captured["spans"])
+        rounds = [i for i, sp in enumerate(spans)
+                  if sp["name"] == "engine.round"]
+        assert len(rounds) == SCRIPT["mixed"] + SCRIPT["decode"]
+        kinds, rids = [], set()
+        for n, i in enumerate(rounds):
+            assert spans[i]["line"] == "engine-serve"
+            assert spans[i]["parent"] is None
+            assert spans[i]["args"]["round"] == \
+                spans[rounds[0]]["args"]["round"] + n
+            assert [spans[k]["name"] for k in kids[i]] == ROUND_PHASES
+            disp = spans[kids[i][2]]["args"]
+            kinds.append(disp["kind"])
+            assert disp["fn"] == {"mixed": "mixed_step",
+                                  "decode": "decode_scan"}[disp["kind"]]
+            assert disp["width"] >= 1 and "decode_slots" in disp
+            if disp["kind"] == "mixed":
+                rids.add(disp["rid"])
+            assert spans[kids[i][1]]["args"]["transfers"] >= 11
+            assert spans[i]["self_ns"] >= 0
+        assert kinds == ["mixed"] * 3 + ["decode"] * 2
+        assert rids == set(captured["rids"])
+        waits = [sp for sp in spans if sp["name"] == "engine.wait_for_work"]
+        assert waits and all(w["line"] == "engine-serve"
+                             and w["parent"] is None for w in waits)
+        assert {"live_slots", "queue_depth"} <= set(waits[0]["args"])
+
+    def test_train_steps_nest_as_the_table_says(self, captured):
+        spans, kids = captured["spans"], _children(captured["spans"])
+        steps = [i for i, sp in enumerate(spans) if sp["name"] == "train"]
+        assert [spans[i]["args"]["step_num"] for i in steps] == [1, 2, 3]
+        for i in steps:
+            assert [spans[k]["name"] for k in kids[i]] == \
+                ["train.get_batch", "train.dispatch"]
+            assert spans[i]["args"]["step"] == spans[i]["args"]["step_num"]
+        line = spans[steps[0]]["line"]
+        for name in ("train.data_wait", "train.loss_fetch"):
+            got = [sp for sp in spans if sp["name"] == name]
+            assert len(got) == 3, name
+            assert all(sp["parent"] is None and sp["line"] == line
+                       for sp in got)
+        assert not captured["trainer"].tracer.events()  # no ring was on
+
+    def test_ring_agrees_in_names_and_nesting(self, captured, tiny_model,
+                                              tmp_path):
+        """The same scripted run with a trace_dir and no capture: the
+        ring holds the same spans, nested the same way, and the tokens
+        are the same to the bit."""
+        sr = _span_reduce()
+        eng = _engine(tiny_model, tmp=tmp_path, prefill_chunk_tokens=8,
+                      step_horizon=4)
+        reqs = [eng.submit(p, SCRIPT["out"], top_k=1)
+                for p in SCRIPT["prompts"]]
+        eng.drain()
+        assert [r.result(5)[0] for r in reqs] == captured["tokens"]
+        ring = sr.program_spans(_ring_as_trace(eng.tracer))
+        kids = _children(ring)
+
+        def shape(spans, kids):
+            return [(spans[i]["name"], [spans[k]["name"] for k in kids[i]])
+                    for i in kids[None]
+                    if spans[i]["name"] == "engine.round"]
+
+        assert shape(ring, kids) == shape(captured["spans"],
+                                          _children(captured["spans"]))
+        book = [sp for sp in ring if sp["name"] == "engine.book"]
+        assert sum(sp["args"]["booked"] for sp in book) == 2 * SCRIPT["out"]
+        assert sum(sp["args"]["retired"] for sp in book) == 2
+        tr_on, _ = _train(captured["cfg"], 3, trace_dir=str(tmp_path))
+        ring = sr.program_spans(_ring_as_trace(tr_on.tracer))
+        kids = _children(ring)
+        steps = [i for i in kids[None] if ring[i]["name"] == "train"]
+        assert [[ring[k]["name"] for k in kids[i]] for i in steps] == \
+            [["train.get_batch", "train.dispatch"]] * 3
+        on = [e["loss"] for e in tr_on.recorder.snapshot()["events"]
+              if e["kind"] == "step"]
+        under_capture = [e["loss"] for e in
+                         captured["trainer"].recorder.snapshot()["events"]
+                         if e["kind"] == "step"]
+        assert on == under_capture  # float-exact
+
+
+class TestRoundCounters:
+    def test_counters_add_up_on_the_scripted_run(self, tiny_model):
+        eng = _engine(tiny_model, prefill_chunk_tokens=8, step_horizon=4)
+        reqs = [eng.submit(p, SCRIPT["out"], top_k=1)
+                for p in SCRIPT["prompts"]]
+        eng.drain()
+        for r in reqs:
+            r.result(5)
+        c = eng.counters()
+        assert c["serve_rows_computed"] == SCRIPT["rows_computed"]
+        assert c["serve_rows_useful"] == SCRIPT["rows_useful"]
+        assert (c["serve_rounds_mixed"], c["serve_rounds_decode"],
+                c["serve_rounds_spec"]) == (SCRIPT["mixed"],
+                                            SCRIPT["decode"], 0)
+        log = list(eng._round_log)
+        assert len(log) == SCRIPT["mixed"] + SCRIPT["decode"]
+        assert sum(r["prefill_tokens"] + r["decode_slots"]
+                   * r["decode_steps"] for r in log) == SCRIPT["rows_useful"]
+        wall = c["serve_round_ms_mixed"] + c["serve_round_ms_decode"]
+        assert wall == pytest.approx(sum(r["ms"] for r in log), abs=0.01)
+        assert c["serve_round_ms_spec"] == 0.0
+        phases = ["schedule", "build_inputs", "dispatch", "fetch", "book"]
+        spent = [c["serve_host_ms_" + p] for p in phases]
+        assert all(x > 0 for x in spent)
+        assert sum(spent) <= wall + 0.01  # children of the rounds
+        assert c["serve_host_ms_wait"] == 0.0  # drain() never waits
+        # the flight recorder kept its event names
+        kinds = [e["kind"] for e in eng.recorder.snapshot()["events"]]
+        assert kinds.count("round.mixed") == SCRIPT["mixed"]
+        assert kinds.count("round.decode_scan") == SCRIPT["decode"]
+
+    def test_emit_helper_is_a_hot_path(self):
+        from megatron_llm_tpu.analysis.lint import HOT_PATHS
+
+        assert "DecodeEngine._emit_round" in \
+            HOT_PATHS["megatron_llm_tpu/inference/engine.py"]
+        assert {"SpanTracer.step_span", "_Span.note"} <= \
+            HOT_PATHS["megatron_llm_tpu/telemetry/trace.py"]
+
+
+TRAIN_SCOPES = ["embed", "layers", "block", "norm", "attention",
+                "qkv_proj", "rope", "attn_core", "out_proj", "mlp", "up",
+                "act", "down", "loss", "head", "vocab_parallel",
+                "optimizer", "clip", "adam"]
+SERVE_SCOPES = ["embed", "layers", "block", "norm", "attention",
+                "qkv_proj", "rope", "attn_core", "kv_write", "page_gather",
+                "out_proj", "mlp", "up", "act", "down", "head", "sample"]
+
+
+def _scopes_in(lowered):
+    import re
+
+    found = set()
+    for name in re.findall(r'loc\("([^"]+)"', lowered.as_text(
+            debug_info=True)):
+        for tok in name.split("/"):
+            found.update(re.findall(r"[A-Za-z_]+", tok))
+    return found
+
+
+class TestNamedScopes:
+    """Scopes are metadata: every name of docs/GUIDE.md's list is in the
+    lowered text, and the compiled programs hold what they held (no
+    collective on one device; the tp inventories stay pinned by the
+    graft-check audit in test_static_analysis.py)."""
+
+    def test_engine_steps_carry_every_scope(self, tiny_model):
+        from megatron_llm_tpu.analysis.audit import collectives_in_text
+
+        eng = _engine(tiny_model, prefill_chunk_tokens=8)
+        with eng.mesh_scope():
+            for fn, args in ((eng._mixed_fn(8, True),
+                              eng._null_mixed_args(8)),
+                             (eng._step_fn(1, False),
+                              eng._null_scan_args(1))):
+                lowered = fn.lower(*args)
+                missing = set(SERVE_SCOPES) - _scopes_in(lowered)
+                assert not missing, missing
+                assert collectives_in_text(
+                    lowered.compile().as_text()) == frozenset()
+
+    def test_train_step_carries_every_scope(self):
+        from megatron_llm_tpu.analysis.audit import collectives_in_text
+        from megatron_llm_tpu.training.trainer import Trainer
+
+        cfg = tiny_config(seq_length=16, max_position_embeddings=16,
+                          compute_dtype=jnp.float32,
+                          params_dtype=jnp.float32)
+        tcfg = TrainConfig(micro_batch_size=2, global_batch_size=2,
+                           lr=1e-3, train_iters=1, log_interval=10**9,
+                           eval_interval=0)
+        trainer = Trainer(LlamaModel(cfg), tcfg,
+                          ParallelConfig(num_microbatches=1))
+        state = trainer.setup()
+        text = np.zeros((1, 2, cfg.seq_length + 1), np.int32)
+        from megatron_llm_tpu.training.trainer import get_batch
+
+        lowered = trainer._get_step_fn(1).lower(
+            state.params, state.opt_state, get_batch(text),
+            jnp.float32(1e-3), jnp.float32(0.0), None, jnp.float32(1e9))
+        missing = set(TRAIN_SCOPES) - _scopes_in(lowered)
+        assert not missing, missing
+        assert collectives_in_text(lowered.compile().as_text()) == \
+            frozenset()
 
 
 # ---------------------------------------------------------------------------
