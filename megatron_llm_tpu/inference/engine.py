@@ -26,11 +26,17 @@ Gemma-on-TPU serving study (arxiv 2605.25645):
   chunk — a ragged span of the oldest admitting prompt, at a saved
   offset — and a single-token decode row for every other live slot,
   all through one jitted MIXED step (models/attention.py chunked paged
-  branch -> ops/prefill_attention.py). A long prompt therefore delays
-  each in-flight decode token by at most one budget-bounded chunk
-  forward instead of its whole prefill, prompts are never pow2-padded,
-  and only one mixed-step trace exists per pow2 width bucket (vs one
-  whole-prompt prefill executable per prompt bucket).
+  branch -> ops/prefill_attention.py). The step lays the round out as
+  ONE packed row axis of `width + slots` tokens — the chunk at its
+  width bucket, then one decode row a slot — so the weights are read
+  once, for that many rows, and only the admitting slot is laid out at
+  the chunk's width (ISSUE 27; attention takes the chunk as (1, width)
+  and the decode rows as (slots, 1), the decode scan's shape). A long
+  prompt therefore delays each in-flight decode token by at most one
+  budget-bounded chunk forward instead of its whole prefill, prompts
+  are never pow2-padded, and only one mixed-step trace exists per pow2
+  width bucket (vs one whole-prompt prefill executable per prompt
+  bucket).
   `prefill_chunk_tokens=0` restores whole-prompt admission: a bucketed
   prefill (`bucket_prefill_len` compile shapes, LRU-bounded executable
   cache) writes the prompt's K/V into the slot's pages between decode
@@ -478,24 +484,33 @@ def _make_step_fn(model, vocab_size, horizon, all_greedy):
     max_variants=24,  # 2 specializations x (log2(chunk budget)+1) widths
     collectives={"single": frozenset(),
                  "tp2": frozenset({"all-reduce"})},  # see decode_scan
-    tmp_bytes_budget=4 << 20,
+    # the audit's fullest engine (int8 KV + int8 weights) reads 348,240
+    tmp_bytes_budget=352_000,
     notes="pow2 chunk-width buckets x {greedy, mixed}; the engine "
           "passes 2*len(mixed_width_buckets(prefill_chunk_tokens)) "
-          "at mint time; attention_window_size is engine-static like "
-          "kv_dtype (see decode_scan) — windowed engines mint the "
-          "same width buckets, never a window-keyed variant")
+          "at mint time; a round is one packed row axis of width + "
+          "slots tokens (the admitting slot's chunk, then a decode row "
+          "a slot), so a width bucket never multiplies by slots; "
+          "attention_window_size is engine-static like kv_dtype (see "
+          "decode_scan) — windowed engines mint the same width "
+          "buckets, never a window-keyed variant")
 def _make_mixed_step_fn(model, vocab_size, width, all_greedy):
     """The jitted MIXED prefill+decode step (chunked admission), traced
-    once per (engine, pow2 width bucket, greedy specialization): every
-    slot contributes one ragged span through the chunked paged stack —
-    the admitting slot a prefill chunk of up to `width` prompt tokens at
-    its saved offset, each decoding slot a single sampled/greedy token,
-    idle slots nothing (chunk_lens 0) — and attention for all of them
-    runs in ONE ragged paged pass (ops/prefill_attention.py). Decode
-    rows sample from the carried last_logits BEFORE the forward, exactly
-    like the decode scan body, so tokens and logprobs are independent of
-    which step flavor served them. Page pools are donated — the update
-    is in place.
+    once per (engine, pow2 width bucket, greedy specialization). The
+    round is ONE packed row axis of `width + slots` tokens: rows
+    0..width-1 are the admitting slot's prefill chunk (up to `width`
+    prompt tokens at its saved offset, chunk_lens[chunk_idx] of them
+    valid), rows width + i one sampled/greedy decode token per slot
+    (valid where the slot decodes; the admitting slot's and idle slots'
+    rows are masked). Embedding, projections, MLP, norms and head run
+    on those width + slots rows in one pass over the weights — only the
+    admitting slot is laid out at the chunk's width — and the paged
+    branch (models/attention.py, "packed_chunk") calls THE ragged paged
+    kernel on the chunk as (1, width) and on the decode rows as (slots,
+    1), the decode scan's own shape. Decode rows sample from the carried
+    last_logits BEFORE the forward, exactly like the decode scan body,
+    so tokens and logprobs are independent of which step flavor served
+    them. Page pools are donated — the update is in place.
 
     Returns per-slot (first token, its logprob under last_logits),
     the CHUNK slot's in-chunk logprobs [lp of chunk token p+1 at p],
@@ -506,6 +521,8 @@ def _make_mixed_step_fn(model, vocab_size, width, all_greedy):
              page_table, lengths, last_logits, chunk_tokens, chunk_lens,
              is_prefill, chunk_idx, greedy, temperature, top_k, top_p,
              seeds, sample_steps):
+        # chunk_tokens: (width,) — the admitting slot's span; every
+        # other operand is per-slot, as in the decode scan
         active = chunk_lens > 0
         lp_full = jax.nn.log_softmax(
             last_logits.astype(jnp.float32), axis=-1)
@@ -515,42 +532,42 @@ def _make_mixed_step_fn(model, vocab_size, width, all_greedy):
             sampled = _per_slot_sample(
                 last_logits, greedy, temperature, top_k, top_p, seeds,
                 sample_steps, vocab_size)
-        first = jnp.where(is_prefill, chunk_tokens[:, 0], sampled)
+        first = jnp.where(is_prefill, chunk_tokens[0], sampled)
         first = jnp.where(active, first, 0)
         first_lp = jnp.take_along_axis(
             lp_full, first[:, None].astype(jnp.int32), axis=-1)[:, 0]
-        toks = chunk_tokens.at[:, 0].set(first)
         caches = {"k_pages_layers": pools_k, "v_pages_layers": pools_v,
                   "page_table": page_table, "lengths": lengths,
-                  "chunk_lens": chunk_lens}
+                  "chunk_lens": chunk_lens, "packed_chunk": chunk_idx}
         if len(pools_ks) > 0:  # int8 pools carry scale pools
             caches["k_scales_layers"] = pools_ks
             caches["v_scales_layers"] = pools_vs
+        chunk_pos = lengths[chunk_idx] + jnp.arange(width)
         logits, new_caches = model.forward(
-            dec_params, toks, kv_caches=caches,
-            position_ids=lengths[:, None] + jnp.arange(width)[None, :],
+            dec_params, jnp.concatenate([chunk_tokens, first])[None],
+            kv_caches=caches,
+            position_ids=jnp.concatenate([chunk_pos, lengths])[None],
         )
+        logits = logits[0]  # (width + slots, V)
         if width > 1:
             # lp of chunk token p+1 under the logits at p — the prompt-
             # logprob stream of a prefill chunk (position p's target is
             # the NEXT prompt token; the chunk's last target arrives
-            # next round via first_lp, the decode scan's layout). Only
-            # the ONE prefill chunk row ever needs this, so slice it
-            # out before the (width, V) log_softmax instead of paying a
-            # (slots, width, V) one on every mixed round — these are
-            # exactly the rounds the decode-interference gauge watches.
-            row_logits = logits[chunk_idx, :-1]
+            # next round via first_lp, the decode scan's layout)
             lp_in = jax.nn.log_softmax(
-                row_logits.astype(jnp.float32), axis=-1)
-            row_toks = jax.lax.dynamic_index_in_dim(
-                toks, chunk_idx, 0, keepdims=False)[1:]
+                logits[:width - 1].astype(jnp.float32), axis=-1)
             chunk_lps = jnp.take_along_axis(
-                lp_in, row_toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
+                lp_in, chunk_tokens[1:, None].astype(jnp.int32),
+                axis=-1)[:, 0]
         else:
             chunk_lps = jnp.zeros((0,), jnp.float32)
-        last_idx = jnp.clip(chunk_lens - 1, 0, width - 1)
-        new_last = jnp.take_along_axis(
-            logits, last_idx[:, None, None], axis=1)[:, 0]
+        # the admitting slot carries the logits at its chunk's last
+        # valid row, a decoding slot those of its own row
+        chunk_last = jax.lax.dynamic_index_in_dim(
+            logits, jnp.clip(chunk_lens[chunk_idx] - 1, 0, width - 1), 0,
+            keepdims=False)
+        new_last = jnp.where(is_prefill[:, None], chunk_last[None],
+                             logits[width:])
         # keep last_logits' dtype (fp32; bf16-compute models upcast
         # here — no-op for fp32 models)
         new_last = jnp.where(active[:, None],
@@ -2369,7 +2386,12 @@ class DecodeEngine:
         tokens to ONE chunk <= the budget) contributes a ragged prompt
         span resumed at its saved offset; every fully-prefilled live
         slot contributes one decode token; other admitting slots sit
-        idle (chunk_lens 0). One jitted dispatch serves all of it.
+        idle (chunk_lens 0). One jitted dispatch serves all of it, laid
+        out as one packed row axis: `chunk_tokens` is the (width,) span
+        of the admitting slot alone, every other operand is per slot
+        (chunk_lens: the span's length for the admitting slot, 1 for a
+        decoding slot, 0 for an idle one), so the round computes width
+        + slots token rows.
         Returns the round's facts for `_emit_round`: decode slots
         advanced, prefill tokens consumed, the chunk request's rid (the
         round's correlation key: a streaming client's stalled `id:`
@@ -2393,7 +2415,7 @@ class DecodeEngine:
             for i in dec:
                 self._ensure_pages(i, self._lengths[i] + 1)
 
-            chunk_tokens = np.zeros((n, width), np.int32)
+            chunk_tokens = np.zeros((width,), np.int32)
             chunk_lens = np.zeros((n,), np.int32)
             is_prefill = np.zeros((n,), bool)
             greedy = np.ones(n, bool)
@@ -2402,7 +2424,7 @@ class DecodeEngine:
             top_p = np.zeros(n, np.float32)
             seeds = np.zeros(n, np.uint32)
             sample_steps = np.zeros(n, np.int32)
-            chunk_tokens[ci, :ln] = s_c.req.prompt[
+            chunk_tokens[:ln] = s_c.req.prompt[
                 s_c.prefill_pos:s_c.prefill_pos + ln]
             chunk_lens[ci] = ln
             is_prefill[ci] = True
@@ -2491,9 +2513,9 @@ class DecodeEngine:
             "log": {"prefill_tokens": ln, "decode_steps": 1,
                     "decode_slots": len(dec)},
             "advance_div": 1 if dec else None,
-            # every slot is laid out at the chunk's width; the chunk's
+            # the chunk at its width plus one row a slot; the chunk's
             # tokens and one token a decoding slot are real
-            "rows_computed": n * width,
+            "rows_computed": width + n,
             "rows_useful": ln + len(dec),
             "phases": {"build_inputs": sp_build, "dispatch": sp_disp,
                        "fetch": sp_fetch, "book": sp_book},
@@ -3117,7 +3139,7 @@ class DecodeEngine:
                 self._pools_ks, self._pools_vs,
                 self._dev(np.zeros_like(self._pt)), zeros_i,
                 self._last_logits,
-                self._dev(np.zeros((n, w), np.int32)),
+                self._dev(np.zeros((w,), np.int32)),
                 zeros_i,
                 self._dev(np.zeros(n, bool)),
                 self._dev(0, np.int32),

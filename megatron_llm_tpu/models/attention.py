@@ -27,6 +27,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.source_info_util import current_name_stack
 from jax.sharding import PartitionSpec as P
 
 from megatron_llm_tpu.models.remat import tag as _savepoint
@@ -237,6 +238,58 @@ def _out_proj(attn_params: dict, ctx: jnp.ndarray, compute_dtype):
     return out
 
 
+def _attend(statics, q, k, v, pools, page_table, starts, chunk_lens,
+            doc_starts=None):
+    """THE paged attention call of the paged branch below: `pools` is
+    (k_pages, v_pages[, k_scales, v_scales]), `statics` the config's
+    (use_decode_attn, decode_attn_min_cache, decode_attn_interpret,
+    attention_window_size). Returns (out, *updated pools)."""
+    from megatron_llm_tpu.ops.prefill_attention import (
+        ragged_paged_attention,
+    )
+
+    use_pallas, min_cache, interpret, window = statics
+    k_scales, v_scales = pools[2:] or (None, None)
+    return ragged_paged_attention(
+        q, k, v, pools[0], pools[1], page_table, starts, chunk_lens,
+        use_pallas=use_pallas, min_cache=min_cache, interpret=interpret,
+        k_scales=k_scales, v_scales=v_scales,
+        window_size=window, doc_starts=doc_starts,
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _attend_packed(statics, scope, q, k, v, pools, page_table, lengths,
+                   chunk_lens, ci):
+    """The packed form of a mixed round (`attention_block`,
+    "packed_chunk"): q/k/v are (1, width + slots, ...) — slot `ci`'s
+    chunk, then one decode row a slot. The chunk goes to the kernel as
+    (nc=1, C=width) against its slot's page-table row, then the decode
+    rows as (nc=slots, C=1) against the whole table, the decode scan's
+    own shape; both scatter into the pools in turn.
+
+    Jitted so that the unrolled layers of a step share ONE trace of it
+    and the lowered program holds it once: every width bucket's warm-up
+    traces, lowers and hashes the program whatever the compile cache
+    holds, and two kernel calls a layer would otherwise make that half
+    as long again. A called function's operations do not inherit the
+    call site's name stack, so the caller hands over its `scope`."""
+    n = lengths.shape[0]
+    w = q.shape[1] - n
+    with jax.named_scope(scope):
+        out_c, *pools = _attend(
+            statics, q[:, :w], k[:, :w], v[:, :w], pools,
+            *(jax.lax.dynamic_slice_in_dim(x, ci, 1)
+              for x in (page_table, lengths, chunk_lens)))
+        dec_lens = jnp.where(jnp.arange(n) == ci, 0,
+                             jnp.minimum(chunk_lens, 1))
+        out_d, *pools = _attend(
+            statics, q[0, w:, None], k[0, w:, None], v[0, w:, None],
+            pools, page_table, lengths, dec_lens)
+        return (jnp.concatenate([out_c, out_d[None, :, 0]], axis=1),
+                *pools)
+
+
 @jax.named_scope("attention")
 def attention_block(
     attn_params: dict,
@@ -271,6 +324,18 @@ def attention_block(
       decode scans, mixed rounds, and spec-verify all land here).
       Without "chunk_lens" the form is the engine's single-token decode
       step (s == 1): every slot is a width-1 chunk at its length.
+      With "packed_chunk" (a scalar int32 slot index next to
+      "chunk_lens": the engine's mixed prefill+decode round) `hidden`
+      is ONE packed row axis (1, width + slots, h) instead of (slots,
+      width, h): rows 0..width-1 are slot packed_chunk's prefill chunk
+      (chunk_lens[packed_chunk] of them valid, at positions
+      lengths[packed_chunk] + t), rows width + i are one decode row per
+      slot (valid where chunk_lens[i] > 0 and i != packed_chunk). The
+      projections around this branch then run on width + slots rows in
+      one pass over the weights, and the kernel is called on the two
+      shapes it already serves: (1, width) against the admitting
+      slot's page-table row, then (slots, 1) against the whole table —
+      the decode scan's own shape.
 
     On a tp serving mesh (DecodeEngine(serving_tp>1), ISSUE 14) BOTH
     paged forms run group-sharded: the pools arrive sharded on the group
@@ -329,10 +394,6 @@ def attention_block(
         if rope_table is not None:
             q = apply_rope(q, rope_table, position_ids)
             k = apply_rope(k, rope_table, position_ids)
-        from megatron_llm_tpu.ops.prefill_attention import (
-            ragged_paged_attention,
-        )
-
         # one gate, inside the entry point (ragged_paged_block):
         # use_pallas=True means "kernel if eligible, XLA twin
         # otherwise"; ONE gate means a decode row takes the SAME
@@ -345,21 +406,31 @@ def attention_block(
         # key like "chunk_lens": present only when the caller packs
         # documents, absent from the engine's carries.
         doc_starts = kv_cache.get("doc_starts")
+        # "packed_chunk" (the engine's mixed round): b == 1 and the s
+        # rows are slot packed_chunk's chunk followed by one decode row
+        # a slot, so only that slot is laid out at the chunk's width
+        packed_chunk = kv_cache.get("packed_chunk")
+        if packed_chunk is not None:
+            assert chunked and doc_starts is None and b == 1 \
+                and s > lengths.shape[0] and position_ids is not None, \
+                "packed_chunk packs one chunk + a decode row per slot " \
+                "into a single (1, width + slots) row axis"
         window = getattr(cfg, "attention_window_size", None)
+
+        statics = (cfg.use_decode_attn, cfg.decode_attn_min_cache,
+                   cfg.decode_attn_interpret, window)
 
         def paged(q, k, v, k_pages, v_pages, page_table, lengths,
                   chunk_lens, *rest):
-            k_scales, v_scales = rest[:2] if quantized else (None, None)
-            return ragged_paged_attention(
-                q, k, v, k_pages, v_pages, page_table, lengths,
-                chunk_lens,
-                use_pallas=cfg.use_decode_attn,
-                min_cache=cfg.decode_attn_min_cache,
-                interpret=cfg.decode_attn_interpret,
-                k_scales=k_scales, v_scales=v_scales,
-                window_size=window,
-                doc_starts=rest[-1] if doc_starts is not None else None,
-            )
+            pools = (k_pages, v_pages) + (tuple(rest[:2]) if quantized
+                                          else ())
+            if packed_chunk is not None:
+                return _attend_packed(
+                    statics, str(current_name_stack()), q, k, v, pools,
+                    page_table, lengths, chunk_lens, rest[-1])
+            return _attend(statics, q, k, v, pools, page_table, lengths,
+                           chunk_lens,
+                           rest[-1] if doc_starts is not None else None)
 
         # on a tp serving mesh the pools arrive sharded on the group
         # axis (kv_pool_spec): each chip scatters and attends over its
@@ -377,6 +448,9 @@ def attention_block(
         if doc_starts is not None:
             operands.append(doc_starts)
             in_specs.append(P())
+        if packed_chunk is not None:
+            operands.append(packed_chunk)
+            in_specs.append(P())
         # kv_write and page_gather open inside (ops/prefill_attention.py)
         with jax.named_scope("attn_core"):
             res = shard_kernel(paged, in_specs, tuple(out_specs),
@@ -389,6 +463,8 @@ def attention_block(
             new_cache["chunk_lens"] = chunk_lens
         if doc_starts is not None:
             new_cache["doc_starts"] = doc_starts
+        if packed_chunk is not None:
+            new_cache["packed_chunk"] = packed_chunk
         if quantized:
             (ctx, new_cache["k_pages"], new_cache["v_pages"],
              new_cache["k_scales"], new_cache["v_scales"]) = res

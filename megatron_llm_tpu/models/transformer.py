@@ -331,10 +331,14 @@ def transformer_stack(
             # ragged chunk lengths ride through every layer (the layer
             # branch scatters + attends the whole span at once); the
             # stack-level length advance is ragged too
-            cl = kv_caches.get("chunk_lens")
             # packed multi-doc prefill (ISSUE 19): per-chunk document
-            # floors thread through every layer exactly like chunk_lens
-            dcs = kv_caches.get("doc_starts")
+            # floors thread through every layer exactly like chunk_lens;
+            # so does the admitting slot's index of a mixed round's
+            # packed row axis (attention_block's paged form)
+            riders = {k: kv_caches[k]
+                      for k in ("chunk_lens", "doc_starts", "packed_chunk")
+                      if kv_caches.get(k) is not None}
+            cl = riders.get("chunk_lens")
             ks = list(kv_caches["k_pages_layers"])
             vs = list(kv_caches["v_pages_layers"])
             # int8 KV pools (ISSUE 9): per-layer fp32 scale pools ride
@@ -345,11 +349,7 @@ def transformer_stack(
                    if kss is not None else None)
             for i in range(L):
                 cache_l = {"k_pages": ks[i], "v_pages": vs[i],
-                           "page_table": pt, "lengths": lens}
-                if cl is not None:
-                    cache_l["chunk_lens"] = cl
-                if dcs is not None:
-                    cache_l["doc_starts"] = dcs
+                           "page_table": pt, "lengths": lens, **riders}
                 if kss is not None:
                     cache_l["k_scales"] = kss[i]
                     cache_l["v_scales"] = vss[i]
@@ -364,11 +364,8 @@ def transformer_stack(
                 "page_table": pt,
                 "lengths": lens + (cl if cl is not None
                                    else hidden.shape[1]),
+                **riders,
             }
-            if cl is not None:
-                new_caches["chunk_lens"] = cl
-            if dcs is not None:
-                new_caches["doc_starts"] = dcs
             if kss is not None:
                 new_caches["k_scales_layers"] = tuple(kss)
                 new_caches["v_scales_layers"] = tuple(vss)

@@ -559,9 +559,10 @@ def ragged_paged_attention(
     under the interpreter) and by the gather-pages twin elsewhere.
 
     Phase is a shape: a decode row is chunk_lens == 1 at starts ==
-    lengths (C == 1 in the engine's decode scan; any C in a mixed
-    round), a prefill span is chunk_lens in 2..C, an idle slot is
-    chunk_lens == 0. Returns (out (nc, C, g, qpk, d), k_pages,
+    lengths (C == 1 in the engine's decode scan and for the decode
+    rows of a mixed round; any C in a spec-verify round), a prefill
+    span is chunk_lens in 2..C (a mixed round's one chunk, nc == 1), an
+    idle slot is chunk_lens == 0. Returns (out (nc, C, g, qpk, d), k_pages,
     v_pages); pad rows (t >= chunk_lens) are exact zeros.
 
     kv dtype is a parameter (ISSUE 9): int8 pools pass the fp32 scale

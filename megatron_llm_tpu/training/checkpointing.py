@@ -223,7 +223,9 @@ def save_checkpoint(
     tracker."""
     save_dir = os.path.abspath(save_dir)  # orbax requires absolute paths
     path = checkpoint_dir(save_dir, iteration, release=release)
-    os.makedirs(save_dir, exist_ok=True)
+    # the iteration's own directory too: orbax makes it on its own
+    # thread, and meta.json below is written before that save is waited on
+    os.makedirs(path, exist_ok=True)
     ckptr = ocp.StandardCheckpointer()
     ckptr.save(os.path.join(path, "model"), params, force=True)
     if opt_state is not None:

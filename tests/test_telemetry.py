@@ -714,9 +714,10 @@ ROUND_PHASES = ["engine.schedule", "engine.build_inputs", "engine.dispatch",
 # scripted run: prompts of 11 and 5 tokens, 5 tokens out each, 2 slots,
 # chunk 8, horizon 4. Mixed rounds: 8 of prompt A (width 8), its last 3
 # (width 4), the 5 of B beside A's first decode token (width 8); then a
-# scan of 4 over both slots and one last step for B alone.
+# scan of 4 over both slots and one last step for B alone. A mixed round
+# computes its chunk's width plus one row a slot, a scan slots x horizon.
 SCRIPT = dict(prompts=[list(range(5, 16)), list(range(40, 45))], out=5,
-              rows_computed=2 * 8 + 2 * 4 + 2 * 8 + 2 * 4 + 2 * 1,
+              rows_computed=(8 + 2) + (4 + 2) + (8 + 2) + 2 * 4 + 2 * 1,
               rows_useful=11 + 5 + 5 + 5, mixed=3, decode=2)
 
 
