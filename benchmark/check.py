@@ -1,5 +1,6 @@
 """What decides `correct`: the timed path's own output against the plain
-reference (`reference/falcon.py`), number by number, each under a limit
+reference (the family's, `reference/<model_type>.py`, over the shared
+arithmetic of `reference/common.py`), number by number, each under a limit
 of its own (`limits/<cell>.json`, set from chip readings: PERF.md).
 
 Training: the losses of the first three steps, the first gradient as the
@@ -24,8 +25,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import weights
-from .reference import falcon as ref
+from . import families, weights
+from .reference import common as ref
 
 
 # ------------------------------------------------------------ the control
@@ -91,22 +92,27 @@ def train_reference(cfg: dict, seed: int, texts: np.ndarray,
     change after the last step; leaves are keyed `name` -> array with
     one entry per block (one entry for a global)."""
     use = cfg["train"]
+    fam = families.find(cfg)
     L = use["num_hidden_layers"]
     matmul = MATMULS[precision]
     words = weights.seed_words(seed)
     mk_layer, mk_glob = _makers(cfg, devices)
     params = {"layers": [mk_layer(words, jnp.int32(i)) for i in range(L)],
               "globals": mk_glob(words)}
-    zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p))
+    # Adam's moments lie where the parameters lie: tied to no sharding,
+    # the moments of a model spread over four chips are replicated
+    zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p),
+                    out_shardings=jax.tree.map(lambda x: x.sharding, params))
     m, v = zeros(params), zeros(params)
     tp = use["tensor_parallel"]
 
     def loss(p, t, l):
         if fault == "no_exchange":
             p = dict(p, layers=[
-                dict(w, wo=one_ranks_share(w["wo"], tp),
-                     w2=one_ranks_share(w["w2"], tp)) for w in p["layers"]])
-        return ref.mean_loss(p, t, l, cfg, matmul)
+                dict(w, **{name: one_ranks_share(w[name], tp, axis)
+                           for name, axis in fam.ROW_PARALLEL.items()})
+                for w in p["layers"]])
+        return fam.reference.mean_loss(p, t, l, cfg, matmul)
 
     lg = jax.jit(jax.value_and_grad(loss))
 
@@ -228,6 +234,7 @@ def serve_reference_logits(cfg: dict, seed: int, samples: list,
     served type; blocks are made, used on every sample, and dropped, one
     at a time."""
     use = cfg["serve"]
+    fam = families.find(cfg)
     L = use["num_hidden_layers"]
     matmul = MATMULS[precision]
     dt = jnp.bfloat16 if use["weights_dtype"] == "bfloat16" else jnp.float32
@@ -239,20 +246,28 @@ def serve_reference_logits(cfg: dict, seed: int, samples: list,
     T = max(len(s["tokens"]) for s in samples)
     T = -(-T // 256) * 256
     positions = jnp.arange(T)
-    blk = jax.jit(lambda w, x: ref.block(w, x, cfg, positions, matmul))
+    programs = {}  # one compiled block per KIND of layer, not per layer
+
+    def blk(w, x, i):
+        kind = fam.layer_kind(cfg, i)
+        if kind not in programs:
+            programs[kind] = jax.jit(lambda w, x: fam.reference.block(
+                w, x, cfg, positions, matmul, layer=i))
+        return programs[kind](w, x)
+
     hs = []
     for s in samples:
         toks = np.zeros(T, np.int32)
         toks[:len(s["tokens"])] = s["tokens"]
-        hs.append(ref.embed(glob, jnp.asarray(toks)))
+        hs.append(fam.reference.embed(glob, jnp.asarray(toks)))
     for i in range(L):
         w = mk_layer(words, jnp.int32(i))
-        hs = [blk(w, h) for h in hs]
+        hs = [blk(w, h, i) for h in hs]
         for leaf in jax.tree.leaves(w):
             leaf.delete()
     # the globals go in as an argument: closed over, the 1.2 GB embedding
     # would be a constant for the compiler to fold
-    head = jax.jit(lambda g, x: ref.final_logits(g, x, cfg, matmul))
+    head = jax.jit(lambda g, x: fam.reference.final_logits(g, x, cfg, matmul))
     out = []
     for s, h in zip(samples, hs):
         p, n = s["prompt_len"], len(s["tokens"])

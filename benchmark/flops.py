@@ -1,11 +1,13 @@
-"""The yardstick's arithmetic: chip peaks, parameter counts, and the
-operations and bytes a configuration's work needs, from its shapes alone.
+"""The yardstick's arithmetic that no family owns: the chip's published
+peaks, and the operations and bytes of a dense decoder's work, from
+counts that a family (`families/<model_type>.py`) takes from its own
+shapes. A family whose work is not a dense decoder's writes its own.
 
 Nothing here is measured and nothing here imports the program. The two
 FLOP formulas are copies of `megatron_llm_tpu/telemetry/chipspec.py`
-(`train_flops_per_token`, `decode_flops_per_token`), restated over the
-configuration file's own keys; the original is listed in PERF.md for a
-later PR to retire. Recomputed (remat) operations never count.
+(`train_flops_per_token`, `decode_flops_per_token`), restated over
+counts; the original is listed in PERF.md for a later PR to retire.
+Recomputed (remat) operations never count.
 """
 
 from __future__ import annotations
@@ -29,104 +31,44 @@ def chip_peaks(device_kind: str) -> dict:
                      "add its published peaks with their source")
 
 
-def qkv_width(cfg: dict) -> int:
-    return cfg["head_dim"] * (cfg["num_attention_heads"]
-                              + 2 * cfg["num_kv_heads"])
-
-
-def attn_width(cfg: dict) -> int:
-    """Width of the attention output: query heads x head size."""
-    return cfg["num_attention_heads"] * cfg["head_dim"]
-
-
-def norms_per_layer(cfg: dict) -> int:
-    return 2 if cfg["new_decoder_architecture"] else 1
-
-
-def layer_matmul_params(cfg: dict) -> int:
-    """Weights of one block that sit in a matrix multiplication."""
-    h, f = cfg["hidden_size"], cfg["ffn_hidden_size"]
-    return h * qkv_width(cfg) + attn_width(cfg) * h + 2 * h * f
-
-
-def layer_params(cfg: dict) -> int:
-    return layer_matmul_params(cfg) \
-        + 2 * cfg["hidden_size"] * norms_per_layer(cfg)
-
-
-def embedding_params(cfg: dict) -> int:
-    return cfg["vocab_size"] * cfg["hidden_size"]
-
-
-def n_params(cfg: dict, layers: int) -> int:
-    """All parameters with a tied embedding/head counted once."""
-    return (layers * layer_params(cfg) + embedding_params(cfg)
-            + 2 * cfg["hidden_size"])
-
-
-def matmul_params(cfg: dict, layers: int, head: bool = True) -> int:
-    """Parameters a token is multiplied by: the blocks' matrices and,
-    where `head`, the (tied) output head. The embedding lookup itself
-    is a gather and costs no operations."""
-    return layers * layer_matmul_params(cfg) \
-        + (embedding_params(cfg) if head else 0)
-
-
-def kv_bytes_per_token(cfg: dict, layers: int, itemsize: int = 2) -> int:
-    return 2 * layers * cfg["num_kv_heads"] * cfg["head_dim"] * itemsize
-
-
-def train_flops_per_token(cfg: dict, layers: int, seq: int) -> float:
+def train_flops_per_token(matmul_params: int, layers: int, attn_width: int,
+                          seq: int) -> float:
     """Forward + backward model FLOPs of one trained token: 6 per matrix
-    weight, plus causal attention (QK^T and PV, 2 FLOPs a multiply-add,
-    forward and twice that backward = 12 * width * seq, halved by the
-    causal mask = 6 * layers * width * seq)."""
-    return 6.0 * matmul_params(cfg, layers) \
-        + 6.0 * layers * attn_width(cfg) * seq
+    weight a token is multiplied by, plus causal attention (QK^T and PV,
+    2 FLOPs a multiply-add, forward and twice that backward = 12 * width
+    * seq, halved by the causal mask = 6 * layers * width * seq).
+    `attn_width`: query heads x head size."""
+    return 6.0 * matmul_params + 6.0 * layers * attn_width * seq
 
 
-def train_attention_flops(cfg: dict, layers: int, seq: int,
+def train_attention_flops(layers: int, attn_width: int, seq: int,
                           tokens: int) -> float:
     """Causal attention's own FLOPs (scores and context, fwd + bwd)."""
-    return 6.0 * layers * attn_width(cfg) * seq * tokens
+    return 6.0 * layers * attn_width * seq * tokens
 
 
-def train_attention_bytes(cfg: dict, layers: int, seq: int, tokens: int,
-                          itemsize: int = 2) -> float:
+def train_attention_bytes(layers: int, attn_width: int, kv_width: int,
+                          tokens: int, itemsize: int = 2) -> float:
     """Bytes attention has to move at the least, forward and backward:
     read q, k, v and write the context going forward; read q, k, v, the
     context and its cotangent and write dq, dk, dv going back. K and V
-    are the un-expanded grouped heads. The score matrix is never counted:
-    a tiled kernel need not write it."""
-    q = attn_width(cfg)
-    kv = 2 * cfg["num_kv_heads"] * cfg["head_dim"]
+    are the un-expanded grouped heads (`kv_width`: both together). The
+    score matrix is never counted: a tiled kernel need not write it."""
+    q, kv = attn_width, kv_width
     fwd = q + kv + q
     bwd = (q + kv) + 2 * q + (q + kv)
-    del seq
     return float(layers * tokens * (fwd + bwd) * itemsize)
 
 
-def serve_token_flops(cfg: dict, layers: int, position: int,
-                      needs_head: bool) -> float:
-    """Forward FLOPs the algorithm needs for one token at cache position
-    `position` (it attends to position + 1 keys): 2 per matrix weight
-    (the head only where the token's logits are needed: every output
-    token and the last prompt token) plus 4 * layers * width per key."""
-    return 2.0 * matmul_params(cfg, layers, head=needs_head) \
-        + 4.0 * layers * attn_width(cfg) * (position + 1)
-
-
-def serve_span_flops(cfg: dict, layers: int, start: int, stop: int,
+def serve_span_flops(block_params: int, head_params: int, layers: int,
+                     attn_width: int, start: int, stop: int,
                      head_tokens: int) -> float:
-    """Sum of `serve_token_flops` over cache positions start..stop-1, of
-    which `head_tokens` need the head."""
+    """Forward FLOPs the algorithm needs for the tokens at cache
+    positions start..stop-1 (the one at `p` attends to p + 1 keys): 2 per
+    matrix weight of the blocks, the head (`head_params`) only for the
+    `head_tokens` whose logits are needed (every output token and the
+    last prompt token), plus 4 * layers * width per key."""
     n = max(stop - start, 0)
     keys = (start + 1 + stop) * n / 2.0  # sum of (p + 1)
-    return (2.0 * matmul_params(cfg, layers, head=False) * n
-            + 2.0 * embedding_params(cfg) * head_tokens
-            + 4.0 * layers * attn_width(cfg) * keys)
-
-
-def weight_bytes(cfg: dict, layers: int, itemsize: int = 2) -> float:
-    """Bytes of the matrices one forward pass has to read once."""
-    return float(matmul_params(cfg, layers) * itemsize)
+    return (2.0 * block_params * n + 2.0 * head_params * head_tokens
+            + 4.0 * layers * attn_width * keys)

@@ -52,6 +52,9 @@ def load_cell(workload: str, bench_path: str | None = None,
         if "workloads" not in m or workload in m["workloads"]:
             metrics.append(m)
     limits = load_json(base, "limits", workload + ".json")["limits"]
+    from . import families
+
+    families.find(cfg, base)  # the family's files lie beside the cell's
     return {"name": workload, "entry": entry, "cfg": cfg, "mix": mix,
             "end_to_end": e2e, "per_layer": metrics, "limits": limits}
 
@@ -156,13 +159,15 @@ class TraceWindow:
         if not self.enabled:
             return None
         self._thread.join()
-        from . import trace_reduce
+        from . import span_reduce
 
         paths = glob.glob(os.path.join(self.dir, "plugins", "profile", "*",
                                        "*.xplane.pb"))
         if not paths:
             raise RuntimeError("the profiler wrote no trace")
-        trace = trace_reduce.load_xplane(paths[0])
+        # `trace_reduce.load_xplane`'s form with the program's spans kept
+        # whole (their `kind`, `round`, `rid`): the span readers need them
+        trace = span_reduce.load(paths[0])
         keep = os.environ.get("BENCH_KEEP_TRACE")
         if keep:
             os.makedirs(os.path.dirname(keep), exist_ok=True)
@@ -211,13 +216,22 @@ def _r_value(ctx, p):
     return ctx["values"].get(p["key"])
 
 
+def _reduced(ctx, fn):
+    """`fn(trace)`, worked out once a trace however many metric files
+    read it."""
+    done = ctx.setdefault("reduced", {})
+    if fn not in done:
+        done[fn] = fn(ctx["trace"])
+    return done[fn]
+
+
 def _r_idle_share(ctx, p):
     trace = ctx.get("trace")
     if not trace:
         return None
     from . import trace_reduce
 
-    busy = trace_reduce.busy_seconds(trace)
+    busy = _reduced(ctx, trace_reduce.busy_seconds)
     if not busy or not ctx["trace_window_s"]:
         return None
     return 100.0 * (1.0 - min(busy) / ctx["trace_window_s"])
@@ -229,7 +243,7 @@ def _r_exposed_collective(ctx, p):
         return None
     from . import trace_reduce
 
-    exposed = trace_reduce.exposed_collective_seconds(trace)
+    exposed = _reduced(ctx, trace_reduce.exposed_collective_seconds)
     if not exposed or not ctx["trace_window_s"]:
         return None
     return 100.0 * max(exposed) / ctx["trace_window_s"]
@@ -247,20 +261,35 @@ def _r_mfu(ctx, p):
     return 100.0 * flops / (seconds * ctx["chips"] * peak)
 
 
+def _work(ctx, p, what: str):
+    """A roofline's FLOPs or bytes: the family's own function, kept with
+    the benchmark and named by the metric file (`params.flops_fn`,
+    `params.bytes_fn`), of the traced part's counters."""
+    if not ctx.get("traced"):
+        return None
+    fn = getattr(ctx["family"], p[what + "_fn"])
+    return fn(ctx["cfg"], ctx["use"], ctx["traced"])
+
+
 def _r_site_roofline(ctx, p):
     """The least time the chip could take for the work (the larger of
     FLOPs over peak and bytes over bandwidth, both per chip) over the
-    traced device time of the operations at the listed source sites."""
+    traced device time of the operations at the listed source `sites`
+    and under the listed named `scopes`."""
     trace = ctx.get("trace")
     if not trace or not ctx.get("peaks"):
         return None
-    from . import trace_reduce
+    from . import span_reduce, trace_reduce
 
-    flops = ctx["values"].get(p["flops"])
-    nbytes = ctx["values"].get(p["bytes"])
+    flops, nbytes = _work(ctx, p, "flops"), _work(ctx, p, "bytes")
     if not flops or not nbytes:
         return None
-    spent = trace_reduce.site_seconds(trace, p["sites"])
+    spent = trace_reduce.site_seconds(trace, p["sites"]) \
+        if p.get("sites") else 0.0
+    if p.get("scopes"):
+        got = _reduced(ctx, span_reduce.scope_seconds)
+        spent += sum(span_reduce.scope_time(got, prefix)
+                     for prefix in p["scopes"])
     if spent <= 0:
         return None
     chips = ctx["chips"]
@@ -269,13 +298,107 @@ def _r_site_roofline(ctx, p):
     return 100.0 * needed / spent
 
 
+def _r_ratio(ctx, p):
+    """`scale` x `num` / `den`, two keys of the run's values (counters,
+    their `.window` or `.traced` differences); `one_minus`: the rest."""
+    num, den = ctx["values"].get(p["num"]), ctx["values"].get(p["den"])
+    if num is None or not den:
+        return None
+    share = num / den
+    return p.get("scale", 1.0) * (1.0 - share if p.get("one_minus")
+                                  else share)
+
+
+def _r_scope_share(ctx, p):
+    """Share of device self time under the named scope `prefix`, in the
+    listed `phases` (all four without the key). A trace none of whose
+    operations carries a scope has nothing to read."""
+    trace = ctx.get("trace")
+    if not trace:
+        return None
+    from . import span_reduce
+
+    got = _reduced(ctx, span_reduce.scope_seconds)
+    if set(got["by_scope"]) <= {span_reduce.UNNAMED}:
+        return None
+    # over the same sums it is a part of, so a share never passes 100
+    total = sum(sum(row.values()) for row in got["by_scope"].values())
+    return 100.0 * span_reduce.scope_time(
+        got, p["prefix"], p.get("phases", span_reduce.PHASES)) / total
+
+
+def _r_span_rounds(ctx, p):
+    """One `field` (`host_ms_p50`, `round_ms_p50`, `rounds`, ...) of the
+    `engine.round` spans of one `kind` (`decode`, `mixed`)."""
+    trace = ctx.get("trace")
+    if not trace:
+        return None
+    from . import span_reduce
+
+    row = _reduced(ctx, span_reduce.round_kinds).get(p["kind"])
+    return None if row is None else row.get(p["field"])
+
+
+def _r_span_gap_share(ctx, p):
+    """Share of the first device's idle seconds that lies under the
+    program span `span` (innermost)."""
+    trace = ctx.get("trace")
+    if not trace:
+        return None
+    from . import span_reduce
+
+    got = _reduced(ctx, span_reduce.gap_attribution)
+    if not got["idle_s"] or not got["by_span"]:
+        return None
+    return 100.0 * got["by_span"].get(p["span"], 0.0) / got["idle_s"]
+
+
+def _r_collective_owner(ctx, p):
+    """Exposed collective seconds of the collectives that the scope
+    `owner` owns, as a share of the traced window."""
+    trace = ctx.get("trace")
+    if not trace or not ctx["trace_window_s"]:
+        return None
+    from . import span_reduce
+
+    owners = _reduced(ctx, span_reduce.collective_owner)
+    if not owners:
+        return None
+    sec = sum(v for k, v in owners.items()
+              if span_reduce.under(k, p["owner"]))
+    return 100.0 * sec / ctx["trace_window_s"]
+
+
 READERS = {
     "value": _r_value,
     "idle_share": _r_idle_share,
     "exposed_collective_share": _r_exposed_collective,
     "mfu": _r_mfu,
     "site_roofline": _r_site_roofline,
+    "ratio": _r_ratio,
+    "scope_share": _r_scope_share,
+    "span_rounds": _r_span_rounds,
+    "span_gap_share": _r_span_gap_share,
+    "collective_owner": _r_collective_owner,
 }
+
+
+def reader_context(cell: dict, use: dict, values: dict, traced: dict,
+                   tracer, trace, device: dict) -> dict:
+    """What the per-layer readers see of a run. `traced`: the traced
+    part's own counters. With a trace, `device` gets its busy seconds
+    and the traced window's length."""
+    from . import families, flops
+
+    if trace:
+        device.update(busy_s=mean_busy(trace), window_s=tracer.window_s)
+    cfg = cell["cfg"]
+    return {"values": values, "chips": cell["entry"]["chips"],
+            "trace": trace, "trace_window_s": tracer.window_s,
+            "cfg": cfg, "use": use, "family": families.find(cfg),
+            "traced": traced,
+            "peaks": flops.chip_peaks(device["kind"])
+            if device["platform"] == "tpu" else None}
 
 
 def mean_busy(trace: dict) -> float:

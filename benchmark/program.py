@@ -1,10 +1,11 @@
 """The one file of the benchmark that touches the program under test.
 
-It builds the program's own objects from a configuration file (the model
-configuration, `Trainer`, `DecodeEngine`), lays the benchmark's seeded
-weights out the way the program wants them, and reads the program's
-counters. Nothing here measures anything. Faults for `prove.py` and the
-tests are planted here, underneath the timed path.
+It builds the program's own objects from a configuration file (`Trainer`,
+`DecodeEngine`; the model object and the map from the seeded leaves to
+the program's parameter tree are the family's, `families/<model_type>.py`),
+lays the benchmark's seeded weights out the way the program wants them,
+and reads the program's counters. Nothing here measures anything. Faults
+for `prove.py` and the tests are planted here, underneath the timed path.
 """
 
 from __future__ import annotations
@@ -14,21 +15,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import weights
+from . import families, weights
 
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
-
-# neutral leaf name -> path in the program's parameter tree
-LAYER_PATHS = {
-    "ln1_scale": ("input_norm", "scale"), "ln1_bias": ("input_norm", "bias"),
-    "ln2_scale": ("mlp_norm", "scale"), "ln2_bias": ("mlp_norm", "bias"),
-    "wqkv": ("attention", "wqkv"), "wo": ("attention", "wo"),
-    "w1": ("mlp", "w1"), "w2": ("mlp", "w2"),
-}
-GLOBAL_PATHS = {
-    "embedding": ("embedding", "word_embeddings"),
-    "lnf_scale": ("final_norm", "scale"), "lnf_bias": ("final_norm", "bias"),
-}
 
 
 def enable_compile_cache():
@@ -40,40 +29,12 @@ def enable_compile_cache():
     path = enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # an executable from the cache carries the names (scopes, source
+    # lines) of the build that stored it; with the metadata in the key a
+    # traced run reads its own build's names, and the untraced runs of
+    # the same checkout share its entries
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
-
-
-def model_config(cfg: dict, use: dict, tp: int = 1):
-    from megatron_llm_tpu.config import ModelConfig
-
-    if cfg["vocab_size"] % (128 * tp):
-        raise ValueError("vocabulary does not divide over the tp ranks")
-    seq = use.get("seq_length", use.get("max_context",
-                                        cfg["max_position_embeddings"]))
-    return ModelConfig(
-        num_layers=use["num_hidden_layers"],
-        hidden_size=cfg["hidden_size"],
-        ffn_hidden_size=cfg["ffn_hidden_size"],
-        num_attention_heads=cfg["num_attention_heads"],
-        num_attention_heads_kv=cfg["num_kv_heads"],
-        kv_channels=cfg["head_dim"],
-        max_position_embeddings=cfg["max_position_embeddings"],
-        seq_length=seq,
-        padded_vocab_size=cfg["vocab_size"],
-        layernorm_epsilon=cfg["layer_norm_epsilon"],
-        use_rms_norm=False, use_bias=cfg["bias"], glu_activation=None,
-        hidden_act=cfg["hidden_act"], position_embedding_type="rotary",
-        rope_theta=cfg["rope_theta"], parallel_attn=cfg["parallel_attn"],
-        parallel_layernorm=cfg["new_decoder_architecture"],
-        tie_embed_logits=cfg["tie_word_embeddings"],
-        hidden_dropout=0.0, attention_dropout=0.0,
-        params_dtype=_DTYPES[use.get("params_dtype",
-                                     use.get("weights_dtype", "float32"))],
-        compute_dtype=_DTYPES[use["compute_dtype"]],
-        init_method_std=cfg["initializer_range"],
-        remat_policy=use.get("remat_policy"),
-        use_flash_attn=use.get("use_flash_attn", False),
-    )
 
 
 def _set(tree: dict, path, value):
@@ -88,26 +49,26 @@ def _get(tree: dict, path):
     return tree
 
 
-def program_tree(layers: dict, glob: dict) -> dict:
-    """Neutral leaves -> the program's parameter tree (same arrays)."""
+def program_tree(cfg: dict, layers: dict, glob: dict) -> dict:
+    """Neutral leaves -> the program's parameter tree (same arrays), by
+    the family's paths."""
+    fam = families.find(cfg)
     out = {}
-    for name, leaf in layers.items():
-        _set(out, ("layers",) + LAYER_PATHS[name], leaf)
-    for name, leaf in glob.items():
-        _set(out, GLOBAL_PATHS[name], leaf)
+    for name, path in fam.layer_paths(cfg).items():
+        _set(out, ("layers",) + path, layers[name])
+    for name, path in fam.global_paths(cfg).items():
+        _set(out, path, glob[name])
     return out
 
 
-def neutral_leaves(tree: dict, has_ln2: bool) -> dict:
+def neutral_leaves(cfg: dict, tree: dict) -> dict:
     """The program's tree -> {neutral name: leaf}; block leaves keep the
     leading layer axis."""
-    out = {}
-    for name, path in LAYER_PATHS.items():
-        if name.startswith("ln2") and not has_ln2:
-            continue
-        out[name] = _get(tree, ("layers",) + path)
-    for name, path in GLOBAL_PATHS.items():
-        out[name] = _get(tree, path)
+    fam = families.find(cfg)
+    out = {name: _get(tree, ("layers",) + path)
+           for name, path in fam.layer_paths(cfg).items()}
+    out.update({name: _get(tree, path)
+                for name, path in fam.global_paths(cfg).items()})
     return out
 
 
@@ -120,7 +81,6 @@ class TrainProgram:
     def __init__(self, cfg: dict, seed: int, chips: int,
                  mark=lambda name: None):
         from megatron_llm_tpu.config import ParallelConfig, TrainConfig
-        from megatron_llm_tpu.models import FalconModel
         from megatron_llm_tpu.parallel import initialize_parallel
         from megatron_llm_tpu.training.trainer import Trainer
 
@@ -129,6 +89,7 @@ class TrainProgram:
         if tp > chips:
             raise ValueError("configuration needs more chips than the cell")
         self.cfg, self.use, self.seed = cfg, use, seed
+        self.family = fam = families.find(cfg)
         self.layers = use["num_hidden_layers"]
         if tp > 1:
             initialize_parallel(tp=tp,
@@ -144,8 +105,9 @@ class TrainProgram:
             lr_warmup_iters=0, weight_decay=use["weight_decay"],
             clip_grad=use["clip_grad"], adam_beta1=use["adam_beta1"],
             adam_beta2=use["adam_beta2"], adam_eps=use["adam_eps"],
-            bf16=use["compute_dtype"] == "bfloat16", seed=seed % (2**31))
-        self.model = FalconModel(model_config(cfg, use, tp))
+            bf16=use["compute_dtype"] == "bfloat16", seed=seed % (2**31),
+            **fam.trainer_args(cfg, use))
+        self.model = fam.model(cfg, use, tp)
         self.trainer = Trainer(self.model, tcfg, pcfg)
         mark("trainer_made")
         self.state = self.trainer.setup()
@@ -161,10 +123,11 @@ class TrainProgram:
             self._make_params(self._words))
         mark("seeded_weights")
         self.fault = None
+        self._stats = {}
 
     def _seeded_params(self, words):
         tree = program_tree(
-            weights.make_stacked(self.cfg, words, self.layers),
+            self.cfg, weights.make_stacked(self.cfg, words, self.layers),
             weights.make_globals(self.cfg, words))
         dt = _DTYPES[self.use["params_dtype"]]
         return jax.tree.map(lambda x: x.astype(dt), tree)
@@ -182,43 +145,55 @@ class TrainProgram:
         if self.fault == "state_unchanged":
             keep = jax.tree.map(jnp.copy, (self.state.params,
                                            self.state.opt_state))
-        stats = self.trainer.train_step(self.state, text)
+        stats = self._stats = self.trainer.train_step(self.state, text)
         loss = float(stats["loss"])
         if self.fault == "state_unchanged":
             self.state.params, self.state.opt_state = keep
         return loss
 
+    def stats(self) -> dict:
+        """What the last step's stats carry beside the loss, as numbers.
+        Read ONCE, after the window has closed: each is a fetch."""
+        out = {}
+        for name, value in self._stats.items():
+            if name != "loss" and np.ndim(value) == 0:
+                try:
+                    out[name] = float(value)
+                except (TypeError, ValueError):
+                    pass
+        return out
+
     def _one_ranks_rows(self, params):
         from .check import one_ranks_share
 
         tp = self.use["tensor_parallel"]
+        paths = self.family.layer_paths(self.cfg)
 
         def cut(p):
-            layers = p["layers"]
-            att = dict(layers["attention"],
-                       wo=one_ranks_share(layers["attention"]["wo"], tp, 1))
-            mlp = dict(layers["mlp"],
-                       w2=one_ranks_share(layers["mlp"]["w2"], tp, 1))
-            return dict(p, layers=dict(layers, attention=att, mlp=mlp))
+            out = jax.tree.map(lambda x: x, p)  # fresh dicts, same leaves
+            for name, axis in self.family.ROW_PARALLEL.items():
+                path = ("layers",) + paths[name]
+                # stacked: the leading axis is the layer
+                _set(out, path, one_ranks_share(_get(p, path), tp, axis + 1))
+            return out
 
         shardings = jax.tree.map(lambda x: x.sharding, params)
         return jax.jit(cut, donate_argnums=0, out_shardings=shardings)(params)
 
     def first_moment_norms(self) -> dict:
         """Per-leaf (and per-block) L2 norms of Adam's first moment."""
-        return _leaf_norms(self.state.opt_state.m,
-                           self.cfg["new_decoder_architecture"])
+        return _leaf_norms(self.cfg, self.state.opt_state.m)
 
     def change_norms(self) -> dict:
         """Per-leaf norms of (parameters now - the seeded parameters), in
         one program, so the difference itself is never held."""
-        has_ln2 = self.cfg["new_decoder_architecture"]
+        cfg = self.cfg
 
         def norms(p, words):
             diff = jax.tree.map(
                 lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
                 p, self._seeded_params(words))
-            return _norms_of(neutral_leaves(diff, has_ln2))
+            return _norms_of(cfg, neutral_leaves(cfg, diff))
 
         got = jax.jit(norms)(self.state.params, self._words)
         return {k: np.asarray(v) for k, v in got.items()}
@@ -236,22 +211,24 @@ class TrainProgram:
         destroy_parallel()
 
 
-def _norms_of(leaves: dict) -> dict:
+def _norms_of(cfg: dict, leaves: dict) -> dict:
     """{neutral name: norms}: one per block for block leaves (leading
     layer axis), one for a global leaf. Traced."""
+    per_block = families.find(cfg).layer_paths(cfg)
     out = {}
     for name, x in leaves.items():
         x = jnp.square(x.astype(jnp.float32))
-        if name in LAYER_PATHS:
+        if name in per_block:
             out[name] = jnp.sqrt(jnp.sum(x.reshape(x.shape[0], -1), axis=1))
         else:
             out[name] = jnp.sqrt(jnp.sum(x))[None]
     return out
 
 
-def _leaf_norms(tree: dict, has_ln2: bool) -> dict:
-    return {k: np.asarray(v) for k, v in
-            jax.jit(_norms_of)(neutral_leaves(tree, has_ln2)).items()}
+def _leaf_norms(cfg: dict, tree: dict) -> dict:
+    got = jax.jit(lambda leaves: _norms_of(cfg, leaves))(
+        neutral_leaves(cfg, tree))
+    return {k: np.asarray(v) for k, v in got.items()}
 
 
 def kernel_fallbacks() -> int:
@@ -283,12 +260,12 @@ class ServeProgram:
     def __init__(self, cfg: dict, seed: int, use: dict | None = None,
                  made=None, mark=lambda name: None):
         from megatron_llm_tpu.inference.engine import DecodeEngine
-        from megatron_llm_tpu.models import FalconModel
 
         use = use or cfg["serve"]
         self.cfg, self.use, self.seed = cfg, use, seed
+        fam = families.find(cfg)
         self.layers = use["num_hidden_layers"]
-        self.model = FalconModel(model_config(cfg, use))
+        self.model = fam.model(cfg, use)
         # `made`: weights another engine of this process already holds
         # (the sweep builds several engines over one set of weights)
         self.made = made or self.make_weights(cfg, seed, use)
@@ -297,7 +274,7 @@ class ServeProgram:
         mark("seeded_weights")
         stacked = {name: _StackView([pl[name] for pl in per_layer])
                    for name in per_layer[0]}
-        params = program_tree(stacked, glob)
+        params = program_tree(cfg, stacked, glob)
         del per_layer, stacked
         self.engine = DecodeEngine(
             self.model, params, slots=use["slots"],
@@ -306,7 +283,7 @@ class ServeProgram:
             prefill_chunk_tokens=use["prefill_chunk_tokens"],
             prefix_cache=use["prefix_cache"], kv_dtype=use["kv_dtype"],
             warmup_compile=False, termination_id=None,
-            vocab_size=cfg["vocab_size"])
+            vocab_size=cfg["vocab_size"], **fam.engine_args(cfg, use))
         del params
         mark("engine_built")
         self.engine.warmup()  # exactly this engine's own buckets
@@ -352,8 +329,10 @@ class ServeProgram:
             use_eod_for_early_termination=False, stream=True)
 
     def counters(self) -> dict:
+        """Six short names the harness itself reads, and every numeric
+        key of `DecodeEngine.counters()` under the engine's own name."""
         c = self.engine.counters()
-        return {
+        out = {
             "steps": c["serve_steps"],
             "prefill_tokens": c["serve_prefill_tokens"],
             "admitted": c["serve_admitted"],
@@ -361,6 +340,10 @@ class ServeProgram:
             "occupancy": c["serve_slot_occupancy"],
             "queue_depth": c["serve_queue_depth"],
         }
+        out.update({k: v for k, v in c.items()
+                    if isinstance(v, (int, float))
+                    and not isinstance(v, bool)})
+        return out
 
     def kernel_fallbacks(self) -> int:
         return kernel_fallbacks()

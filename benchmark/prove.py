@@ -25,7 +25,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark import check, harness, program, traffic  # noqa: E402
 
 
-def emit(out, **row):
+def emit(out, limits=None, **row):
+    if limits is not None:
+        # under the limits file as it stands: the program's runs have to
+        # read true, a control's and a fault's false
+        row["correct"], _ = check.verdict(row["numbers"], limits)
     line = json.dumps(row)
     print(line, flush=True)
     out.write(line + "\n")
@@ -41,6 +45,7 @@ def prove_train(cell, seeds, controls, out):
     chips = cell["entry"]["chips"]
     devices = jax.devices()[:chips]
     faults = ["half_batch"] + (["no_exchange"] if chips > 1 else [])
+    limits = cell["limits"]
     for k, seed in enumerate(seeds):
         t0 = time.perf_counter()
         texts = traffic.train_batches(mix, seed, mix["reference_steps"],
@@ -49,19 +54,19 @@ def prove_train(cell, seeds, controls, out):
         got = train_cell.follow(prog, texts)
         prog.free()
         want = check.train_reference(cfg, seed, texts, devices=devices)
-        emit(out, what="program", seed=seed, loss=got["loss"],
+        emit(out, limits, what="program", seed=seed, loss=got["loss"],
              numbers=check.train_numbers(got, want),
              seconds=time.perf_counter() - t0)
         if k >= controls:
             continue
         ctl = check.train_reference(cfg, seed, texts, precision="int8",
                                     devices=devices)
-        emit(out, what="control_int8", seed=seed,
+        emit(out, limits, what="control_int8", seed=seed,
              numbers=check.train_numbers(ctl, want))
         for fault in faults:
             bad = check.train_reference(cfg, seed, texts, fault=fault,
                                         devices=devices)
-            emit(out, what="fault_" + fault, seed=seed,
+            emit(out, limits, what="fault_" + fault, seed=seed,
                  numbers=check.train_numbers(bad, want))
 
 

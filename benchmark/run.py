@@ -4,7 +4,8 @@
   python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 A new process each time: it finds the cell's configuration, traffic mix
-and per-layer metric files by the names in BENCHMARK.json, builds the
+and per-layer metric files by the names in BENCHMARK.json and the
+architecture's family by the configuration's `model_type`, builds the
 model on the device from the seed, warms up, measures for --seconds, and
 prints one JSON object as the last line of standard output. Without a
 TPU (or with fewer chips than the cell asks for) it exits non-zero and

@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from . import check, flops, harness, program, traffic
+from . import check, families, harness, program, traffic
 
 
 class Tap:
@@ -113,6 +113,7 @@ def run(cell: dict, seed: int, seconds: float, trace_on: bool, t_start: float,
     `control`; a benchmark run never computes it."""
     cfg, mix = cell["cfg"], cell["mix"]
     use = cfg["serve"]
+    fam = families.find(cfg)
     chips = cell["entry"]["chips"]
     layers, slots, vocab = use["num_hidden_layers"], use["slots"], \
         cfg["vocab_size"]
@@ -232,18 +233,24 @@ def run(cell: dict, seed: int, seconds: float, trace_on: bool, t_start: float,
         values.update(sat_ttft_p90_ms=harness.percentile(ttft, 0.9),
                       sat_tpot_p90_ms=harness.percentile(tpot, 0.9))
     values["batch_occupancy"] = 100.0 * busy_slot_s / (slots * window_s)
-    values["window_flops"] = _flops(cfg, layers, records, t_open, t_close)
+    values["window_flops"] = _flops(fam, cfg, layers, records, t_open,
+                                    t_close)
+    # every counter the engine has: its closing reading under its own
+    # name, the window's difference as `<name>.window`
+    for name, value in c_close.items():
+        values.setdefault(name, value)
+        values[name + ".window"] = value - c_open.get(name, 0)
+    traced = {}
     if trace_on:
         t0, t1 = tracer.t0, tracer.t1
         s0, s1 = tracer.snap0, tracer.snap1
         out_tr = sum(1 for rec in records for t in rec["tap"].stamps
                      if t0 <= t <= t1)
         toks = out_tr + (s1["prefill_tokens"] - s0["prefill_tokens"])
-        values["traced_wm_flops"] = (
-            2.0 * flops.matmul_params(cfg, layers, head=False) * toks
-            + 2.0 * flops.embedding_params(cfg) * out_tr)
-        values["traced_wm_bytes"] = (s1["steps"] - s0["steps"]) \
-            * flops.weight_bytes(cfg, layers)
+        # the traced part's own counters, `<name>.traced` among the values
+        traced = {name: s1[name] - s0.get(name, 0) for name in s1}
+        traced.update(tokens=toks, out_tokens=out_tr)
+        values.update({name + ".traced": v for name, v in traced.items()})
     peak = harness.memory_peak_bytes(chips)
     values["peak_hbm_gb"] = peak / 1e9 if peak else None
     device = dict(device, memory_peak_bytes=peak)
@@ -290,13 +297,8 @@ def run(cell: dict, seed: int, seconds: float, trace_on: bool, t_start: float,
     attempted = n_records if open_loop else n_finished + failed
     ok = ok and failed == 0 and bool(samples)
 
-    ctx = {"values": values, "chips": chips, "trace": trace,
-           "trace_window_s": tracer.window_s,
-           "peaks": flops.chip_peaks(device["kind"])
-           if device["platform"] == "tpu" else None}
-    if trace_on:
-        device.update(busy_s=harness.mean_busy(trace),
-                      window_s=tracer.window_s)
+    ctx = harness.reader_context(cell, use, values, traced, tracer, trace,
+                                 device)
     line = harness.result_line(
         cell, trace_on, correct=ok, attempted=attempted, failed=failed,
         values=values, device=device, ctx=ctx, compared=compared)
@@ -305,7 +307,7 @@ def run(cell: dict, seed: int, seconds: float, trace_on: bool, t_start: float,
     return line
 
 
-def _flops(cfg, layers, records, t0, t1) -> float:
+def _flops(fam, cfg, layers, records, t0, t1) -> float:
     """Model FLOPs of every prompt and output token processed in
     [t0, t1]: a prompt is booked when its first token comes out, an
     output token when it is emitted (its own forward pass follows)."""
@@ -316,9 +318,9 @@ def _flops(cfg, layers, records, t0, t1) -> float:
             continue
         p = rec["p"]
         if t0 <= st[0] <= t1:
-            total += flops.serve_span_flops(cfg, layers, 0, p, 1)
+            total += fam.serve_span_flops(cfg, layers, 0, p, 1)
         inside = [i for i, t in enumerate(st) if t0 <= t <= t1]
         if inside:
-            total += flops.serve_span_flops(
+            total += fam.serve_span_flops(
                 cfg, layers, p + inside[0], p + inside[-1] + 1, len(inside))
     return total

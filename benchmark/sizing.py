@@ -5,7 +5,7 @@ compiler accounts per device. Nothing runs; a compile that passes is not
 a chip run. Run here, before a chip call:
 
   JAX_PLATFORMS=cpu python3 benchmark/sizing.py train falcon-7b
-  JAX_PLATFORMS=cpu python3 benchmark/sizing.py train benchmark/tests/data/falcon-40b.json
+  JAX_PLATFORMS=cpu python3 benchmark/sizing.py train falcon-40b
   JAX_PLATFORMS=cpu python3 benchmark/sizing.py serve falcon-7b [slots chunk]
 """
 
@@ -21,7 +21,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from benchmark import harness, program  # noqa: E402
+from benchmark import families, harness, program, weights  # noqa: E402
 
 
 def _topo():
@@ -55,7 +55,6 @@ def _report(name, lowered):
 
 def size_train(cfg: dict):
     from megatron_llm_tpu.config import ParallelConfig, TrainConfig
-    from megatron_llm_tpu.models import FalconModel
     from megatron_llm_tpu.ops import dispatch
     from megatron_llm_tpu.optimizer.optimizer import (
         OptimizerState,
@@ -76,7 +75,7 @@ def size_train(cfg: dict):
     use.update(json.loads(os.environ.get("SIZING_OVERRIDE", "{}")))
     tp, sp = use["tensor_parallel"], use["sequence_parallel"]
     topo = _topo()
-    model = FalconModel(program.model_config(cfg, use, tp))
+    model = families.find(cfg).model(cfg, use, tp)
     ctx = initialize_parallel(tp=tp, sequence_parallel=sp,
                               devices=topo.devices[:tp])
     try:
@@ -125,7 +124,6 @@ def size_train(cfg: dict):
 
 def size_serve(cfg: dict, slots=None, chunk=None):
     from megatron_llm_tpu.inference import engine as eng
-    from megatron_llm_tpu.models import FalconModel
     from megatron_llm_tpu.ops import dispatch
 
     dispatch.on_tpu = lambda: True
@@ -134,23 +132,20 @@ def size_serve(cfg: dict, slots=None, chunk=None):
     slots = int(slots or use["slots"])
     chunk = int(chunk or use["prefill_chunk_tokens"])
     dev = SingleDeviceSharding(_topo().devices[0])
-    model = FalconModel(program.model_config(cfg, use))
+    model = families.find(cfg).model(cfg, use)
     mc = model.cfg
-    L, h, V = mc.num_layers, mc.hidden_size, mc.padded_vocab_size
+    L, V = mc.num_layers, mc.padded_vocab_size
     bf = jnp.bfloat16
 
     def arr(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=dev)
 
-    layer = {"input_norm": {"scale": arr((h,), bf), "bias": arr((h,), bf)},
-             "attention": {"wqkv": arr((h, mc.qkv_projection_size), bf),
-                           "wo": arr((mc.num_attention_heads * mc.head_dim,
-                                      h), bf)},
-             "mlp": {"w1": arr((h, mc.ffn_hidden_size), bf),
-                     "w2": arr((mc.ffn_hidden_size, h), bf)}}
-    dec = {"embedding": {"word_embeddings": arr((V, h), bf)},
-           "layers": tuple(layer for _ in range(L)),
-           "final_norm": {"scale": arr((h,), bf), "bias": arr((h,), bf)}}
+    # the decode tree's shapes: the family's seeded leaves laid out as
+    # the program lays them out, then as the engine keeps them
+    dec = jax.eval_shape(lambda: model.prepare_decode_params(
+        program.program_tree(cfg, weights.make_stacked(cfg, 0, L),
+                             weights.make_globals(cfg, 0))))
+    dec = jax.tree.map(lambda x: arr(x.shape, bf), dec)
     pages = 1 + slots * use["max_context"] // use["page_size"]
     pool = tuple(arr((pages, use["page_size"], mc.num_query_groups,
                       mc.head_dim), bf) for _ in range(L))
@@ -177,7 +172,7 @@ def size_serve(cfg: dict, slots=None, chunk=None):
         out.append(_report(
             f"mixed_step slots{slots} w{w}",
             mixed.lower(dec, pool, pool, (), (), pt, i32, logits,
-                        arr((n, w), jnp.int32), i32, arr((n,), bool),
+                        arr((w,), jnp.int32), i32, arr((n,), bool),
                         arr((), jnp.int32), *common_tail)))
     return out
 

@@ -319,16 +319,25 @@ def scope_seconds(trace: dict) -> dict:
     }
 
 
+def under(scope: str, prefix: str) -> bool:
+    """Whether the scope path holds `prefix` as whole path elements
+    (`attention`, `loss/head`), anywhere along it."""
+    return "/" + prefix.strip("/") + "/" in "/" + scope + "/"
+
+
+def scope_time(got: dict, prefix: str, phases=PHASES) -> float:
+    """Seconds, out of `scope_seconds`' answer `got`, under the scopes
+    that hold `prefix`, in the listed phases."""
+    return sum(row[p] for scope, row in got["by_scope"].items()
+               if under(scope, prefix) for p in phases)
+
+
 def scope_share(trace: dict, prefix: str, phases=PHASES) -> float | None:
-    """Share of device self time under scopes that hold `prefix` as whole
-    path elements (`attention`, `loss/head`), in the listed phases."""
+    """`scope_time` as a share of the device's self time."""
     got = scope_seconds(trace)
     if not got["total_s"]:
         return None
-    want = "/" + prefix.strip("/") + "/"
-    sec = sum(row[p] for scope, row in got["by_scope"].items()
-              if want in "/" + scope + "/" for p in phases)
-    return sec / got["total_s"]
+    return scope_time(got, prefix, phases) / got["total_s"]
 
 
 # ---------------------------------------------------------------- rounds

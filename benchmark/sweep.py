@@ -3,9 +3,9 @@
 benchmark run: its findings are written as numbers into the configuration
 and traffic files.
 
-  python3 benchmark/sweep.py shapes "8x512,16x128,32x64" [seconds]
+  python3 benchmark/sweep.py shapes "8x512,16x128,32x64" [seconds] [config]
       saturated tokens/s of the offline-batch mix for each slots x chunk
-  python3 benchmark/sweep.py rates "0.4,0.6,0.8,1.0" [seconds]
+  python3 benchmark/sweep.py rates "0.4,0.6,0.8,1.0" [seconds] [config]
       chat-steady at each fixed rate, with the configuration file's shape
 One process, one set of weights; each shape builds its own engine.
 """
@@ -18,6 +18,9 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark import harness, program, serve_cell, traffic  # noqa: E402
+
+
+DEFAULT_CONFIG = "falcon-7b"  # a by-hand tool's default, no more
 
 
 def drive(prog, mix, seed, seconds, slots, vocab):
@@ -77,7 +80,8 @@ def main():
     seconds = float(sys.argv[3]) if len(sys.argv) > 3 else 12.0
     program.enable_compile_cache()
     harness.require_chips(1)
-    cfg = harness.load_json(harness.HERE, "configs", "falcon-7b.json")
+    config = sys.argv[4] if len(sys.argv) > 4 else DEFAULT_CONFIG
+    cfg = harness.load_json(harness.HERE, "configs", config + ".json")
     vocab, seed = cfg["vocab_size"], 4242
     made = program.ServeProgram.make_weights(cfg, seed, cfg["serve"])
     if what == "shapes":

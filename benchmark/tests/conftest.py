@@ -32,6 +32,16 @@ TINY_CELLS = [
 LIKE = {"tiny-train": "falcon7b-train-2k", "tiny-chat": "falcon7b-serve-chat",
         "tiny-batch": "falcon7b-serve-batch",
         "tiny-train40-tp4": "falcon7b-train-2k"}
+# a SECOND FAMILY, added the way a later PR adds one: `second_family/`
+# holds new files only (a family module, its reference, a configuration,
+# per-layer metrics, limits); the cells reuse two mixes that are there
+SECOND_CELLS = [
+    {"name": "tiny2-train", "config": "tiny2", "traffic": "tiny-train",
+     "chips": 1, "why": "rehearsal of a second family"},
+    {"name": "tiny2-batch", "config": "tiny2", "traffic": "tiny-batch",
+     "chips": 1, "why": "rehearsal of a second family"},
+]
+SECOND_E2E = {"tiny2-train": "train_tok_s_chip", "tiny2-batch": "serve_tok_s"}
 # CPU float32-vs-bf16 readings at toy sizes; not the chip's limits
 TINY_LIMITS = {
     "train": {"loss_gap_step1": 2e-3, "loss_gap_step2": 2e-3,
@@ -94,9 +104,36 @@ def build_copy(tmp: str) -> str:
         with open(os.path.join(base, "limits", cell["name"] + ".json"),
                   "w") as f:
             json.dump({"cell": cell["name"], "limits": TINY_LIMITS[kind]}, f)
+    add_second_family(base, bench)
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return base
+
+
+def add_second_family(base: str, bench: dict):
+    """New files copied in, entries appended to the index; no file of
+    the copy is opened for writing."""
+    second = os.path.join(HERE, "second_family")
+    for sub in sorted(os.listdir(second)):
+        for f in sorted(os.listdir(os.path.join(second, sub))):
+            target = os.path.join(base, sub, f)
+            assert not os.path.exists(target), target
+            shutil.copy(os.path.join(second, sub, f), target)
+            if sub == "metrics":
+                with open(target) as g:
+                    m = json.load(g)
+                bench["per_layer"].append({k: m[k] for k in (
+                    "name", "unit", "better", "source", "layer", "moves",
+                    "workloads")})
+    bench["configs"].append({
+        "name": "tiny2", "source": "benchmark/tests",
+        "file": "benchmark/configs/tiny2.json", "reduced": [],
+        "why": "rehearsal of a second family"})
+    bench["workloads"] += SECOND_CELLS
+    for m in bench["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"] += [c for c, e in SECOND_E2E.items()
+                               if e == m["name"]]
 
 
 @pytest.fixture(scope="session")

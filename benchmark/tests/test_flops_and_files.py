@@ -8,7 +8,7 @@ import re
 
 import pytest
 
-from benchmark import flops, harness
+from benchmark import families, flops, harness
 
 ROOT = os.path.dirname(harness.HERE)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -25,40 +25,43 @@ def bench():
 
 def test_falcon_7b_hand_counts():
     c = cfg("falcon-7b")
+    fam = families.find(c)
     # block: 4544*4672 + 4544*4544 + 2*4544*18176 + 2*4544 (one norm)
-    assert flops.layer_params(c) == 21229568 + 20647936 + 165183488 + 9088
-    assert flops.embedding_params(c) == 65024 * 4544
-    assert round(flops.n_params(c, 32) / 1e9, 2) == 6.92
-    assert round(flops.n_params(c, 2) / 1e6, 1) == 709.6
-    assert flops.kv_bytes_per_token(c, 32) == 8192
-    assert round(flops.weight_bytes(c, 32) / 1e9, 2) == 13.84
+    assert fam.layer_params(c) == 21229568 + 20647936 + 165183488 + 9088
+    assert fam.embedding_params(c) == 65024 * 4544
+    assert round(fam.n_params(c, 32) / 1e9, 2) == 6.92
+    assert round(fam.n_params(c, 2) / 1e6, 1) == 709.6
+    assert fam.kv_bytes_per_token(c, 32) == 8192
+    assert round(fam.weight_bytes(c, 32) / 1e9, 2) == 13.84
 
 
 def test_falcon_40b_hand_counts():
-    # the configuration prepared for the four-chip cell (PERF.md section 7)
-    c = harness.load_json(harness.HERE, "tests", "data", "falcon-40b.json")
-    assert round(flops.layer_params(c) / 1e6, 1) == 679.5
-    assert round(flops.embedding_params(c) / 1e6, 1) == 532.7
-    assert round(flops.n_params(c, 4) / 1e9, 2) == 3.25
-    assert round(flops.n_params(c, 3) / 1e9, 2) == 2.57
+    c = cfg("falcon-40b")
+    fam = families.find(c)
+    assert round(fam.layer_params(c) / 1e6, 1) == 679.5
+    assert round(fam.embedding_params(c) / 1e6, 1) == 532.7
+    assert round(fam.n_params(c, 4) / 1e9, 2) == 3.25
+    assert round(fam.n_params(c, 3) / 1e9, 2) == 2.57
 
 
 def test_train_flops_per_token():
     c = cfg("falcon-7b")
+    fam = families.find(c)
     n = 2 * (21229568 + 20647936 + 165183488) + 65024 * 4544
-    assert flops.train_flops_per_token(c, 2, 2048) == \
+    assert fam.train_flops_per_token(c, 2, 2048) == \
         6.0 * n + 6.0 * 2 * 4544 * 2048
     # attention alone: 4 FLOPs a (query, key, channel) forward, 3x with
     # the backward, halved by the causal mask
-    assert flops.train_attention_flops(c, 2, 2048, 4096) == \
+    assert fam.train_attention_flops(c, 2, 2048, 4096) == \
         4 * 3 * 0.5 * 2 * 4544 * 2048 * 4096
 
 
 def test_serve_span_is_the_sum_of_its_tokens():
     c = cfg("falcon-7b")
-    one_by_one = sum(flops.serve_token_flops(c, 32, p, p >= 7)
+    fam = families.find(c)
+    one_by_one = sum(fam.serve_token_flops(c, 32, p, p >= 7)
                      for p in range(3, 10))
-    assert flops.serve_span_flops(c, 32, 3, 10, 3) == \
+    assert fam.serve_span_flops(c, 32, 3, 10, 3) == \
         pytest.approx(one_by_one, rel=1e-12)
 
 
@@ -183,3 +186,39 @@ def test_configs_state_every_change():
         assert body["ffn_hidden_size"] == 4 * body["hidden_size"]
         for key in c["reduced"]:
             assert key in body["published"]
+
+
+def _four_chip_readings():
+    path = os.path.join(harness.HERE, "tests", "data",
+                        "train4c_readings.jsonl")
+    with open(path) as f:
+        return [json.loads(line) for line in f]
+
+
+@pytest.mark.parametrize("what,fails", [
+    ("program", None),
+    ("control_int8", {"loss_gap_step2", "loss_gap_step3", "grad_norm_gap",
+                      "change_norm_gap"}),
+    ("fault_half_batch", {"loss_gap_step1", "grad_norm_gap",
+                          "change_norm_gap"}),
+    ("fault_no_exchange", {"loss_gap_step1", "grad_norm_gap",
+                           "change_norm_gap"})])
+def test_four_chip_limits_part_the_chips_own_readings(what, fails):
+    """Every reading taken on the four chips (my chip runs, PR 28:
+    `prove.py` on 8 seeds, the cell's 13 runs), through the cell's own
+    `verdict` under the limits file as committed: each run of the program
+    comes out correct, the int8 control and each planted fault on every
+    seed not, and each fails at least the numbers the limits file says it
+    is held against."""
+    from benchmark import check
+
+    limits = harness.load_json(harness.HERE, "limits",
+                               "falcon40b-train-4chip.json")["limits"]
+    rows = [r for r in _four_chip_readings() if r["what"] == what]
+    assert len(rows) >= 3
+    for row in rows:
+        ok, compared = check.verdict(row["numbers"], limits)
+        assert ok == (fails is None), row
+        if fails:
+            over = {k for k, c in compared.items() if c["value"] > c["limit"]}
+            assert fails <= over, (row["seed"], over)

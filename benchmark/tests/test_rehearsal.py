@@ -32,7 +32,8 @@ def run_cell(tiny_base, name, trace=False, seconds=1.0, **kw):
 
 
 @pytest.mark.parametrize("name", ["tiny-train", "tiny-chat", "tiny-batch",
-                                  "tiny-train40-tp4"])
+                                  "tiny-train40-tp4", "tiny2-train",
+                                  "tiny2-batch"])
 def test_last_line_end_to_end(tiny_base, name):
     cell, line = run_cell(tiny_base, name)
     assert list(line)[:5] == KEYS and list(line)[-1] == "compared"
@@ -59,9 +60,37 @@ def test_last_line_traced(tiny_base, name):
     assert {"busy_s", "window_s"} <= set(line["device"])
 
 
+@pytest.mark.parametrize("name,excerpt,metric,value", [
+    ("tiny2-train", "train_scopes_excerpt", "optimizer_share.tiny2", 9.4510),
+    ("tiny2-batch", "serve_excerpt", "rows_useful_share.tiny2", None)])
+def test_second_family_traced(tiny_base, monkeypatch, name, excerpt, metric,
+                              value):
+    """The second family's cells, traced, with a trace recorded on the
+    chip put in the place of the CPU's (which has no device plane): the
+    metrics its new files bring, read by the new readers, are on the
+    line."""
+    from benchmark import trace_reduce
+
+    recorded = trace_reduce.load_excerpt(os.path.join(
+        harness.HERE, "tests", "data", excerpt + ".json.gz"))
+    monkeypatch.setattr(harness.TraceWindow, "finish",
+                        lambda self: self._thread.join() or recorded)
+    cell, line = run_cell(tiny_base, name, trace=True)
+    assert line["correct"] is True
+    assert metric in line["metrics"]
+    # the one file without a `workloads` list covers a cell added later
+    assert line["metrics"]["compiles_in_window"]["value"] == 0
+    got = line["metrics"][metric]["value"]
+    assert 0 < got <= 100
+    if value is not None:
+        assert got == pytest.approx(value, abs=1e-3)
+    assert line["device"]["busy_s"] > 0
+
+
 @pytest.mark.parametrize("name,fault", [
     ("tiny-train", "state_unchanged"), ("tiny-train", "half_batch"),
-    ("tiny-train40-tp4", "no_exchange")])
+    ("tiny-train40-tp4", "no_exchange"), ("tiny2-train", "half_batch"),
+    ("tiny2-train", "state_unchanged")])
 def test_training_faults_are_caught(tiny_base, name, fault):
     _, line = run_cell(tiny_base, name, fault=fault)
     assert line["correct"] is False
@@ -83,8 +112,9 @@ def test_faults_planted_in_the_reference_read_far(tiny_base, fault):
     assert not ok
 
 
-def test_altered_token_is_caught(tiny_base):
-    _, line = run_cell(tiny_base, "tiny-chat", fault="token_altered")
+@pytest.mark.parametrize("name", ["tiny-chat", "tiny2-batch"])
+def test_altered_token_is_caught(tiny_base, name):
+    _, line = run_cell(tiny_base, name, fault="token_altered")
     assert line["correct"] is False
 
 
@@ -115,13 +145,14 @@ def test_int8_control_fails_serving(tiny_base):
     assert not ok
 
 
-def test_int8_control_fails_training(tiny_base):
+@pytest.mark.parametrize("name", ["tiny-train", "tiny2-train"])
+def test_int8_control_fails_training(tiny_base, name):
     """The control in the program's place: the int8 reference against
-    the float32 one, under the tiny cell's limits."""
+    the float32 one, under the tiny cell's limits; each family's."""
     from benchmark import check, traffic
 
     tmp = os.path.dirname(tiny_base)
-    cell = harness.load_cell("tiny-train",
+    cell = harness.load_cell(name,
                              os.path.join(tmp, "BENCHMARK.json"), tiny_base)
     texts = traffic.train_batches(cell["mix"], 5, 3, cell["cfg"]["vocab_size"])
     want = check.train_reference(cell["cfg"], 5, texts)

@@ -17,7 +17,7 @@ import math
 import sys
 import time
 
-from . import check, flops, harness, program, traffic
+from . import check, families, harness, program, traffic
 
 WARM_MIN, WARM_MAX, WARM_AGREE = 3, 12, 0.02
 
@@ -42,6 +42,7 @@ def run(cell: dict, seed: int, seconds: float, trace_on: bool, t_start: float,
         device: dict, stages=None, fault: str | None = None) -> dict:
     cfg, mix = cell["cfg"], cell["mix"]
     use = cfg["train"]
+    fam = families.find(cfg)
     chips = cell["entry"]["chips"]
     layers, seq = use["num_hidden_layers"], mix["seq_length"]
     tokens_per_step = mix["rows_per_step"] * seq
@@ -102,7 +103,7 @@ def run(cell: dict, seed: int, seconds: float, trace_on: bool, t_start: float,
     step_ms = [(e - s) * 1e3 for s, e in
                zip([t_open] + ends[:done - 1], ends[:done])]
     tok_s_chip = done * tokens_per_step / window_s / chips
-    fpt = flops.train_flops_per_token(cfg, layers, seq)
+    fpt = fam.train_flops_per_token(cfg, layers, seq)
     values = {
         "train_tok_s_chip": tok_s_chip,
         "setup_s": setup_s,
@@ -113,17 +114,20 @@ def run(cell: dict, seed: int, seconds: float, trace_on: bool, t_start: float,
         "window_flops": fpt * done * tokens_per_step,
         "window_s": window_s,
     }
+    # what the last step's stats carry beside the loss: read once, here,
+    # after the window has closed
+    for name, value in prog.stats().items():
+        values.setdefault(name, value)
+    traced = {}
     if trace_on:
         # the traced part's own steps: fence to fence inside the trace
         inside = [e for e in ends[:done] if tracer.t0 <= e <= tracer.t1]
         if len(inside) >= 2:
             k = len(inside) - 1
-            values["traced_flops"] = fpt * k * tokens_per_step
-            values["traced_s"] = inside[-1] - inside[0]
-            values["traced_attn_flops"] = flops.train_attention_flops(
-                cfg, layers, seq, k * tokens_per_step)
-            values["traced_attn_bytes"] = flops.train_attention_bytes(
-                cfg, layers, seq, k * tokens_per_step)
+            traced = {"steps": k, "tokens": k * tokens_per_step,
+                      "seq_length": seq, "seconds": inside[-1] - inside[0]}
+            values["traced_flops"] = fpt * traced["tokens"]
+            values["traced_s"] = traced["seconds"]
     peak = harness.memory_peak_bytes(chips)
     values["peak_hbm_gb"] = peak / 1e9 if peak else None
     device = dict(device, memory_peak_bytes=peak)
@@ -152,13 +156,8 @@ def run(cell: dict, seed: int, seconds: float, trace_on: bool, t_start: float,
     ok, compared = check.verdict(numbers, cell["limits"])
     ok = ok and bad == 0 and done > 0
 
-    ctx = {"values": values, "chips": chips, "trace": trace,
-           "trace_window_s": tracer.window_s,
-           "peaks": flops.chip_peaks(device["kind"])
-           if device["platform"] == "tpu" else None}
-    if trace_on:
-        device.update(busy_s=harness.mean_busy(trace),
-                      window_s=tracer.window_s)
+    ctx = harness.reader_context(cell, use, values, traced, tracer, trace,
+                                 device)
     return harness.result_line(
         cell, trace_on, correct=ok, attempted=done, failed=bad,
         values=values, device=device, ctx=ctx, compared=compared)
