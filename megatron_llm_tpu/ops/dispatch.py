@@ -1,8 +1,8 @@
 """Kernel-or-reference dispatch shared by the Pallas entry points in ops/.
 
 Every entry point takes `use_pallas` (None = "on a TPU backend") and runs
-a static gate that may still say no (head dim not lane-aligned, no block
-that divides the shape, ...), in which case the numerically matching XLA
+a static gate that may still say no (no block that divides the shape,
+a paged kernel's head dim, ...), in which case the numerically matching XLA
 reference serves the call. Off the TPU that is the normal path (the CPU
 suite relies on it) and nothing is recorded. On a TPU backend a call that
 asked for a kernel and did not get one is never silent: it is logged once

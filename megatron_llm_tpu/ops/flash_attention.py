@@ -17,6 +17,13 @@ saves only O and the per-row logsumexp; the backward recomputes the score
 blocks and accumulates dq (grid over q blocks) and dk/dv (grid over k
 blocks) in fp32 VMEM scratch, with delta = rowsum(dO * O) precomputed.
 
+A head width that is not a multiple of the 128-lane tile (Falcon's 64)
+is zero-padded to the next multiple around the kernel calls and the
+outputs cut back (`_pad_lanes`): zero columns add nothing to q . k and
+come out as zero columns of o, dq, dk, dv, and the softmax scale stays
+that of the TRUE width. A lane-aligned head pads by nothing and takes
+the same path.
+
 `flash_attention` dispatches to the Pallas kernels on TPU and to a
 numerically identical XLA fallback elsewhere; `interpret=True` runs the
 real kernels through the Pallas interpreter (used by the CPU test suite).
@@ -73,6 +80,17 @@ MAX_ROWS = 2048
 # backward holds two such blocks) — keeps wide-GQA shapes inside VMEM now
 # that the default block_k is 1024
 MAX_CELLS = 1 << 20
+LANES = 128  # the kernels split the lane axis into (head, d): d % LANES == 0
+
+
+def _pad_lanes(*xs):
+    """Zero-pad each operand's head axis (the last) to the next multiple
+    of the lane tile; a lane-aligned head comes back as it is."""
+    pad = -xs[0].shape[-1] % LANES
+    if not pad:
+        return xs
+    return tuple(jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+                 for x in xs)
 
 
 def _xla_reference(q, k, v, causal: bool):
@@ -310,10 +328,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 def _flash_fwd_pallas(q, k, v, causal, block_q, block_k, interpret=False):
     """q: (b, s, g, qpk, d); k,v: (b, t, g, d).
     Returns (o (b,s,g,qpk,d), lse (b*g, s*qpk, 1) fp32 rows-major)."""
-    b, s, g, qpk, d = q.shape
+    b, s, g, qpk, d_true = q.shape
     t = k.shape[1]
-    sm_scale = 1.0 / (d ** 0.5)
+    sm_scale = 1.0 / (d_true ** 0.5)  # of the true width, not the padded
     assert s % block_q == 0 and t % block_k == 0
+    q, k, v = _pad_lanes(q, k, v)
+    d = q.shape[-1]
 
     qf = q.transpose(0, 2, 1, 3, 4).reshape(b * g, s, qpk * d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * g, t, d)
@@ -368,7 +388,8 @@ def _flash_fwd_pallas(q, k, v, causal, block_q, block_k, interpret=False):
         ),
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(b, g, s, qpk, d).transpose(0, 2, 1, 3, 4), lse
+    out = out.reshape(b, g, s, qpk, d)[..., :d_true]
+    return out.transpose(0, 2, 1, 3, 4), lse
 
 
 # ---------------------------------------------------------------------------
@@ -507,14 +528,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q, block_k,
                       interpret=False, dlse_rows=None):
-    b, s, g, qpk, d = q.shape
+    b, s, g, qpk, d_true = q.shape
     t = k.shape[1]
-    sm_scale = 1.0 / (d ** 0.5)
+    sm_scale = 1.0 / (d_true ** 0.5)
+    q, k, v, do_p = _pad_lanes(q, k, v, do)
+    d = q.shape[-1]
 
     qf = q.transpose(0, 2, 1, 3, 4).reshape(b * g, s, qpk * d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * g, t, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * g, t, d)
-    dof = do.transpose(0, 2, 1, 3, 4).reshape(b * g, s, qpk * d)
+    dof = do_p.transpose(0, 2, 1, 3, 4).reshape(b * g, s, qpk * d)
     # delta = rowsum(dO * O) — one fused elementwise reduce, XLA does this
     # as well as a kernel would (ref FA2 preprocess step); rows-major layout
     # matching lse
@@ -605,9 +628,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, block_q, block_k,
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
-    dq = dq.reshape(b, g, s, qpk, d).transpose(0, 2, 1, 3, 4)
-    dk = dk.reshape(b, g, t, d).transpose(0, 2, 1, 3)
-    dv = dv.reshape(b, g, t, d).transpose(0, 2, 1, 3)
+    dq = dq.reshape(b, g, s, qpk, d)[..., :d_true].transpose(0, 2, 1, 3, 4)
+    dk = dk.reshape(b, g, t, d)[..., :d_true].transpose(0, 2, 1, 3)
+    dv = dv.reshape(b, g, t, d)[..., :d_true].transpose(0, 2, 1, 3)
     return dq, dk, dv
 
 
@@ -703,8 +726,8 @@ def flash_attention_with_lse(
     building block for merging attention across blocks that live on
     different devices (ring attention's per-hop step)."""
     if want_kernel(use_pallas, interpret):
-        blocks = _pick_blocks(q.shape[1], k.shape[1], q.shape[-1],
-                              q.shape[3], block_q, block_k)
+        blocks = _pick_blocks(q.shape[1], k.shape[1], q.shape[3],
+                              block_q, block_k)
         if blocks is not None:
             note_kernel("flash_attention")
             return _flash_lse((causal, *blocks, interpret), q, k, v)
@@ -718,7 +741,7 @@ def flash_reaches_kernel(q_shape, t: int) -> bool:
     site under a mesh must know, since only the Mosaic call needs a fully
     manual region (parallel/mesh.shard_kernel)."""
     return want_kernel(None) and _pick_blocks(
-        q_shape[1], t, q_shape[-1], q_shape[3], DEFAULT_BLOCK_Q,
+        q_shape[1], t, q_shape[3], DEFAULT_BLOCK_Q,
         DEFAULT_BLOCK_K) is not None
 
 
@@ -727,10 +750,11 @@ def _report_no_blocks(q, k):
                     t=k.shape[1], qpk=q.shape[3], d=q.shape[-1])
 
 
-def _pick_blocks(s, t, d, qpk, block_q, block_k):
+def _pick_blocks(s, t, qpk, block_q, block_k):
     """Shared block selection for both entry points: shrink to divisors,
-    bound the fp32 score block rows*block_k under VMEM (MAX_CELLS), gate
-    on lane alignment. Returns (bq, bk) or None for the XLA fallback."""
+    bound the fp32 score block rows*block_k under VMEM (MAX_CELLS).
+    Returns (bq, bk) or None for the XLA fallback. The head width is no
+    gate: the kernel wrappers pad it to the lane tile (`_pad_lanes`)."""
     bq = _choose_block(s, block_q, qpk)
     bk = _choose_block(t, block_k)
     while (bq is not None and bk is not None and bk > 128
@@ -739,7 +763,7 @@ def _pick_blocks(s, t, d, qpk, block_q, block_k):
     while (bq is not None and bk is not None
            and bq * qpk * bk > MAX_CELLS and bq * qpk > 256):
         bq = _choose_block(s, bq // 2, qpk)
-    if bq is None or bk is None or d % 128 != 0:
+    if bq is None or bk is None:
         return None
     return bq, bk
 
@@ -766,8 +790,8 @@ def flash_attention(
     from jax.ad_checkpoint import checkpoint_name
 
     if want_kernel(use_pallas, interpret):
-        blocks = _pick_blocks(q.shape[1], k.shape[1], q.shape[-1],
-                              q.shape[3], block_q, block_k)
+        blocks = _pick_blocks(q.shape[1], k.shape[1], q.shape[3],
+                              block_q, block_k)
         if blocks is not None:
             note_kernel("flash_attention")
             return checkpoint_name(

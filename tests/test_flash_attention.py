@@ -10,17 +10,26 @@ flash-attn (ref transformer.py:508-523) with the external flash_attn
 package's numerics.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from conftest import kernel_interpret_mode
+
 from megatron_llm_tpu.ops.flash_attention import (
     _choose_block,
     _xla_reference,
+    _xla_reference_with_lse,
     flash_attention,
+    flash_attention_with_lse,
 )
+
+# `megatron_llm_tpu.ops.flash_attention` as an attribute is the FUNCTION
+# (the package re-exports it); this is the module
+flash_module = importlib.import_module("megatron_llm_tpu.ops.flash_attention")
 
 INTERPRET = kernel_interpret_mode()
 
@@ -42,7 +51,7 @@ def _flash_interp(q, k, v, causal=True, block_q=64, block_k=64):
     )
 
 
-# d=128 keeps the kernel's lane-alignment dispatch condition satisfied
+# d=128: the lane-aligned head, nothing padded (TestNarrowHead has 64 and 80)
 CASES = [
     # (g, qpk) : MHA, GQA, MQA
     pytest.param(4, 1, id="mha"),
@@ -127,6 +136,87 @@ class TestBackward:
             np.asarray(got, np.float32), np.asarray(ref, np.float32),
             rtol=0.1, atol=0.5,
         )
+
+
+class TestNarrowHead:
+    """A head that is not a multiple of the 128-lane tile (Falcon's 64,
+    an odd 80) is zero-padded around the kernels and cut back: outputs,
+    all three gradients and the lse equal the XLA reference's, whose
+    softmax scale is that of the TRUE width."""
+
+    @pytest.mark.parametrize("d", [64, 80])
+    @pytest.mark.parametrize("g,qpk", [pytest.param(1, 8, id="mqa"),
+                                       pytest.param(2, 4, id="gqa")])
+    def test_output_and_grads_match_xla(self, g, qpk, d):
+        q, k, v = _rand_qkv(2, 128, g, qpk, d, seed=4)
+        np.testing.assert_allclose(
+            np.asarray(_flash_interp(q, k, v)),
+            np.asarray(_xla_reference(q, k, v, True)), rtol=1e-5, atol=1e-5)
+
+        def loss(attend):
+            return lambda q, k, v: jnp.sum(jnp.square(attend(q, k, v)))
+
+        ref = jax.grad(loss(lambda q, k, v: _xla_reference(q, k, v, True)),
+                       argnums=(0, 1, 2))(q, k, v)
+        got = jax.grad(loss(_flash_interp), argnums=(0, 1, 2))(q, k, v)
+        for r, f, name in zip(ref, got, "qkv"):
+            assert f.shape == r.shape
+            np.testing.assert_allclose(np.asarray(f), np.asarray(r),
+                                       rtol=1e-4, atol=1e-4,
+                                       err_msg=f"d{name}")
+
+    @pytest.mark.parametrize("d", [64, 80])
+    @pytest.mark.parametrize("g,qpk", [pytest.param(1, 8, id="mqa"),
+                                       pytest.param(2, 4, id="gqa")])
+    def test_lse_and_grads_through_it(self, g, qpk, d):
+        q, k, v = _rand_qkv(1, 128, g, qpk, d, seed=5)
+
+        def kernel(q, k, v):
+            return flash_attention_with_lse(
+                q, k, v, causal=True, use_pallas=True, interpret=INTERPRET,
+                block_q=64, block_k=64)
+
+        def xla(q, k, v):
+            return _xla_reference_with_lse(q, k, v, True)
+
+        (o1, l1), (o2, l2) = kernel(q, k, v), xla(q, k, v)
+        np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
+                                   rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
+                                   rtol=1e-5, atol=1e-5)
+
+        def obj(impl):
+            def f(q, k, v):
+                o, lse = impl(q, k, v)
+                return (o ** 2).sum() + jnp.sin(lse).sum()
+            return f
+
+        g1 = jax.grad(obj(kernel), argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(obj(xla), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5)
+
+    def test_lane_aligned_head_is_not_padded(self, monkeypatch):
+        """A 128-wide call takes the path it took: nothing is padded in
+        the forward or the backward, and the result is bitwise the
+        kernel's own on the same operands. (The shapes are this test's
+        alone: `flash_attention` is jitted, a cached trace pads nothing.)"""
+        pads = []
+        real_pad = jnp.pad
+        monkeypatch.setattr(
+            flash_module.jnp, "pad",
+            lambda x, *a, **kw: pads.append(x.shape) or real_pad(x, *a, **kw))
+        q, k, v = _rand_qkv(1, 128, 2, 2, 128, seed=6)
+        jax.grad(lambda q: jnp.sum(_flash_interp(q, k, v)))(q)
+        assert not pads
+        direct, _ = flash_module._flash_fwd_pallas(
+            q, k, v, True, 64, 64, interpret=INTERPRET)
+        np.testing.assert_array_equal(
+            np.asarray(_flash_interp(q, k, v)), np.asarray(direct))
+        q64, k64, v64 = _rand_qkv(1, 128, 2, 2, 64, seed=6)
+        _flash_interp(q64, k64, v64)
+        assert pads == [(1, 128, 2, 2, 64), (1, 128, 2, 64), (1, 128, 2, 64)]
 
 
 class TestBlockChooser:

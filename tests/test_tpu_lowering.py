@@ -60,16 +60,42 @@ def compile_on(topo, fn, *shapes):
     return lowered.as_text().count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("g,qpk", [(4, 1), (2, 4)])
-def test_flash_fwd_bwd(topo, g, qpk):
+@pytest.mark.parametrize("b,s,g,qpk,d", [
+    (1, 1024, 4, 1, D), (1, 1024, 2, 4, D),
+    # the two training cells' shapes a chip: head 64 is zero-padded to
+    # the lane tile inside ops/flash_attention.py
+    pytest.param(2, 2048, 1, 71, 64, id="falcon7b-mqa-head64"),
+    pytest.param(2, 2048, 2, 16, 64, id="falcon40b-tp4-gqa-head64")])
+def test_flash_fwd_bwd(topo, reported, b, s, g, qpk, d):
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(
             jnp.float32).sum()
 
     n = compile_on(topo, jax.grad(loss, argnums=(0, 1, 2)),
-                   ((1, 1024, g, qpk, D), BF16), ((1, 1024, g, D), BF16),
-                   ((1, 1024, g, D), BF16))
+                   ((b, s, g, qpk, d), BF16), ((b, s, g, d), BF16),
+                   ((b, s, g, d), BF16))
     assert n == 3  # forward, dq, dk/dv
+    assert not reported()
+
+
+def test_flash_with_lse_head64(topo, reported):
+    """A ring hop at head 64 (cp > 1) reaches the kernel too, so
+    `_ring_dispatch` wraps it as it does a 128-wide one."""
+    from megatron_llm_tpu.ops.flash_attention import (
+        flash_attention_with_lse,
+        flash_reaches_kernel,
+    )
+
+    def loss(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, causal=False)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    q = (1, 1024, 2, 16, 64)
+    assert flash_reaches_kernel(q, 1024)
+    n = compile_on(topo, jax.grad(loss, argnums=(0, 1, 2)), (q, BF16),
+                   ((1, 1024, 2, 64), BF16), ((1, 1024, 2, 64), BF16))
+    assert n == 3
+    assert not reported()
 
 
 @pytest.mark.parametrize("g,qpk", [(32, 1), (8, 4), (1, 8)])
@@ -223,11 +249,18 @@ def compile_train_step(topo, dp=1, pp=1, cp=1, tp=1, sp=False,
         destroy_parallel()
 
 
-def test_train_step_tp4_sp_flash(topo):
+@pytest.mark.parametrize("heads", [
+    pytest.param({}, id="head128"),
+    pytest.param(dict(num_attention_heads=8, num_attention_heads_kv=4),
+                 id="head64-gqa")])
+def test_train_step_tp4_sp_flash(topo, reported, heads):
     """The layout in finetune.py's docstring, with the flash kernel in
-    the step: GSPMD refuses a bare Mosaic call under this mesh."""
-    n, _ = compile_train_step(topo, tp=4, sp=True)
+    the step: GSPMD refuses a bare Mosaic call under this mesh. At head
+    64 (the four-chip cell's form: grouped K/V, one group a chip) the
+    kernel is in the step as well."""
+    n, _ = compile_train_step(topo, tp=4, sp=True, **heads)
     assert n >= 3
+    assert not reported()
 
 
 @pytest.mark.slow
