@@ -569,7 +569,7 @@ def default_paths(root: str) -> List[str]:
             for f in sorted(filenames):
                 if f.endswith(".py"):
                     out.append(os.path.join(dirpath, f))
-    for f in ("bench.py", "verify_correctness.py", "finetune.py",
+    for f in ("verify_correctness.py", "finetune.py",
               "pretrain_bert.py", "pretrain_t5.py", "pretrain_ict.py"):
         p = os.path.join(root, f)
         if os.path.exists(p):

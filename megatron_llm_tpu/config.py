@@ -299,7 +299,7 @@ class ParallelConfig:
     # Opt-in EQuARX-style int8 gradient reduction (ops/quantization
     # conventions: symmetric RTN, per-chunk fp32 scales, fp32
     # accumulation of dequantized partials). Default OFF: the fp path
-    # is bitwise-unchanged; drift is measured in bench extra.zero1, not
+    # is bitwise-unchanged; drift is bounded by tests/test_zero1.py, not
     # assumed. Requires use_distributed_optimizer on a pure-dp mesh.
     quantized_grad_reduce: bool = False
     # Collective overlap scheduling (ISSUE 12). Both default OFF: the
@@ -677,7 +677,8 @@ def llama_config(
         # Train through the Pallas flash kernel by default, like the
         # reference trains Llama through FlashAttention-2
         # (ref: transformer.py:508-523); proven to compile under Mosaic on
-        # TPU and to beat the XLA path (tests/test_flash_attention.py + bench).
+        # TPU and to beat the XLA path (tests/test_flash_attention.py;
+        # PERF.md, PR 32, both training cells).
         use_flash_attn=True,
     )
     cfg.update(overrides)

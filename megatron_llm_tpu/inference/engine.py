@@ -97,7 +97,7 @@ their pages, and the host-side refcount/eviction accounting never sees
 a dtype), and `quantize_weights=True` swaps the decode GEMV weights for
 one-shot weight-only int8. Both default OFF: the fp path keeps its
 bitwise generate_tokens parity; the int8 path's accuracy is a measured
-drift bound (bench `extra.quant`, docs/GUIDE.md "Quantized serving").
+drift bound (tests/test_quantization.py, docs/GUIDE.md "Quantized serving").
 
 ISSUE 14 grows the engine a mesh axis and a fleet: `serving_tp > 1`
 shards the page pools (and scale pools) over the head/group axis and
@@ -896,8 +896,8 @@ class DecodeEngine:
       scale pools (quantized at write time in the scatter paths,
       dequantized in-register by the paged kernels / on the gathered
       view by the XLA twins) — roughly half the pool bytes/token and
-      half the decode kernels' cache traffic, at a measured (bench
-      `extra.quant`) greedy logprob drift. bf16 keeps the bitwise
+      half the decode kernels' cache traffic, at a bounded
+      (tests/test_quantization.py) greedy logprob drift. bf16 keeps the bitwise
       generate_tokens parity contract.
     - `quantize_weights` (default False): one-shot weight-only int8 of
       the decode GEMV weights (per-output-channel scales,

@@ -4,16 +4,15 @@ replicas (ISSUE 14).
 A single engine — even tp-sharded — caps out at one mesh's throughput;
 the next scaling axis is N independent replicas behind a dispatcher.
 The interesting routing decision is CACHE-AWARE: production traffic is
-dominated by shared system prompts (the `extra.serving.prefix` bench
-mix), and each replica's `PrefixCache` (inference/prefix_cache.py)
+dominated by shared system prompts, and each replica's `PrefixCache` (inference/prefix_cache.py)
 holds the shared pages of exactly the prompts IT has served. Random or
 round-robin dispatch scatters a shared prefix across every replica —
 each one pays the full prefill once and caches a private copy; routing
 by prefix affinity sends a prompt to the replica that already holds its
 longest page-aligned prefix, so the fleet prefills each shared prefix
 roughly once and TTFT on shared traffic collapses toward the cache-hit
-floor (bench `extra.serving.scaleout` measures affinity-vs-random p95
-TTFT on the 80%-shared mix).
+floor (no cell of `benchmark/` runs a router: not measured on the chip;
+tests/test_router.py pins that affinity concentrates the prefix).
 
 Design (each rule is load-bearing):
 

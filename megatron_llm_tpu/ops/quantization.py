@@ -31,9 +31,10 @@ no zero point — K/V and weights are zero-centered), dequantized error
 <= scale/2 per element. An all-zero row quantizes to zeros with scale
 0 and dequantizes to exact zeros (no NaN path). EQuARX (PAPERS.md)
 motivates the "cheap symmetric scheme + fp32 accumulation" choice;
-accuracy is measured, not assumed: bench.py `extra.quant` reports max
-greedy logprob drift vs the bf16 path in-row, and docs/GUIDE.md
-"Quantized serving" states the contract.
+accuracy is measured, not assumed: tests/test_quantization.py bounds the
+greedy logprob drift vs the fp path (no cell of `benchmark/` serves int8:
+its speed is not measured on the chip), and docs/GUIDE.md "Quantized
+serving" states the contract.
 """
 
 from __future__ import annotations

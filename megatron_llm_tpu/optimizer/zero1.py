@@ -48,7 +48,7 @@ us, not inferred by GSPMD:
   and the dp partials are dequantized and accumulated in fp32
   (EQuARX, PAPERS.md: cheap symmetric scheme + fp32 accumulation).
   ~3.9x less gradient wire traffic; accuracy is MEASURED, not assumed
-  (bench extra.zero1 reports >=50-step loss-trajectory drift).
+  (tests/test_zero1.py bounds the loss-trajectory drift).
 
 Numerics contract (pinned by tests/test_zero1.py): with quantization
 OFF, the explicit path is BITWISE identical to the replicated-Adam

@@ -17,7 +17,7 @@ Three pieces, one contract:
 ISSUE 15 adds the device-cost layer on top:
 
 - `chipspec.ChipSpec` / `detect_chip` — the TPU generation spec table
-  (per-chip peak FLOP/s, HBM bytes/s) bench and the runtime both read;
+  (per-chip peak FLOP/s, HBM bytes/s) the runtime gauges read;
 - `costs.CostRegistry` — compiled-cost capture (cost_analysis FLOPs /
   bytes + memory_analysis temp/args) at jit-mint time, keyed by
   compile-contract name + specialization;

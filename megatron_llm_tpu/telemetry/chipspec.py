@@ -1,15 +1,13 @@
 """TPU generation spec table: the ONE source of peak-FLOP/s and HBM
 numbers for MFU and roofline math (ISSUE 15).
 
-Before this module, the hardware peaks lived as constants inside
-bench.py (`V5E_PEAK_BF16`, `V5E_HBM_BYTES_S`) — invisible at runtime,
-so nothing live could say "this step ran at 54% MFU" or "this decode
-round achieved 62% of HBM line rate". The pjit-TPUv4 paper (PAPERS.md)
-makes hardware utilization the headline metric for exactly this class
-of system; that requires the peaks to be a runtime fact, not a bench
-comment. Both bench and the runtime (trainer goodput ledger, engine
-dispatch-overhead gauge, CostRegistry roofline math) now read THIS
-table.
+The pjit-TPUv4 paper (PAPERS.md) makes hardware utilization the
+headline metric for exactly this class of system; that requires the
+peaks to be a runtime fact. The runtime (trainer goodput ledger, engine
+dispatch-overhead gauge, CostRegistry roofline math) reads THIS table.
+The yardstick keeps its own peaks and FLOP formulas with the benchmark
+(`benchmark/flops.py`): the cells' `train_mfu` / `serve_mfu` come from
+there, not from here.
 
 Detection reads `jax.devices()[0].device_kind` (lazy jax import — this
 module itself stays import-light for the telemetry package). Because
@@ -47,7 +45,7 @@ class ChipSpec:
     """Per-chip peaks for one TPU generation.
 
     `source` records how this spec was chosen ("detected" or
-    "override") so every gauge/bench row that cites it can state
+    "override") so every gauge that cites it can state
     whether the denominator was read from the device or asserted by the
     operator.
     """
@@ -146,9 +144,8 @@ def train_flops_per_token(n_params: int, num_layers: int,
                           hidden_size: int, seq_length: int) -> float:
     """fwd+bwd model FLOPs per trained token: 6*N for the matmuls plus
     causal attention (12*L*h*s per token fwd+bwd with the 1/2 causal
-    discount = 6*L*h*s). The ONE definition bench MFU and the trainer's
-    live MFU gauge share — they must never disagree about the
-    numerator."""
+    discount = 6*L*h*s). The numerator of the trainer's live MFU gauge
+    (the cells' `train_mfu` uses `benchmark/flops.py`)."""
     return 6.0 * n_params + 6.0 * num_layers * hidden_size * seq_length
 
 

@@ -256,9 +256,9 @@ class CheckpointManager:
 
     `last_blocked_ms` is the wall time the caller was actually stalled
     by the most recent `save()` (previous-save wait + device→host copy)
-    — exported as the `ckpt_blocked_ms` timers gauge by the trainer and
-    measured against the synchronous save wall time by bench.py's
-    `extra.ckpt` row. Call `wait_until_finished()` (or `close()`) before
+    — exported as the `ckpt_blocked_ms` timers gauge by the trainer
+    (no cell of `benchmark/` saves a checkpoint: the stall is not
+    measured on the chip). Call `wait_until_finished()` (or `close()`) before
     process exit so the final save commits."""
 
     def __init__(self, save_dir: str, keep_latest_n: Optional[int] = None,

@@ -399,8 +399,8 @@ class Trainer:
         """Once, at step 0: the active remat policy — and, under
         --log_memory_to_tensorboard, the compiled per-device temp/args
         bytes of the exact train step — so a WandB/tensorboard perf
-        trajectory is attributable to the memory/FLOP trade in effect
-        (the step-0 analogue of bench.py's remat sweep). The memory
+        trajectory is attributable to the memory/FLOP trade in effect.
+        The memory
         analysis is opt-in: it retraces and relowers the train step.
         (On jax 0.9.0 the AOT compile and the jit call share one
         executable cache, so it is no longer a second XLA compile.)"""

@@ -1,5 +1,5 @@
-"""Virtual multi-device CPU provisioning (shared by tests/conftest.py,
-tools/graft_check.py and bench.py's CPU-subprocess rows).
+"""Virtual multi-device CPU provisioning (shared by tests/conftest.py and
+tools/graft_check.py).
 
 JAX can emulate an n-device mesh on one host with
 --xla_force_host_platform_device_count — the capability that lets this

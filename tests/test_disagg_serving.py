@@ -20,10 +20,7 @@ Pinned here:
   unreferenced => evictable), donor-side reclaim after a receiver
   failure mid-transfer, pool-full fallback, and greedy BITWISE parity
   vs the single-engine oracle through the live two-stage router —
-  including spec decode on the decode replica;
-- the bench `extra.serving.disagg` harness runs on CPU and emits its
-  headline keys with routing decisions reproducible from the recorded
-  modeled backlogs (non-slow: tier-1 exercises the plumbing).
+  including spec decode on the decode replica.
 """
 
 import threading
@@ -424,58 +421,6 @@ class TestRetryAfterClamp:
     def test_constant_fallback_when_nothing_models(self):
         r = ReplicaRouter([DisaggFakeReplica(0, retry_after=None)])
         assert r.retry_after_s() == 1.0
-
-
-# ---------------------------------------------------------------------------
-# bench plumbing (slow since PR 21: TestTwoStageRouting and
-# TestHandoffEnginesEndToEnd keep the hand-off path in tier-1; this row
-# only adds bench.py's harness around it, at the suite's second-highest
-# cost, and tier-1 had to pay for tests/test_tpu_lowering.py)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchPlumbing:
-    @pytest.mark.slow
-    def test_bench_disagg_stats_plumbing(self):
-        """The extra.serving.disagg harness runs on CPU and emits its
-        headline keys (the artifact run uses the bench model on TPU
-        devices; the math is identical), with routing decisions
-        reproducible from the recorded modeled backlogs."""
-        import jax
-        import jax.numpy as jnp
-
-        import bench
-        from megatron_llm_tpu.config import tiny_config
-        from megatron_llm_tpu.models import LlamaModel
-
-        cfg = tiny_config(compute_dtype=jnp.float32,
-                          use_decode_attn=False)
-        model = LlamaModel(cfg)
-        params = model.init(jax.random.key(7))
-        row = bench.serving_disagg_stats(
-            model, params, slots=2, page_size=16, max_context=96,
-            chunk=16, vocab_size=256, n_long=2, n_short=2,
-            long_prompt=40, short_prompt=8, long_gen=2, short_gen=4,
-            step_horizon=4)
-        for key in ("disagg_vs_symmetric_ttft_p95",
-                    "batch_ttft_p95_ratio",
-                    "disagg_vs_symmetric_tok_s",
-                    "decode_interference_ratio",
-                    "router_decisions", "methodology"):
-            assert key in row, key
-        assert row["disagg"]["aggregate_tok_s"] > 0
-        assert row["symmetric"]["aggregate_tok_s"] > 0
-        # every long went two-stage, every short direct
-        assert row["disagg"]["prefill_replica_dispatches"] == 2
-        assert row["disagg"]["transfer_pages"] > 0
-        assert row["symmetric"]["transfer_pages"] == 0
-        paths = [d["path"] for d in row["router_decisions"]]
-        assert "two_stage" in paths and "direct" in paths
-        # reproducibility: two-stage placements carry the modeled-
-        # FLOPs snapshot they were derived from (cost registry is on)
-        two = [d for d in row["router_decisions"]
-               if d["path"] == "two_stage"]
-        assert all("modeled_flops" in d for d in two)
 
 
 # ---------------------------------------------------------------------------

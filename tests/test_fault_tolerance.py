@@ -25,7 +25,6 @@ Pinned here:
 - GET /health speaks load-balancer: 200 while serving, 503 when the
   engine loop died poisoned or stopped; engine `deadline_s` fails the
   waiter with TimeoutError and reclaims the slot's pages;
-- bench.py's `ckpt_stall_stats` harness runs end to end on CPU.
 
 All tier-1 (CPU, subprocesses with timeouts) except the running-request
 deadline test, which needs a compiled engine step.
@@ -954,30 +953,3 @@ def test_zero1_sharded_state_bitwise_resume(tmp_path):
             np.testing.assert_array_equal(a, b)
     finally:
         destroy_parallel()
-
-
-# ---------------------------------------------------------------------------
-# bench harness (CPU-tested, ISSUE-5 CI satellite)
-# ---------------------------------------------------------------------------
-
-
-def test_ckpt_bench_harness(tmp_path, tiny_saved):
-    """bench.py's `ckpt_stall_stats` end to end on CPU with a tiny
-    model: emits the sync/async stall numbers, asserts bitwise restore
-    and retention internally, cleans up after itself."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    cfg, model, params, opt, _ = tiny_saved
-    base = str(tmp_path / "bench_ckpt")
-    row = bench.ckpt_stall_stats(cfg, params, opt, base, n_saves=2)
-    assert row["sync_save_ms"] > 0
-    assert row["async_blocked_ms"] >= 0
-    assert row["async_restore_bitwise"] is True
-    assert row["ckpt_bytes"] > 0
-    assert 0 <= row["async_vs_sync_stall"]
-    assert not os.path.exists(base)  # cleaned up

@@ -4,8 +4,8 @@ reference, GQA/MQA/MHA, causal and full (VERDICT r1 missing #4 / weak #3).
 Interpret mode comes from the ONE shared conftest policy
 (`kernel_interpret_mode` / MEGATRON_TPU_KERNEL_INTERPRET): on CPU the
 real Pallas kernels run through the interpreter; the same kernels
-compile natively on TPU (driven by bench.py and the on-chip numerics
-check in the verify workflow). Ref parity target: training through
+compile natively on TPU (both training cells of `benchmark/` run them;
+`chip_smoke.py` compares each with its XLA twin on the chip). Ref parity target: training through
 flash-attn (ref transformer.py:508-523) with the external flash_attn
 package's numerics.
 """
@@ -32,8 +32,6 @@ from megatron_llm_tpu.ops.flash_attention import (
 flash_module = importlib.import_module("megatron_llm_tpu.ops.flash_attention")
 
 INTERPRET = kernel_interpret_mode()
-
-pytestmark = pytest.mark.slow
 
 
 def _rand_qkv(b, s, g, qpk, d, dtype=jnp.float32, seed=0):

@@ -26,8 +26,8 @@ Pinned here:
 - off-by-default invisibility: an unmanaged, non-recovering router
   keeps the legacy /metrics and flight_record schemas byte-shape;
 - (slow) kill-a-real-replica convergence: zero failed requests,
-  chaos-run streams bitwise vs the no-chaos oracle, recovery time in
-  the bench extra.serving.autonomy row.
+  chaos-run streams bitwise vs the no-chaos oracle, the controller's
+  replace event carries the recovery time.
 """
 
 import queue as queue_mod
@@ -707,17 +707,63 @@ class TestRealReplicaConvergence:
         return model, model.init(jax.random.key(7))
 
     def test_kill_real_replica_zero_failed_requests(self, tiny_model):
-        import bench
+        import jax
+        import numpy as np
+
+        from megatron_llm_tpu.inference.engine import DecodeEngine
+        from megatron_llm_tpu.inference.fleet import FleetController
+        from megatron_llm_tpu.inference.router import (
+            EngineReplica,
+            ReplicaRouter,
+        )
 
         model, params = tiny_model
-        row = bench.serving_autonomy_stats(
-            model, params, replicas=2, slots=2, page_size=16,
-            max_context=96, chunk=16, vocab_size=256, n_requests=6,
-            prompt_len=24, gen=8, kill_after=2, step_horizon=4)
-        assert row["failed_requests"] == 0, row["failures"]
-        assert row["bitwise_resubmits_match"] is True
-        assert row["fleet_replaced"] == 1
-        assert row["resubmitted"] >= 1
-        assert row["recovery_s"] is not None and row["recovery_s"] > 0
-        assert row["convergence_tok_s_ratio"] > 0
-        assert "methodology" in row
+        devs = jax.devices()
+        rs = np.random.RandomState(0)
+        prompts = [list(rs.randint(2, 256, 24)) for _ in range(6)]
+
+        def build(rid=None):
+            over = {} if rid is None else dict(
+                replica_id=rid, devices=[devs[rid]])
+            return DecodeEngine(
+                model, params, slots=2, page_size=16, max_context=96,
+                max_queue=6, termination_id=None, vocab_size=256,
+                prefill_chunk_tokens=16, prefix_cache=True,
+                step_horizon=4, **over)
+
+        # oracle: one plain engine, same traffic, no chaos
+        oracle = build()
+        oreqs = [oracle.submit(p, 8, top_k=1) for p in prompts]
+        oracle.drain()
+        want = [r.result(60)[0] for r in oreqs]
+
+        # replica 0 dies through the engine's real poison path after 2
+        # accepted submits; the controller rebuilds it on its device
+        chaos = ChaosPolicy(seed=0, kill_replica=0, kill_after_submits=2)
+        router = ReplicaRouter(
+            [EngineReplica(build(i), chaos=chaos) for i in range(2)],
+            recover_requests=True, unhealthy_cooldown_s=60.0)
+        ctl = FleetController(
+            router, check_interval_s=0.05, drain_timeout_s=5.0,
+            spawn_replica=lambda old: EngineReplica(
+                build(old.replica_id)))
+        router.start()
+        ctl.start()
+        try:
+            reqs = [router.submit(p, 8, top_k=1) for p in prompts]
+            # zero failed requests: every result() returns
+            got = [r.result(timeout=600.0)[0] for r in reqs]
+            deadline = time.monotonic() + 120.0
+            while (router.router_stats().get("serve_fleet_replaced", 0)
+                   < 1 and time.monotonic() < deadline):
+                time.sleep(0.1)
+            stats = router.router_stats()
+            replace_evs = [e for e in ctl.flight_events()
+                           if e["kind"] == "replace"]
+        finally:
+            ctl.stop()
+            router.stop(drain=True)
+        assert got == want  # resubmitted streams bitwise the oracle's
+        assert stats["serve_fleet_replaced"] == 1
+        assert stats["serve_resubmitted"] >= 1
+        assert max(e["recovery_s"] for e in replace_evs) > 0

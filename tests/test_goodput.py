@@ -23,9 +23,7 @@ Pinned here (tier-1):
   with the auto-dumped flight record loading and correlating the trip
   (the poison/rollback postmortem path, pointed at latency);
 - HTTPReplica histogram proxying (the PR-14 gap): Prometheus text ->
-  rebuilt Histogram -> merged fleet distribution round-trips exactly;
-- the bench `extra.goodput` harness runs on the CPU harness with its
-  in-row bitwise + sum-to-wall asserts live.
+  rebuilt Histogram -> merged fleet distribution round-trips exactly.
 """
 
 import json
@@ -689,29 +687,3 @@ class TestRemoteHistograms:
                "serve_ttft_ms_sum 9\nserve_ttft_ms_count 3\n")
         with pytest.raises(ValueError, match="non-monotone"):
             histograms_from_prometheus(bad)
-
-
-# ---------------------------------------------------------------------------
-# bench harness (CPU)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_goodput_harness_cpu():
-    """The `extra.goodput` row's harness runs on the CPU harness with
-    its in-row asserts live (tier-1, like extra.telemetry's): bitwise
-    on==off streams + losses, the sum-to-wall invariant, and a
-    captured cost table."""
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import goodput_stats
-
-    out = goodput_stats(slots=2, n_reqs=4, gen=8, prompt_len=10,
-                        train_steps=4, seq=16, chip_spec="v5e")
-    assert out["streams_bitwise_on_vs_off"]
-    assert out["train_losses_bitwise_on_vs_off"]
-    assert out["goodput_sum_to_wall_ok"]
-    assert out["serve_on"]["cost_records"] > 0
-    assert 0 <= out["goodput_fraction"] <= 1
-    assert "methodology" in out

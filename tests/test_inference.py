@@ -33,8 +33,6 @@ from megatron_llm_tpu.inference.sampling import (
     sample,
 )
 
-pytestmark = pytest.mark.slow
-
 
 @pytest.fixture(scope="module")
 def tiny_model():
@@ -236,6 +234,7 @@ def test_temperature_flattens_distribution():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.slow  # 71 s on the sandbox: over tier-1's 60 s a test
 def test_beam_search_finds_exhaustive_best(tiny_model):
     model, params = tiny_model
     vocab = 16  # restrict scoring to a tiny effective vocab

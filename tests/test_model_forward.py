@@ -4,12 +4,9 @@ megatron/mpu/tests/test_layers.py dense-reference checks)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from megatron_llm_tpu.config import tiny_config
 from megatron_llm_tpu.models import FalconModel, GPTModel, LlamaModel
-
-pytestmark = pytest.mark.slow
 
 
 def test_llama_forward_shapes():

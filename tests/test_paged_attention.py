@@ -903,37 +903,3 @@ def test_transformer_stack_chunk_plumbing():
                                    position_ids=pos[:1])
     np.testing.assert_array_equal(np.asarray(logits[0, :4]),
                                   np.asarray(logits_solo[0, :4]))
-
-
-class TestBenchKernelUnifyRow:
-    """The `extra.kernel_unify` bench harness (CPU-tested like the
-    serving/quant harnesses): the in-row bitwise assert ran, the split
-    emulation priced both launches, and the entry-point inventory came
-    from the live AST walk."""
-
-    def test_kernel_unify_stats_harness(self):
-        import importlib
-        import sys
-
-        sys.path.insert(0, "/root/repo")
-        bench = importlib.import_module("bench")
-        from megatron_llm_tpu.config import tiny_config
-        from megatron_llm_tpu.models import LlamaModel
-
-        cfg = tiny_config(compute_dtype=jnp.float32,
-                          use_decode_attn=False)
-        model = LlamaModel(cfg)
-        params = model.init(jax.random.key(7))
-        row = bench.kernel_unify_stats(
-            model, params, slots=2, page_size=16, max_context=64,
-            vocab_size=256, n_requests=3, prompt_len=20, gen=6,
-            chunk=8, op_T=64, op_page_size=16)
-        assert row["split_equals_fused_bitwise"] is True
-        assert row["paged_entry_points"] == 1
-        assert row["paged_entry_points_pre_unification"] == 2
-        assert row["unified_decode_us"] > 0
-        assert row["split_scatter_plus_attend_us"] > 0
-        assert row["unified_decode_gbps"] > 0
-        assert row["unified_chunk_gbps"] > 0
-        assert row["engine_decode_tok_s"] > 0
-        assert "methodology" in row

@@ -277,34 +277,3 @@ class TestEngineWeightQuant:
             for (_, lp0), (_, lp1) in zip(out_fp, out_qw)
             for a, b in zip(lp0[:23], lp1[:23]))
         assert drift < 0.1, drift
-
-
-# ---------------------------------------------------------------------------
-# Bench plumbing (the extra.quant row harness, CPU-tested like the
-# serving/interference/prefix harnesses; slow since PR 21 — TestEngineInt8
-# pins the behaviour in tier-1, this row only adds bench.py's harness,
-# and tier-1 had to pay for tests/test_entry_points.py)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchQuantRow:
-    @pytest.mark.slow
-    def test_quant_serving_stats_harness(self, tiny_model):
-        import importlib
-        import sys
-
-        sys.path.insert(0, "/root/repo")
-        bench = importlib.import_module("bench")
-        model, params = tiny_model
-        q = bench.quant_serving_stats(
-            model, params, slots=2, page_size=16, max_context=64,
-            vocab_size=256, n_requests=3, prompt_len=20, gen=6, chunk=8)
-        assert q["kv_capacity_ratio"] >= 1.5
-        assert q["int8_vs_bf16_decode_tok_s"] > 0
-        assert q["int8"]["max_prompt_logprob_drift_vs_bf16"] < 0.05
-        assert 0.0 <= q["int8"]["greedy_token_match_frac"] <= 1.0
-        assert q["tokens_per_gib_int8"] > q["tokens_per_gib_bf16"]
-        assert "methodology" in q
-        # the small-fix contract: op-stats bytes derive from dtype
-        assert (q["int8"]["kv_bytes_per_token"]
-                < q["bf16"]["kv_bytes_per_token"])

@@ -23,8 +23,7 @@ Pinned here:
   fleet;
 - (slow) two real engine replicas end to end: affinity keeps a shared
   prefix on one replica whose PrefixCache then HITS, streams match the
-  single-engine oracle; the bench `extra.serving.scaleout` harness
-  runs on CPU and emits its headline keys.
+  single-engine oracle.
 """
 
 import threading
@@ -474,27 +473,3 @@ class TestEngineReplicasEndToEnd:
         assert home.counters()["serve_prefix_hits"] >= 1
         stats = router.router_stats()
         assert stats["router_affinity_hits"] >= 1
-
-    def test_bench_scaleout_stats_plumbing(self, tiny_model):
-        """The extra.serving.scaleout harness runs on CPU and emits
-        its headline keys with sane values (the artifact run uses the
-        bench model on TPU devices; the math is identical)."""
-        import bench
-
-        model, params = tiny_model
-        row = bench.serving_scaleout_stats(
-            model, params, replicas=2, slots=2, page_size=16,
-            max_context=96, chunk=16, vocab_size=256, n_requests=8,
-            sys_prompt=40, uniq_suffix=4, gen=8, step_horizon=4)
-        for key in ("router_affinity_vs_random_ttft_p95",
-                    "aggregate_tok_s_scaling",
-                    "affinity_vs_random_prefill_tokens",
-                    "methodology"):
-            assert key in row, key
-        assert row["affinity"]["aggregate_tok_s"] > 0
-        assert row["single_replica"]["replicas"] == 1
-        # affinity routing must concentrate the shared prefix: the
-        # fleet prefills fewer tokens than random dispatch
-        assert (row["affinity"]["prefill_tokens"]
-                <= row["random"]["prefill_tokens"])
-        assert row["affinity"]["affinity_hit_rate"] > 0

@@ -34,8 +34,6 @@ from megatron_llm_tpu.inference.generation import (
 )
 from megatron_llm_tpu.models import LlamaModel
 
-pytestmark = pytest.mark.slow
-
 # greedy decode from this prompt settles into a 3-cycle on the seed-7
 # tiny model (probed; pinned by test_cycle_traffic_accepts below) —
 # exactly the traffic prompt-lookup drafting exists for

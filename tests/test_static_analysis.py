@@ -442,14 +442,12 @@ class TestRepoGate:
         report = json.loads(out.read_text())
         assert report["ok"]
         # the folded per-PR go/no-go object: every gate named, GO, no
-        # reasons; bench gate absent (no artifact supplied here — the
-        # TPU bench run attaches it)
+        # reasons
         v = report["verdict"]
         assert v["verdict"] == "GO" and v["ok"], v
         assert v["gates"] == {"lint": True, "audit": True,
                               "costs": True}
         assert v["reasons"] == []
-        assert v["bench"] is None
         assert "-> GO" in proc.stdout
         assert report["lint"]["ok"] and not report["lint"]["new"]
         aud = report["audit"]
@@ -558,17 +556,11 @@ class TestRepoGate:
         with pytest.raises(ValueError, match="justification"):
             load_cost_baseline(str(p))
 
-    def test_verdict_folds_gates_and_bench_headline(self, tmp_path):
+    def test_verdict_folds_gates(self):
         """ROADMAP 5c acceptance, pure-function half: build_verdict
-        turns the section reports + the bench headline diff into the
-        one go/no-go object — any failing gate is NO-GO with a reason
-        naming it, a bench headline past the drop floor vetoes, an
-        artifact WITHOUT a baseline is informational only."""
-        from tools.graft_check import (
-            BENCH_HEADLINE_MAX_DROP,
-            _bench_diff,
-            build_verdict,
-        )
+        turns the section reports into the one go/no-go object — any
+        failing gate is NO-GO with a reason naming it."""
+        from tools.graft_check import build_verdict
 
         clean = {
             "lint": {"ok": True, "new": [], "stale_baseline_keys": []},
@@ -586,25 +578,6 @@ class TestRepoGate:
         v = build_verdict(broken)
         assert v["verdict"] == "NO-GO" and not v["gates"]["costs"]
         assert any("costs" in r for r in v["reasons"])
-        # bench: artifact alone records, artifact + baseline gates
-        art = tmp_path / "bench.json"
-        base = tmp_path / "bench_base.json"
-        art.write_text(json.dumps({"value": 90.0, "unit": "tok/s"}))
-        base.write_text(json.dumps({"value": 100.0}))
-        info = _bench_diff(str(art), None)
-        assert info["ok"] is None  # not armed
-        assert build_verdict(clean, bench=info)["verdict"] == "GO"
-        armed = _bench_diff(str(art), str(base))
-        assert armed["ok"] is False  # 10% drop > the 5% floor
-        assert armed["headline_ratio"] == 0.9
-        v = build_verdict(clean, bench=armed)
-        assert v["verdict"] == "NO-GO"
-        assert v["gates"]["bench_headline"] is False
-        assert any("bench" in r for r in v["reasons"])
-        # inside the floor: GO
-        art.write_text(json.dumps(
-            {"value": 100.0 * (1 - BENCH_HEADLINE_MAX_DROP)}))
-        assert _bench_diff(str(art), str(base))["ok"] is True
 
 
 class TestOnePagedEntryPoint:

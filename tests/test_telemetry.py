@@ -26,8 +26,7 @@ Pinned here (tier-1):
   SIGTERM artifacts are pinned in test_fault_tolerance.py);
 - the profiler hook: POST-/profile-style request_profile() is a loud
   no-op when capture is unsupported, the engine keeps serving, and the
-  hook re-arms;
-- bench.py's `telemetry_stats` harness runs end to end on CPU.
+  hook re-arms.
 """
 
 from __future__ import annotations
@@ -978,26 +977,3 @@ class TestNamedScopes:
         assert not missing, missing
         assert collectives_in_text(lowered.compile().as_text()) == \
             frozenset()
-
-
-# ---------------------------------------------------------------------------
-# bench harness (CPU-tested like extra.overlap)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_telemetry_harness_runs():
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import telemetry_stats
-
-    out = telemetry_stats(slots=2, n_reqs=4, gen=8, prompt_len=10,
-                          train_steps=3, seq=16)
-    assert out["streams_bitwise_on_vs_off"] is True
-    assert out["train_losses_bitwise_on_vs_off"] is True
-    assert isinstance(out["telemetry_overhead_pct"], float)
-    assert out["serve_on"]["span_events"] > 0
-    assert out["serve_off"]["span_events"] == 0
-    assert out["serve_on"]["ttft_hist_count"] == 4
-    assert "BITWISE" in out["methodology"]

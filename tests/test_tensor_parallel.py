@@ -41,8 +41,6 @@ from megatron_llm_tpu.parallel.sharding import (
     param_specs,
 )
 
-pytestmark = pytest.mark.slow
-
 
 def _fp32_cfg(**overrides):
     """All-fp32 tiny config so sharded-vs-unsharded comparisons are tight."""

@@ -22,9 +22,7 @@ Pinned here:
   appear ONLY on window-enabled engines — the legacy JSON schema
   (tests/test_telemetry.py pins bytes) is untouched when off;
 - loud config/ctor errors: window < 1 and window-without-chunked-
-  admission fail at construction, not mid-traffic;
-- bench.py's `longcontext_stats` harness runs end to end on CPU and
-  its in-row bitwise assert ran.
+  admission fail at construction, not mid-traffic.
 """
 
 from __future__ import annotations
@@ -33,7 +31,6 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from megatron_llm_tpu.config import tiny_config
@@ -206,36 +203,3 @@ class TestWindowCapacity:
             tiny_config(**BASE, attention_window_size=0)
         cfg = tiny_config(**BASE, attention_window_size=64)
         assert dataclasses.replace(cfg).attention_window_size == 64
-
-
-class TestBenchLongContextRow:
-    """The `extra.serving.longcontext` bench harness, CPU-tested like
-    the other serving harnesses: windowed vs dense engines under mixed
-    long + short traffic, the in-row bitwise stream assert ran, and
-    the capacity/traffic columns are present and sane."""
-
-    def test_longcontext_stats_harness(self):
-        import importlib
-        import sys
-
-        sys.path.insert(0, "/root/repo")
-        bench = importlib.import_module("bench")
-
-        model = _model()
-        params = model.init(jax.random.key(7))
-        row = bench.longcontext_stats(
-            model, params, window=48, slots=2, page_size=16,
-            max_context=192, page_budget=96, vocab_size=256,
-            long_prompt=24, long_gen=72, short_prompt=8, short_gen=8)
-        assert row["window_tokens"] == 48
-        assert row["streams_bitwise_vs_mask_only"] is True
-        assert row["window_peak_pages_per_long_slot"] <= \
-            row["window_page_bound_per_slot"]
-        assert row["dense_peak_pages_per_long_slot"] > \
-            row["window_peak_pages_per_long_slot"]
-        assert row["window_reclaimed_pages"] > 0
-        assert row["window_decode_read_bytes_per_token"] < \
-            row["dense_decode_read_bytes_per_token"]
-        assert row["window_ttft_p95_ms"] >= 0
-        assert "methodology" in row
-        assert np.isfinite(row["window_decode_read_bytes_per_token"])

@@ -27,24 +27,19 @@ Three passes over the real package, one exit code:
   cost_analysis FLOPs and memory_analysis temp bytes against the
   checked-in baseline (megatron_llm_tpu/analysis/cost_baseline.json)
   — the compile-cost regression gate: a silent 2x FLOPs regression in
-  any jitted entry point fails CI loudly, long before a bench run
-  notices the slowdown. Same stale-key/justification workflow as the
-  lint baseline: MISSING keys (new audited rows) and STALE keys
-  (audited rows gone) both fail; `--update-costs --justify "..."`
-  rewrites the baseline with the current measurements, stamping the
-  justification on every entry whose value moved. Under `all` the
-  costs pass reuses the audit report already computed — one lowering
-  pass feeds both gates.
+  any jitted entry point fails CI loudly, before any chip run. Same
+  stale-key/justification workflow as the lint baseline: MISSING keys
+  (new audited rows) and STALE keys (audited rows gone) both fail;
+  `--update-costs --justify "..."` rewrites the baseline with the
+  current measurements, stamping the justification on every entry
+  whose value moved. Under `all` the costs pass reuses the audit
+  report already computed — one lowering pass feeds both gates.
 
-- `verdict` (ROADMAP 5c) runs all three gates and folds them — plus
-  the bench headline diff when artifact JSONs are supplied via
-  `--bench-artifact` (this run) and `--bench-baseline` (the pinned
-  prior run) — into ONE machine-readable go/no-go object: every gate
-  named, every failure a reason string, `"verdict": "GO" | "NO-GO"`.
-  The per-PR regression gate: what BENCH_r05-era discipline did by
-  hand, as machinery. A bench artifact without a baseline is recorded
-  informationally (headline echoed, gate not armed); a headline
-  tok/s drop past BENCH_HEADLINE_MAX_DROP vs the baseline is NO-GO.
+- `verdict` (ROADMAP 5c) runs all three gates and folds them into ONE
+  machine-readable go/no-go object: every gate named, every failure a
+  reason string, `"verdict": "GO" | "NO-GO"`. Speed is not its
+  business: the driver judges that per cell from `benchmark/`
+  (BENCHMARK.json, PERF_LEDGER.jsonl).
 
 Runs anywhere in < 90 s with JAX_PLATFORMS=cpu (the audit sets it
 itself). Exit codes: 0 clean, 1 findings/violations, 2 usage.
@@ -72,12 +67,6 @@ COST_BASELINE = os.path.join(
 # compiler fusion choices, so the bar is looser.
 COST_FLOPS_MAX_RATIO = 1.25
 COST_TEMP_MAX_RATIO = 1.5
-
-# verdict's bench-headline gate: the artifact's headline value (tok/s/
-# chip) may drop at most this fraction vs the pinned baseline artifact
-# before the verdict flips to NO-GO. Wall-clock numbers are noisier
-# than compiled costs, so the bar is a ratio, not an equality.
-BENCH_HEADLINE_MAX_DROP = 0.05
 
 
 def run_lint(list_keys: bool = False) -> dict:
@@ -287,70 +276,11 @@ def run_costs(audit_report=None, baseline_path: str = COST_BASELINE,
     }
 
 
-def _bench_diff(artifact_path, baseline_path):
-    """The bench half of the verdict: echo this run's headline, and
-    when a pinned baseline artifact rides along, gate the headline
-    value (tok/s/chip) against BENCH_HEADLINE_MAX_DROP. Returns None
-    when no artifact was supplied (the gate simply isn't armed —
-    compile-cost diffs already cover every jitted entry point)."""
-    if not artifact_path:
-        return None
-    with open(artifact_path, "r", encoding="utf-8") as fh:
-        art = json.load(fh)
-    out = {
-        "headline_value": art.get("value"),
-        "unit": art.get("unit"),
-        "vs_paper_baseline": art.get("vs_baseline"),
-        "artifact": artifact_path,
-        "max_drop": BENCH_HEADLINE_MAX_DROP,
-    }
-    # ISSUE 20: the self-driving-fleet acceptance headlines ride the
-    # artifact under extra.serving.autonomy — when present, the
-    # zero-failed-request bar and the bitwise-resubmit pin become
-    # their own gate (absent on legacy artifacts -> unarmed)
-    auto = ((art.get("extra") or {}).get("serving") or {}).get(
-        "autonomy")
-    if auto is not None:
-        failed = auto.get("failed_requests")
-        bitwise = auto.get("bitwise_resubmits_match")
-        out["autonomy"] = {
-            "failed_requests": failed,
-            "bitwise_resubmits_match": bitwise,
-            "recovery_s": auto.get("recovery_s"),
-            "convergence_tok_s_ratio": auto.get(
-                "convergence_tok_s_ratio"),
-            "ok": failed == 0 and bool(bitwise),
-        }
-    if not baseline_path:
-        out |= {"ok": None,
-                "note": "no --bench-baseline: headline recorded, "
-                        "gate not armed"}
-        return out
-    with open(baseline_path, "r", encoding="utf-8") as fh:
-        base = json.load(fh)
-    now, then = art.get("value"), base.get("value")
-    if not isinstance(now, (int, float)) \
-            or not isinstance(then, (int, float)) or then <= 0:
-        out |= {"ok": False,
-                "note": f"unreadable headline values "
-                        f"(now={now!r}, baseline={then!r})"}
-        return out
-    ratio = now / then
-    out |= {
-        "baseline_value": then,
-        "baseline_artifact": baseline_path,
-        "headline_ratio": round(ratio, 4),
-        "ok": ratio >= 1.0 - BENCH_HEADLINE_MAX_DROP,
-    }
-    return out
-
-
-def build_verdict(report, bench=None) -> dict:
-    """Fold the gate sections (and the optional bench diff) into the
-    ONE go/no-go object (ROADMAP 5c): every gate named with its
-    boolean, every failure compressed to a reason string a human (or
-    the next automation layer) can act on without re-running the
-    passes. Pure function over already-computed reports — tested
+def build_verdict(report) -> dict:
+    """Fold the gate sections into the ONE go/no-go object (ROADMAP
+    5c): every gate named with its boolean, every failure compressed to
+    a reason string a human (or the next automation layer) can act on
+    without re-running the passes. Pure function over already-computed reports — tested
     directly, no lowering pass needed."""
     gates, reasons = {}, []
     lint = report.get("lint")
@@ -382,36 +312,12 @@ def build_verdict(report, bench=None) -> dict:
                 reasons.append(
                     f"costs: {len(costs[field])} {field} "
                     f"(first: {costs[field][0]})"[:200])
-    if bench is not None:
-        # ok=None (artifact without baseline) is informational, not a
-        # gate — only an ARMED bench diff can veto
-        if bench.get("ok") is not None:
-            gates["bench_headline"] = bool(bench["ok"])
-            if not bench["ok"]:
-                reasons.append(
-                    f"bench: headline {bench.get('headline_value')} vs "
-                    f"baseline {bench.get('baseline_value')} "
-                    f"(ratio {bench.get('headline_ratio')}, floor "
-                    f"{1.0 - BENCH_HEADLINE_MAX_DROP})")
-        auto = bench.get("autonomy")
-        if auto is not None:
-            # ISSUE 20: the chaos-convergence headlines gate on their
-            # own — a run that failed requests (or whose resubmits
-            # were not bitwise) is a NO-GO regardless of tok/s
-            gates["bench_autonomy"] = bool(auto["ok"])
-            if not auto["ok"]:
-                reasons.append(
-                    f"autonomy: {auto.get('failed_requests')} failed "
-                    f"request(s), bitwise_resubmits_match="
-                    f"{auto.get('bitwise_resubmits_match')} (the "
-                    f"zero-failed-request convergence bar)")
     ok = all(gates.values())
     return {
         "verdict": "GO" if ok else "NO-GO",
         "ok": ok,
         "gates": gates,
         "reasons": reasons,
-        "bench": bench,
     }
 
 
@@ -436,13 +342,6 @@ def main(argv=None) -> int:
     ap.add_argument("--justify", default="",
                     help="justification stamped on updated cost-"
                          "baseline entries")
-    ap.add_argument("--bench-artifact", metavar="PATH", default=None,
-                    help="verdict only: this run's bench JSON "
-                         "(bench.py output) — headline echoed into "
-                         "the verdict")
-    ap.add_argument("--bench-baseline", metavar="PATH", default=None,
-                    help="verdict only: the pinned prior bench JSON — "
-                         "arms the headline-regression gate")
     args = ap.parse_args(argv)
 
     report = {}
@@ -460,9 +359,7 @@ def main(argv=None) -> int:
             update=args.update_costs, justify=args.justify)
 
     if args.command == "verdict":
-        verdict = build_verdict(
-            report, bench=_bench_diff(args.bench_artifact,
-                                      args.bench_baseline))
+        verdict = build_verdict(report)
         report["verdict"] = verdict
         ok = verdict["ok"]
         for r in verdict["reasons"]:
