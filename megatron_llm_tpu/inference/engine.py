@@ -1181,6 +1181,21 @@ class DecodeEngine:
                 page_size)
         V = self.cfg.padded_vocab_size
         self._last_logits = self._dev(np.zeros((slots, V), np.float32))
+        homes = {x.sharding for x in jax.tree.leaves(self._dec_params)
+                 if getattr(x, "committed", False)}
+        if self._ctx is None and len(homes) == 1:
+            # one committed argument (a restored checkpoint's leaf, a
+            # device_put one) commits every output of a jitted step,
+            # and a committed argument is another program than an
+            # uncommitted one: what rides from round to round starts
+            # out the way it comes back, or warm-up compiles one
+            # program and the first round another (seen on the v5e at
+            # PR 34 with committed leaves: three 32-layer compiles
+            # inside a 50 s window)
+            (self._pools_k, self._pools_v, self._pools_ks, self._pools_vs,
+             self._last_logits) = jax.device_put(
+                (self._pools_k, self._pools_v, self._pools_ks,
+                 self._pools_vs, self._last_logits), homes.pop())
         # host-authoritative mirrors (tiny; shipped to device each step)
         self._pt = np.zeros((slots, self.max_pages_per_slot), np.int32)
         self._lengths = np.zeros((slots,), np.int32)

@@ -225,6 +225,25 @@ class GPTModel:
           and keeps the GLU elementwise-local
           (parallel/sharding.decode_param_specs). Single-chip engines
           keep the flatten (the sublane-bandwidth win above).
+
+        - `wqkv` (h, qkv) is held HEAD-major, (heads, head_dim, h) with
+          heads = groups x (q_per_kv + 2) in `split_qkv`'s order — its
+          transpose, cut by head; `ops/quantization.qdot` issues the
+          same products from the rank-3 leaf. `split_qkv` cuts the
+          projection's columns into heads, so the compiler produces it
+          head-major and for that reads the weight with h as the minor
+          (lane) axis, while a TPU's default layout of the 2-D leaf
+          has qkv there: every layer of every step re-laid its weight
+          out (Falcon-7B: 42.5 MB x 32 read and written = 3.3 ms of a
+          25.3 ms decode round, traced on v5e, PR 26/27; head 128
+          alike, compile-only). The rank-3 shape's DEFAULT layout has h
+          minor. (A layout pinned on the 2-D leaf does the same and
+          does not survive the persistent compile cache on jax 0.9.0 /
+          libtpu 0.0.34: PERF.md §6, PR 34.)
+
+        The tied table's per-step re-layout copy (591 MB for 8 rows at
+        Falcon-7B's widths) is cured where the rows are read, not here:
+        `models/language_model.embed_tokens`.
         """
         import jax
 
@@ -237,12 +256,16 @@ class GPTModel:
         stacked = params["layers"]
 
         def layer_slice(i):
-            layer = jax.tree.map(lambda x: x[i], stacked)
+            layer = dict(jax.tree.map(lambda x: x[i], stacked))
+            attn = dict(layer["attention"])
+            wqkv = attn["wqkv"]
+            attn["wqkv"] = wqkv.T.reshape(-1, self.cfg.head_dim,
+                                          wqkv.shape[0])
+            layer["attention"] = attn
             if self.cfg.glu_activation and flatten_glu:
                 mlp = dict(layer["mlp"])
                 w1 = mlp["w1"]
                 mlp["w1"] = w1.reshape(w1.shape[0], -1)
-                layer = dict(layer)
                 layer["mlp"] = mlp
             return layer
 

@@ -21,6 +21,7 @@ from megatron_llm_tpu.models.transformer import (
     init_norm_params,
     transformer_stack,
 )
+from megatron_llm_tpu.ops.flash_attention import LANES
 from megatron_llm_tpu.parallel.mesh import shard_activation
 
 
@@ -66,13 +67,49 @@ def init_language_model_params(cfg, key) -> dict:
     return params
 
 
+# a one-hot product reads the table once (Falcon-7B: 591 MB = 0.72 ms on
+# a v5e) and is bound by that read up to ~240 rows (197 TFLOP/s over
+# 819 GB/s); the gather's re-layout copy reads AND writes it (1.85 ms
+# traced), which the product's FLOPs reach near 600 rows
+ONE_HOT_MAX_ROWS = 512
+
+
+def _rows_by_one_hot(params, table_shape, n_rows: int) -> bool:
+    """Whether `embed_tokens` takes its rows as a one-hot product against
+    the table instead of a gather: for the few rows of a served round
+    (a decode-layout tree: prepare_decode_params' tuple of layers)
+    against a table the device lays out VOCAB-minor. A TPU's default
+    layout of a 2-D array makes the axis that pads least against the
+    lane tile the minor one; where that is the vocabulary (Falcon-7B:
+    65024 = 508 tiles, hidden 4544 = 35.5) the tied head reads the
+    table as it lies and a row gather cannot, so the compiler re-laid
+    the whole table out before it: every round, for 8 rows (1.85 ms of a
+    25.3 ms decode round, traced on v5e, PR 26). The product reads the
+    table in the head's layout. A table that lies hidden-minor (hidden a
+    multiple of the tile) is gathered as before; so are the many rows of
+    a training step or a whole-prompt prefill."""
+    vocab, hidden = table_shape
+    return (isinstance(params.get("layers"), (list, tuple))
+            and n_rows <= ONE_HOT_MAX_ROWS
+            and -hidden % LANES > -vocab % LANES)
+
+
 @jax.named_scope("embed")
 def embed_tokens(params, cfg, tokens, position_ids=None, dropout_rng=None,
                  deterministic=True, tokentype_ids=None):
     """(b, s) int32 -> (b, s, h) compute-dtype (ref: Embedding.forward
     language_model.py:230-260, incl. SP scatter of the dropout region)."""
     emb = params["embedding"]["word_embeddings"]
-    hidden = jnp.take(emb, tokens, axis=0).astype(cfg.compute_dtype)
+    if _rows_by_one_hot(params, emb.shape, tokens.size):
+        # exact: one 1.0 a row against the table as the head reads it
+        rows = jnp.einsum(
+            "...v,vh->...h", jax.nn.one_hot(tokens, emb.shape[0],
+                                            dtype=emb.dtype), emb,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        hidden = rows.astype(cfg.compute_dtype)
+    else:
+        hidden = jnp.take(emb, tokens, axis=0).astype(cfg.compute_dtype)
     if cfg.position_embedding_type == "absolute":
         pos = params["embedding"]["position_embeddings"]
         if position_ids is None:

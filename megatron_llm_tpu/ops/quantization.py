@@ -94,7 +94,12 @@ def quantize_weight(w: jnp.ndarray) -> dict:
     """Per-OUTPUT-channel int8 for a (in_dim, out_dim) matmul weight:
     scale over axis 0, so `x @ W ~= (x @ int8) * scale[None, :]` — the
     scale application is a cheap per-column multiply on the GEMV output
-    instead of a full dequantized weight materialization."""
+    instead of a full dequantized weight materialization. The decode
+    tree's head-major `wqkv` (heads, head_dim, in_dim) quantizes over
+    its LAST axis: the same channels, their scales in column order."""
+    if w.ndim == 3:
+        data, scale = quantize_rows(w, axis=-1)
+        return {"int8_data": data, "scale": scale.reshape(-1)}
     assert w.ndim == 2, (
         "weight-only quantization expects the 2D decode layout "
         f"(prepare_decode_params flattens GLU first), got {w.shape}")
@@ -106,6 +111,17 @@ def is_quantized_weight(w) -> bool:
     return isinstance(w, dict) and "int8_data" in w
 
 
+def _wdot(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """`x @ w` for an (in, out) weight. A rank-3 weight is the decode
+    tree's head-major `wqkv` (heads, head_dim, in)
+    (GPTModel.prepare_decode_params): the same products summed over
+    `in`, the columns coming out in the same order."""
+    if w.ndim == 3:
+        y = jnp.einsum("...h,ndh->...nd", x, w)
+        return y.reshape(*y.shape[:-2], -1)
+    return x @ w
+
+
 def qdot(x: jnp.ndarray, w, dt) -> jnp.ndarray:
     """`x @ w` for a plain fp weight (bitwise-identical to the
     pre-quantization call sites: `x @ w.astype(dt)`) or a weight-only
@@ -113,9 +129,9 @@ def qdot(x: jnp.ndarray, w, dt) -> jnp.ndarray:
     the dot fusion, per-channel scale applied to the output in fp32
     then cast back to the compute dtype)."""
     if is_quantized_weight(w):
-        y = x @ w["int8_data"].astype(dt)
+        y = _wdot(x, w["int8_data"].astype(dt))
         return (y.astype(jnp.float32) * w["scale"]).astype(dt)
-    return x @ w.astype(dt)
+    return _wdot(x, w.astype(dt))
 
 
 @compile_contract(

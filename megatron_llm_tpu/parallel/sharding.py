@@ -236,7 +236,8 @@ def decode_param_specs(cfg, dec_params: dict) -> dict:
     the leading layer axis removed, for the tp-sharded serving engine
     (inference/engine.py serving_tp > 1):
 
-    - wqkv / b1 (glu (2, f)) column-parallel: output dim over `model`
+    - wqkv (head-major (heads, head_dim, h): the heads axis) / b1 (glu
+      (2, f)) column-parallel: output dim over `model`
     - wo / w2 row-parallel: input dim over `model`
     - w1 in the UNFLATTENED (h, 2, f) GLU layout: f over `model`. The
       single-chip decode flatten to (h, 2f) concatenates [gate | up]
@@ -252,7 +253,10 @@ def decode_param_specs(cfg, dec_params: dict) -> dict:
         specs: dict = {
             "input_norm": jax.tree.map(lambda _: P(), tree["input_norm"]),
         }
-        attn = {"wqkv": P(None, MODEL_AXIS), "wo": P(MODEL_AXIS, None)}
+        # wqkv head-major (heads, head_dim, h): the heads axis is the
+        # (h, qkv) leaf's column axis cut by head, so the same
+        # contiguous split lands on every chip
+        attn = {"wqkv": P(MODEL_AXIS, None, None), "wo": P(MODEL_AXIS, None)}
         if "bqkv" in tree["attention"]:
             attn["bqkv"] = P(MODEL_AXIS)
             attn["bo"] = P(None)

@@ -197,13 +197,16 @@ _DOT = re.compile(
 
 def _token_rows(text, heads):
     """(token rows, is a weight matmul) of every dot in a lowered
-    program: a weight matmul has a 2-D right operand and one row a
-    token; an attention dot folds the heads into its rows."""
+    program: a weight matmul has one row a token against a 2-D right
+    operand, or against the decode tree's head-major (heads, head_dim,
+    hidden) `wqkv`; an attention dot folds the heads into its rows."""
     out = []
     for lhs, rhs, res in _DOT.findall(text):
-        res = [int(x) for x in res.split("x")]
-        weight = rhs.count("x") == 1
-        rows = int(np.prod(res[:-1]))
+        lhs, rhs, res = ([int(x) for x in t.split("x")]
+                         for t in (lhs, rhs, res))
+        weight = len(rhs) == 2 or (
+            len(rhs) == 3 and len(res) == len(lhs) + 1)
+        rows = int(np.prod(res[:len(lhs) - 1]))
         out.append((rows if weight else rows // heads, weight))
     return out
 
@@ -217,8 +220,9 @@ def test_mixed_step_lowers_to_width_plus_slots_rows(params):
         *eng._null_mixed_args(width)).as_text()
     dots = _token_rows(text, cfg.num_attention_heads)
     weight_rows = [r for r, w in dots if w]
-    # qkv, out, up, down a layer, and the head
-    assert len(weight_rows) == 4 * cfg.num_layers + 1
+    # qkv, out, up, down a layer, the head, and the embedding's rows as
+    # a one-hot product (the toy's table lies vocab-minor on a TPU)
+    assert len(weight_rows) == 4 * cfg.num_layers + 2
     assert set(weight_rows) == {width + slots}
     assert slots * width not in [r for r, _ in dots]
     # attention: the chunk at (1, width), the decode rows at (slots, 1)

@@ -96,7 +96,11 @@ class TestWeightQuant:
                 ref = fp_l[path[0]][path[1]]
                 got = q_l[path[0]][path[1]]
                 assert got["int8_data"].shape == ref.shape
-                assert got["scale"].shape == (ref.shape[1],)
+                # one scale an output channel: wqkv is head-major
+                # (heads, head_dim, h), the others (in, out)
+                assert got["scale"].shape == (
+                    (ref.shape[0] * ref.shape[1],) if ref.ndim == 3
+                    else (ref.shape[1],))
             # everything else (norms) untouched, bitwise
             np.testing.assert_array_equal(
                 np.asarray(fp_l["input_norm"]["scale"]),

@@ -94,7 +94,7 @@ class TestPoolSpecRule:
         jax.tree.map(lambda a, s: None, dec, specs,
                      is_leaf=lambda x: isinstance(x, P))
         l0 = specs["layers"][0]
-        assert l0["attention"]["wqkv"] == P(None, MODEL_AXIS)
+        assert l0["attention"]["wqkv"] == P(MODEL_AXIS, None, None)
         assert l0["attention"]["wo"] == P(MODEL_AXIS, None)
         assert l0["mlp"]["w1"] == P(None, None, MODEL_AXIS)
         assert l0["mlp"]["w2"] == P(MODEL_AXIS, None)
