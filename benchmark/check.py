@@ -96,8 +96,10 @@ def train_reference(cfg: dict, seed: int, texts: np.ndarray,
     L = use["num_hidden_layers"]
     matmul = MATMULS[precision]
     words = weights.seed_words(seed)
-    mk_layer, mk_glob = _makers(cfg, devices)
-    params = {"layers": [mk_layer(words, jnp.int32(i)) for i in range(L)],
+    kinds = [fam.layer_kind(cfg, i) for i in range(L)]
+    mk_layer, mk_glob = _makers(cfg, L, devices)
+    params = {"layers": [mk_layer[kinds[i]](words, jnp.int32(i))
+                         for i in range(L)],
               "globals": mk_glob(words)}
     # Adam's moments lie where the parameters lie: tied to no sharding,
     # the moments of a model spread over four chips are replicated
@@ -108,9 +110,11 @@ def train_reference(cfg: dict, seed: int, texts: np.ndarray,
 
     def loss(p, t, l):
         if fault == "no_exchange":
+            # each block's own row-parallel leaves (its kind's)
             p = dict(p, layers=[
                 dict(w, **{name: one_ranks_share(w[name], tp, axis)
-                           for name, axis in fam.ROW_PARALLEL.items()})
+                           for name, axis in fam.ROW_PARALLEL.items()
+                           if name in w})
                 for w in p["layers"]])
         return fam.reference.mean_loss(p, t, l, cfg, matmul)
 
@@ -139,13 +143,14 @@ def train_reference(cfg: dict, seed: int, texts: np.ndarray,
         if s == 0:
             grad_norms = jax.device_get(norms)
 
-    @jax.jit
-    def change(now, w, i=None):
-        p0 = mk_glob(w) if i is None else mk_layer(w, i)
+    def change(now, w, i=None, kind=None):
+        p0 = mk_glob(w) if i is None else mk_layer[kind](w, i)
         return _leaf_norms({k: now[k] - p0[k] for k in now})
 
+    change = jax.jit(change, static_argnames="kind")  # one program a kind
     ch_layers = [jax.device_get(change(params["layers"][i], words,
-                                       jnp.int32(i))) for i in range(L)]
+                                       jnp.int32(i), kind=kinds[i]))
+                 for i in range(L)]
     ch_glob = jax.device_get(change(params["globals"], words))
     for leaf in jax.tree.leaves((params, m, v)):
         leaf.delete()
@@ -155,13 +160,14 @@ def train_reference(cfg: dict, seed: int, texts: np.ndarray,
             "change_norms": _stack(ch_layers, ch_glob)}
 
 
-def _makers(cfg, devices=None):
-    """(make one block, make the globals), each a compiled program of the
-    seed's words (an argument: one program serves every seed). On
-    several chips the reference's weights are spread over their memory
-    (the 40B's do not fit one): each matrix split along its longer axis.
-    The arithmetic stays the plain reference's; the compiler places it."""
-    lsh = gsh = None
+def _makers(cfg, layers, devices=None):
+    """({kind: make one block of it}, make the globals), each a compiled
+    program of the seed's words (an argument: one program serves every
+    seed). On several chips the reference's weights are spread over
+    their memory (the 40B's do not fit one): each matrix split along its
+    longer axis. The arithmetic stays the plain reference's; the
+    compiler places it."""
+    lsh, gsh = (lambda like: None), None
     if devices is not None and len(devices) > 1:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -174,19 +180,24 @@ def _makers(cfg, devices=None):
             return NamedSharding(mesh, P(*["d" if i == ax else None
                                            for i in range(x.ndim)]))
 
-        lsh = jax.tree.map(spec, jax.eval_shape(
-            lambda: weights.make_layer(cfg, 0, 0)))
+        def lsh(like):  # a kind's shardings, from that kind's own shapes
+            return jax.tree.map(spec, jax.eval_shape(
+                lambda: weights.make_layer(cfg, 0, like)))
+
         gsh = jax.tree.map(spec, jax.eval_shape(
             lambda: weights.make_globals(cfg, 0)))
-    mk_layer = jax.jit(lambda w, i: weights.make_layer(cfg, w, i),
-                       out_shardings=lsh)
+    mk_layer = weights.layer_makers(cfg, layers, shardings=lsh)
     mk_glob = jax.jit(lambda w: weights.make_globals(cfg, w),
                       out_shardings=gsh)
     return mk_layer, mk_glob
 
 
 def _stack(layers: list, glob: dict) -> dict:
-    out = {k: np.asarray([float(l[k]) for l in layers]) for k in layers[0]}
+    """Per-block norms -> {leaf name: one entry for each block that has
+    a leaf of that name, in layer order}; one entry for a global."""
+    names = dict.fromkeys(k for l in layers for k in l)
+    out = {k: np.asarray([float(l[k]) for l in layers if k in l])
+           for k in names}
     out.update({k: np.asarray([float(v)]) for k, v in glob.items()})
     return out
 
@@ -241,7 +252,7 @@ def serve_reference_logits(cfg: dict, seed: int, samples: list,
     rnd = lambda t: jax.tree.map(  # noqa: E731
         lambda x: x.astype(dt).astype(jnp.float32), t)
     words = weights.seed_words(seed)
-    mk_layer = jax.jit(lambda w, i: rnd(weights.make_layer(cfg, w, i)))
+    mk_layer = weights.layer_makers(cfg, L, finish=rnd)
     glob = jax.jit(lambda w: rnd(weights.make_globals(cfg, w)))(words)
     T = max(len(s["tokens"]) for s in samples)
     T = -(-T // 256) * 256
@@ -261,7 +272,7 @@ def serve_reference_logits(cfg: dict, seed: int, samples: list,
         toks[:len(s["tokens"])] = s["tokens"]
         hs.append(fam.reference.embed(glob, jnp.asarray(toks)))
     for i in range(L):
-        w = mk_layer(words, jnp.int32(i))
+        w = mk_layer[fam.layer_kind(cfg, i)](words, jnp.int32(i))
         hs = [blk(w, h, i) for h in hs]
         for leaf in jax.tree.leaves(w):
             leaf.delete()

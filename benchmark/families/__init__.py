@@ -12,12 +12,11 @@ edited. `find(cfg)` returns the family's module with its reference as
 
 A family module holds (see `falcon.py`, the first one):
 
-  seeded weights   `layer_shapes(cfg, layer)`, `global_shapes(cfg)`,
-                   `draw(key, name, shape, cfg)`: the neutral leaves that
-                   `weights.py` draws for reference and program alike;
-                   `layer_kind(cfg, layer)`: layers of one kind have the
-                   same leaves and share a compiled reference block
-  into the program `model(cfg, use, tp)`, `layer_paths(cfg)`,
+  seeded weights   `layer_kind(cfg, layer)`, `layer_shapes(cfg, layer)`,
+                   `global_shapes(cfg)`, `draw(key, name, shape, cfg)`:
+                   the neutral leaves that `weights.py` draws for
+                   reference and program alike
+  into the program `model(cfg, use, tp)`, `layer_paths(cfg, kind)`,
                    `global_paths(cfg)` (neutral leaf -> the program's
                    parameter tree), `trainer_args(cfg, use)`,
                    `engine_args(cfg, use)`, `ROW_PARALLEL` (the leaves
@@ -33,6 +32,42 @@ A family module holds (see `falcon.py`, the first one):
   its reference    `embed`, `block(w, x, cfg, positions, matmul, layer=i)`,
                    `final_logits`, `mean_loss` (cross-entropy and every
                    other term of the family's loss)
+
+The blocks of a model may be of several KINDS (one with attention and one
+without, a dense MLP and a routed one): a block's leaves, their shapes,
+their paths into the program and which of them are row-parallel are its
+kind's. The kind is static. A family whose blocks are all alike has one
+kind and is the case n = 1 of what follows; the harness has no other path
+for it.
+
+  1. `layer_kind(cfg, layer)` gets a Python integer, the block's index
+     among the cell's layers, and returns a hashable. `layer_shapes(cfg,
+     layer)` is only ever called with a Python integer and gives the
+     leaves of that layer's kind. `weights.by_kind` groups the cell's
+     layers by kind, in layer order.
+  2. `weights.make_layer` draws block i from the shapes of its kind with
+     the key `fold_in(key(seed), 1 + i)`, i counted over ALL the layers,
+     its leaves numbered in the sorted order of that block's own names.
+     The index may be a traced argument, so whatever is compiled is
+     compiled once a kind (`weights.layer_makers`: the reference's
+     blocks, the served weights), never once a layer. Stacks are one a
+     kind (`weights.make_stacked`): entry j of a kind's stack is that
+     kind's j-th layer.
+  3. `layer_paths(cfg, kind)` maps that kind's leaves to their paths
+     under the program's `layers`; a kind's stack (an array with a
+     leading axis in training, a view of per-block buffers in serving)
+     goes there whole. The paths of a family of several kinds begin
+     with the name the program gives that kind's stack, so the
+     program's tree holds one stack a kind. `ROW_PARALLEL` names a leaf
+     and the axis the ranks split, for every kind that has a leaf of
+     that name; a block's row-parallel leaves are those of its own.
+  4. Whatever is read leaf by leaf (norms of gradients, moments and
+     changes, on the program's side and the reference's alike) has, for
+     each leaf name, one entry for each block THAT HAS a leaf of that
+     name, in layer order. Two kinds may use one name (their entries
+     then interleave by layer) or different ones.
+  5. The reference's `block` gets its layer's index as `layer=i` and
+     picks its kind by it; layers of one kind share one compiled block.
 """
 
 from __future__ import annotations

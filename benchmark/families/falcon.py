@@ -76,8 +76,9 @@ def draw(key, name: str, shape, cfg: dict):
 ROW_PARALLEL = {"wo": 0, "w2": 0}
 
 
-def layer_paths(cfg: dict) -> dict:
-    """Neutral block leaf -> its path under the program's `layers`."""
+def layer_paths(cfg: dict, kind=None) -> dict:
+    """Neutral block leaf -> its path under the program's `layers`; one
+    kind, so `kind` is not looked at."""
     paths = {
         "ln1_scale": ("input_norm", "scale"),
         "ln1_bias": ("input_norm", "bias"),

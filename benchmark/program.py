@@ -49,27 +49,31 @@ def _get(tree: dict, path):
     return tree
 
 
-def program_tree(cfg: dict, layers: dict, glob: dict) -> dict:
+def program_tree(cfg: dict, stacks: dict, glob: dict) -> dict:
     """Neutral leaves -> the program's parameter tree (same arrays), by
-    the family's paths."""
+    the family's paths. `stacks`: {kind: that kind's stacked leaves}
+    (`weights.make_stacked`); each goes under `layers` whole."""
     fam = families.find(cfg)
     out = {}
-    for name, path in fam.layer_paths(cfg).items():
-        _set(out, ("layers",) + path, layers[name])
+    for kind, stack in stacks.items():
+        for name, path in fam.layer_paths(cfg, kind).items():
+            _set(out, ("layers",) + path, stack[name])
     for name, path in fam.global_paths(cfg).items():
         _set(out, path, glob[name])
     return out
 
 
-def neutral_leaves(cfg: dict, tree: dict) -> dict:
-    """The program's tree -> {neutral name: leaf}; block leaves keep the
-    leading layer axis."""
+def neutral_leaves(cfg: dict, tree: dict, layers: int):
+    """The program's tree -> ({kind: {neutral name: leaf}}, {neutral
+    name: global leaf}), what `program_tree` was given; a kind's leaves
+    keep their leading axis, one entry for each of its layers."""
     fam = families.find(cfg)
-    out = {name: _get(tree, ("layers",) + path)
-           for name, path in fam.layer_paths(cfg).items()}
-    out.update({name: _get(tree, path)
-                for name, path in fam.global_paths(cfg).items()})
-    return out
+    stacks = {kind: {name: _get(tree, ("layers",) + path)
+                     for name, path in fam.layer_paths(cfg, kind).items()}
+              for kind in weights.by_kind(cfg, layers)}
+    glob = {name: _get(tree, path)
+            for name, path in fam.global_paths(cfg).items()}
+    return stacks, glob
 
 
 # ---------------------------------------------------------------- training
@@ -167,14 +171,17 @@ class TrainProgram:
         from .check import one_ranks_share
 
         tp = self.use["tensor_parallel"]
-        paths = self.family.layer_paths(self.cfg)
+        fam, cfg = self.family, self.cfg
 
         def cut(p):
             out = jax.tree.map(lambda x: x, p)  # fresh dicts, same leaves
-            for name, axis in self.family.ROW_PARALLEL.items():
-                path = ("layers",) + paths[name]
-                # stacked: the leading axis is the layer
-                _set(out, path, one_ranks_share(_get(p, path), tp, axis + 1))
+            for kind in weights.by_kind(cfg, self.layers):
+                for name, path in fam.layer_paths(cfg, kind).items():
+                    if name in fam.ROW_PARALLEL:
+                        # stacked: the leading axis is the kind's layers
+                        path = ("layers",) + path
+                        _set(out, path, one_ranks_share(
+                            _get(p, path), tp, fam.ROW_PARALLEL[name] + 1))
             return out
 
         shardings = jax.tree.map(lambda x: x.sharding, params)
@@ -182,18 +189,18 @@ class TrainProgram:
 
     def first_moment_norms(self) -> dict:
         """Per-leaf (and per-block) L2 norms of Adam's first moment."""
-        return _leaf_norms(self.cfg, self.state.opt_state.m)
+        return _leaf_norms(self.cfg, self.state.opt_state.m, self.layers)
 
     def change_norms(self) -> dict:
         """Per-leaf norms of (parameters now - the seeded parameters), in
         one program, so the difference itself is never held."""
-        cfg = self.cfg
+        cfg, layers = self.cfg, self.layers
 
         def norms(p, words):
             diff = jax.tree.map(
                 lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
                 p, self._seeded_params(words))
-            return _norms_of(cfg, neutral_leaves(cfg, diff))
+            return _norms_of(cfg, diff, layers)
 
         got = jax.jit(norms)(self.state.params, self._words)
         return {k: np.asarray(v) for k, v in got.items()}
@@ -211,23 +218,31 @@ class TrainProgram:
         destroy_parallel()
 
 
-def _norms_of(cfg: dict, leaves: dict) -> dict:
-    """{neutral name: norms}: one per block for block leaves (leading
-    layer axis), one for a global leaf. Traced."""
-    per_block = families.find(cfg).layer_paths(cfg)
+def _norms_of(cfg: dict, tree: dict, layers: int) -> dict:
+    """The program's tree -> {neutral name: norms}: for a block leaf one
+    entry for each block that has a leaf of that name, in layer order
+    (two kinds that share a name interleave), for a global leaf one.
+    Traced."""
+    stacks, glob = neutral_leaves(cfg, tree, layers)
+    groups = weights.by_kind(cfg, layers)
+    found = {}  # name -> [(the layers of a kind that has it, their norms)]
+    for kind, stack in stacks.items():
+        for name, x in stack.items():
+            x = jnp.square(x.astype(jnp.float32))
+            found.setdefault(name, []).append(
+                (groups[kind],
+                 jnp.sqrt(jnp.sum(x.reshape(x.shape[0], -1), axis=1))))
     out = {}
-    for name, x in leaves.items():
-        x = jnp.square(x.astype(jnp.float32))
-        if name in per_block:
-            out[name] = jnp.sqrt(jnp.sum(x.reshape(x.shape[0], -1), axis=1))
-        else:
-            out[name] = jnp.sqrt(jnp.sum(x))[None]
+    for name, parts in found.items():
+        order = np.argsort(np.concatenate([idx for idx, _ in parts]))
+        out[name] = jnp.concatenate([n for _, n in parts])[order]
+    for name, x in glob.items():
+        out[name] = jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))[None]
     return out
 
 
-def _leaf_norms(cfg: dict, tree: dict) -> dict:
-    got = jax.jit(lambda leaves: _norms_of(cfg, leaves))(
-        neutral_leaves(cfg, tree))
+def _leaf_norms(cfg: dict, tree: dict, layers: int) -> dict:
+    got = jax.jit(lambda tree: _norms_of(cfg, tree, layers))(tree)
     return {k: np.asarray(v) for k, v in got.items()}
 
 
@@ -242,16 +257,19 @@ def kernel_fallbacks() -> int:
 
 class _StackView:
     """A 'stacked' leaf that is really one standalone buffer per block:
-    `view[i]` is block i's array. The program's `prepare_decode_params`
-    slices `x[i]` out of a stacked tree, which for a model that fills the
-    chip would hold every block twice; this hands it the per-block
-    buffers it is about to make."""
+    `view[j]` is the array of the j-th block of its kind. The program's
+    `prepare_decode_params` slices `x[j]` out of a stacked tree, which
+    for a model that fills the chip would hold every block twice; this
+    hands it the per-block buffers it is about to make. Each is handed
+    out ONCE and not kept: where the program cuts a layout of its own
+    from a leaf, the seeded one goes as soon as the program lets go of
+    it (unless the caller of `ServeProgram` holds the weights itself)."""
 
     def __init__(self, per_layer):
-        self._per_layer = per_layer
+        self._per_layer = dict(enumerate(per_layer))
 
-    def __getitem__(self, i):
-        return self._per_layer[i]
+    def __getitem__(self, j):
+        return self._per_layer.pop(j)
 
 
 class ServeProgram:
@@ -266,16 +284,17 @@ class ServeProgram:
         fam = families.find(cfg)
         self.layers = use["num_hidden_layers"]
         self.model = fam.model(cfg, use)
-        # `made`: weights another engine of this process already holds
-        # (the sweep builds several engines over one set of weights)
-        self.made = made or self.make_weights(cfg, seed, use)
-        per_layer, glob = self.made
+        # `made`: weights the caller holds and keeps (the sweep builds
+        # several engines over one set); made here, they are the engine's
+        # alone once it is built
+        per_layer, glob = made or self.make_weights(cfg, seed, use)
         jax.block_until_ready(per_layer)
         mark("seeded_weights")
-        stacked = {name: _StackView([pl[name] for pl in per_layer])
-                   for name in per_layer[0]}
-        params = program_tree(cfg, stacked, glob)
-        del per_layer, stacked
+        stacks = {kind: {name: _StackView([per_layer[i][name] for i in idx])
+                         for name in per_layer[idx[0]]}
+                  for kind, idx in weights.by_kind(cfg, self.layers).items()}
+        params = program_tree(cfg, stacks, glob)
+        del per_layer, glob, stacks
         self.engine = DecodeEngine(
             self.model, params, slots=use["slots"],
             page_size=use["page_size"], max_context=use["max_context"],
@@ -294,16 +313,19 @@ class ServeProgram:
 
     @staticmethod
     def make_weights(cfg: dict, seed: int, use: dict):
-        """(per-block leaves, global leaves) in the served type: one
-        compiled program, run once per block, on the device."""
+        """(per-block leaves in layer order, global leaves) in the served
+        type: one compiled program a kind of block, run once per block,
+        on the device."""
         dt = _DTYPES[use["weights_dtype"]]
         words = weights.seed_words(seed)
-        make_layer = jax.jit(lambda w, i: jax.tree.map(
-            lambda x: x.astype(dt), weights.make_layer(cfg, w, i)))
-        per_layer = [make_layer(words, jnp.int32(i))
-                     for i in range(use["num_hidden_layers"])]
-        glob = jax.jit(lambda w: jax.tree.map(
-            lambda x: x.astype(dt), weights.make_globals(cfg, w)))(words)
+        L = use["num_hidden_layers"]
+        served = lambda t: jax.tree.map(  # noqa: E731
+            lambda x: x.astype(dt), t)
+        fam = families.find(cfg)
+        make_layer = weights.layer_makers(cfg, L, finish=served)
+        per_layer = [make_layer[fam.layer_kind(cfg, i)](words, jnp.int32(i))
+                     for i in range(L)]
+        glob = jax.jit(lambda w: served(weights.make_globals(cfg, w)))(words)
         return per_layer, glob
 
     def plant(self, fault: str):
@@ -358,7 +380,6 @@ class ServeProgram:
         trees = [eng._pools_k, eng._pools_v, eng._last_logits]
         if not keep_weights:
             trees.append(eng._dec_params)
-            self.made = None
         self.engine = eng = None
         for leaf in jax.tree.leaves(trees):
             if isinstance(leaf, jax.Array) and not leaf.is_deleted():

@@ -140,8 +140,9 @@ def size_serve(cfg: dict, slots=None, chunk=None):
     def arr(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=dev)
 
-    # the decode tree's shapes: the family's seeded leaves laid out as
-    # the program lays them out, then as the engine keeps them
+    # the decode tree's shapes: the family's seeded leaves (one stack a
+    # kind of block) laid out as the program lays them out, then as the
+    # engine keeps them
     dec = jax.eval_shape(lambda: model.prepare_decode_params(
         program.program_tree(cfg, weights.make_stacked(cfg, 0, L),
                              weights.make_globals(cfg, 0))))

@@ -105,26 +105,32 @@ def build_copy(tmp: str) -> str:
                   "w") as f:
             json.dump({"cell": cell["name"], "limits": TINY_LIMITS[kind]}, f)
     add_second_family(base, bench)
+    # a family whose blocks are of two kinds: files only, no cell (the
+    # program has no such model to run one: test_two_kinds.py)
+    add_new_files(base, bench, os.path.join(HERE, "two_kinds"))
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return base
 
 
-def add_second_family(base: str, bench: dict):
-    """New files copied in, entries appended to the index; no file of
-    the copy is opened for writing."""
-    second = os.path.join(HERE, "second_family")
-    for sub in sorted(os.listdir(second)):
-        for f in sorted(os.listdir(os.path.join(second, sub))):
+def add_new_files(base: str, bench: dict, new: str):
+    """A family's new files copied in, its per-layer metrics appended to
+    the index; no file of the copy is opened for writing."""
+    for sub in sorted(os.listdir(new)):
+        for f in sorted(os.listdir(os.path.join(new, sub))):
             target = os.path.join(base, sub, f)
             assert not os.path.exists(target), target
-            shutil.copy(os.path.join(second, sub, f), target)
+            shutil.copy(os.path.join(new, sub, f), target)
             if sub == "metrics":
                 with open(target) as g:
                     m = json.load(g)
                 bench["per_layer"].append({k: m[k] for k in (
                     "name", "unit", "better", "source", "layer", "moves",
                     "workloads")})
+
+
+def add_second_family(base: str, bench: dict):
+    add_new_files(base, bench, os.path.join(HERE, "second_family"))
     bench["configs"].append({
         "name": "tiny2", "source": "benchmark/tests",
         "file": "benchmark/configs/tiny2.json", "reduced": [],

@@ -45,7 +45,7 @@ def draw(key, name: str, shape, cfg: dict):
     return x * std
 
 
-def layer_paths(cfg: dict) -> dict:
+def layer_paths(cfg: dict, kind=None) -> dict:
     return {"ln1_scale": ("input_norm", "scale"),
             "ln2_scale": ("post_attention_norm", "scale"),
             "wqkv": ("attention", "wqkv"), "wo": ("attention", "wo"),

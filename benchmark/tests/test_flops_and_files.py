@@ -197,25 +197,26 @@ def _four_chip_readings():
 
 @pytest.mark.parametrize("what,fails", [
     ("program", None),
-    ("control_int8", {"loss_gap_step2", "loss_gap_step3", "grad_norm_gap",
-                      "change_norm_gap"}),
+    ("control_int8", {"loss_gap_step3", "grad_norm_gap", "change_norm_gap"}),
     ("fault_half_batch", {"loss_gap_step1", "grad_norm_gap",
                           "change_norm_gap"}),
     ("fault_no_exchange", {"loss_gap_step1", "grad_norm_gap",
                            "change_norm_gap"})])
 def test_four_chip_limits_part_the_chips_own_readings(what, fails):
     """Every reading taken on the four chips (my chip runs, PR 28:
-    `prove.py` on 8 seeds, the cell's 13 runs), through the cell's own
-    `verdict` under the limits file as committed: each run of the program
-    comes out correct, the int8 control and each planted fault on every
-    seed not, and each fails at least the numbers the limits file says it
-    is held against."""
+    `prove.py` on 8 seeds, the cell's 13 runs; PR 35: `prove.py` on 17
+    more; the ledger's reading that refused PR 31), through the cell's
+    own `verdict` under the limits file as committed: each run of the
+    program comes out correct, the int8 control and each planted fault
+    on every seed not, and each fails at least the numbers the limits
+    file says it is held against (since PR 35 the second step's loss is
+    not the control's to fail: one of its six seeds reads under it)."""
     from benchmark import check
 
     limits = harness.load_json(harness.HERE, "limits",
                                "falcon40b-train-4chip.json")["limits"]
     rows = [r for r in _four_chip_readings() if r["what"] == what]
-    assert len(rows) >= 3
+    assert len(rows) >= 6
     for row in rows:
         ok, compared = check.verdict(row["numbers"], limits)
         assert ok == (fails is None), row

@@ -45,10 +45,12 @@ def test_forward_loss_and_gradient_agree(tiny_base, name):
                        atol=2e-4)
     loss_r, grads_r = common.loss_and_grads(ref, plain, tokens, labels, cfg)
     assert float(loss_p) == pytest.approx(float(loss_r), rel=1e-5)
-    got = program.neutral_leaves(cfg, grads_p)
-    assert set(got) == set(plain["layers"][0]) | set(plain["globals"])
-    for k, g in got.items():
-        if k in plain["layers"][0]:
+    stacks, got = program.neutral_leaves(cfg, grads_p, L)
+    (stack,) = stacks.values()  # these families have one kind of block
+    assert set(stack) == set(plain["layers"][0])
+    assert set(got) == set(plain["globals"])
+    for k, g in {**stack, **got}.items():
+        if k in stack:
             want = np.stack([np.asarray(l[k]) for l in grads_r["layers"]])
         else:
             want = np.asarray(grads_r["globals"][k])
