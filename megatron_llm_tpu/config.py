@@ -48,6 +48,15 @@ _GRANULARITY_TO_POLICY = {None: "none", "selective": "selective",
 # ---------------------------------------------------------------------------
 
 
+class CapabilityError(ValueError):
+    """A feature was asked of a model that cannot give it yet.
+    `feature` names it; the message says what stands in the way."""
+
+    def __init__(self, feature: str, why: str):
+        super().__init__(f"{feature}: not available for this model ({why})")
+        self.feature = feature
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters (ref: arguments.py:406-474 network_size group)."""
@@ -143,7 +152,41 @@ class ModelConfig:
     num_tokentypes: int = 0
     add_binary_head: bool = False
 
+    # A layer's KIND is (operator, feed-forward), static per layer
+    # (`layer_kind`). `layer_types[i]` names layer i's operator,
+    # "full_attention" or "conv" (the gated short convolution of
+    # models/short_conv.py, `conv_L_cache` taps); None = attention
+    # everywhere. With `num_experts` > 0 every layer from
+    # `num_dense_layers` on has the routed MLP of models/moe.py
+    # (`num_experts_per_tok` experts a token, each `moe_intermediate_size`
+    # wide) in place of the dense one. `qk_layernorm`: RMSNorm over each
+    # q and k head's channels before RoPE.
+    layer_types: Optional[tuple] = None
+    conv_L_cache: int = 3
+    qk_layernorm: bool = False
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: Optional[int] = None
+    num_dense_layers: int = 0
+    use_expert_bias: bool = False
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+
     def __post_init__(self):
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if len(self.layer_types) != self.num_layers or not set(
+                    self.layer_types) <= {"full_attention", "conv"}:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} operators "
+                    f"({sorted(set(self.layer_types))}) for "
+                    f"{self.num_layers} layers of 'full_attention' | 'conv'")
+        if self.num_experts and not (
+                0 < self.num_experts_per_tok <= self.num_experts
+                and self.moe_intermediate_size):
+            raise ValueError(
+                "num_experts > 0 needs num_experts_per_tok in "
+                "[1, num_experts] and moe_intermediate_size")
         if self.kv_channels is None:
             object.__setattr__(
                 self, "kv_channels", self.hidden_size // self.num_attention_heads
@@ -244,6 +287,23 @@ class ModelConfig:
         # GLU doubles the up-projection width (ref: transformer.py:92-102).
         mult = 2 if self.glu_activation else 1
         return mult * self.ffn_hidden_size
+
+    def layer_kind(self, layer: int) -> tuple:
+        """Layer `layer`'s (operator, feed-forward): ("attention" |
+        "conv", "mlp" | "moe")."""
+        conv = self.layer_types is not None \
+            and self.layer_types[layer] == "conv"
+        routed = self.num_experts > 0 and layer >= self.num_dense_layers
+        return ("conv" if conv else "attention", "moe" if routed else "mlp")
+
+    @property
+    def layer_kinds(self) -> tuple:
+        return tuple(self.layer_kind(i) for i in range(self.num_layers))
+
+    @property
+    def has_slot_state(self) -> bool:
+        """Whether serving carries a per-slot state beside the paged K/V."""
+        return any(op == "conv" for op, _ in self.layer_kinds)
 
     def pad_vocab_size(self, vocab_size: int, tp: int = 1) -> int:
         """Pad vocab so it divides evenly over TP ranks (ref: tokenizer.py:49-63)."""

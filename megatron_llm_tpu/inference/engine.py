@@ -132,8 +132,10 @@ from megatron_llm_tpu.analysis.contracts import (
     compile_contract,
     release_variant,
 )
+from megatron_llm_tpu.config import CapabilityError
 from megatron_llm_tpu.inference.generation import bucket_prefill_len
 from megatron_llm_tpu.inference.prefix_cache import PrefixCache
+from megatron_llm_tpu.models.moe import N_STATS
 from megatron_llm_tpu.inference.sampling import (
     NEG_INF,
     modify_logits_for_top_p,
@@ -158,6 +160,10 @@ ROUND_KINDS = ("mixed", "decode", "spec")
 ROUND_NAMES = {"mixed": ("engine.mixed_step", "round.mixed"),
                "decode": ("engine.decode_scan", "round.decode_scan"),
                "spec": ("engine.spec_verify", "round.spec_verify")}
+# `counters()` of a model that routes, in `models/moe.py`'s `stats` order,
+# summed over rounds and routed layers
+MOE_COUNTERS = ("serve_moe_pairs", "serve_moe_experts_touched",
+                "serve_moe_expert_slots", "serve_moe_hottest_pairs")
 HOST_PHASES = ("schedule", "build_inputs", "dispatch", "fetch", "book",
                "wait")
 
@@ -381,6 +387,48 @@ class _Slot:
             self.req.prompt)
 
 
+# a hand-off payload's names for the cache tree's page pools (the wire
+# format of export_prefix / import_prefix)
+_PAYLOAD_NAMES = {"k_pages_layers": "k", "v_pages_layers": "v",
+                  "k_scales_layers": "ks", "v_scales_layers": "vs"}
+
+
+class _CacheStep:
+    """A jitted step over `(dec_params, cache, *operands)`. Called, it is
+    the jitted function. `lower` also takes the pools the way the steps
+    took them before the cache was one tree, `(dec_params, pools_k,
+    pools_v, pools_ks, pools_vs, page_table, ...)`: what
+    `benchmark/sizing.py` still hands it. Those pools name a geometry
+    (pages, page size, type, placement); the tree that is lowered is the
+    model's own for it, so a model that keeps per-slot state is sized
+    with its state and with pools for its attention layers alone."""
+
+    def __init__(self, fn, model):
+        self._fn, self._model = fn, model
+
+    def __call__(self, *args):
+        return self._fn(*args)
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
+
+    def lower(self, dec_params, *args):
+        if not isinstance(args[0], dict):
+            pools_k, page_table = args[0], args[4]
+            like = jax.eval_shape(
+                lambda: self._model.init_paged_kv_caches(
+                    page_table.shape[0], *pools_k[0].shape[:2],
+                    page_table.shape[1], kv_dtype=pools_k[0].dtype))
+            cache = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype,
+                    sharding=getattr(pools_k[0], "sharding", None)),
+                {k: v for k, v in like.items()
+                 if k not in ("page_table", "lengths")})
+            args = (cache,) + args[4:]
+        return self._fn.lower(dec_params, *args)
+
+
 @compile_contract(
     "engine.decode_scan",
     max_variants=16,  # 2 specializations x (log2(horizon)+1) pow2 buckets
@@ -410,23 +458,23 @@ def _make_step_fn(model, vocab_size, horizon, all_greedy):
     current page, paged attention over owned pages). Batching HORIZON
     steps per host round-trip amortizes dispatch latency; the host
     clamps the horizon to the nearest slot completion, so no request
-    ever overruns its budget inside a horizon. Page pools are donated —
-    the update is in place. Int8 engines (ISSUE 9) pass the fp32 scale
-    pools as pools_ks/pools_vs (donated, updated alongside the data in
-    the scan carry); fp engines pass empty tuples and trace the same
-    program they always did."""
+    ever overruns its budget inside a horizon. `cache` is the engine's
+    ONE tree of device state (`GPTModel.init_paged_kv_caches` less the
+    page table and the lengths: the page pools, an int8 engine's fp32
+    scale pools, the per-slot state of layers that keep one), donated —
+    the update is in place — and handed back whole, so a further kind
+    of state is an entry of the tree and not an argument. A model that
+    routes also returns the round's `moe_stats` (models/moe.py)."""
+    routed = model.cfg.num_experts > 0
 
-    def step(dec_params, pools_k, pools_v, pools_ks, pools_vs,
-             page_table, lengths, last_logits, active, forced,
-             use_forced, greedy, temperature, top_k, top_p, seeds,
+    def step(dec_params, cache, page_table, lengths, last_logits, active,
+             forced, use_forced, greedy, temperature, top_k, top_p, seeds,
              sample_steps):
         # forced/use_forced: (slots, horizon) — the remaining prompt
         # tokens are known in advance, so teacher forcing rides the scan
-        quant = len(pools_ks) > 0  # int8 pools carry scale pools
 
         def body(carry, xs):
-            pools_k, pools_v, pools_ks, pools_vs, lengths, last_logits, \
-                steps_c = carry
+            cache, lengths, last_logits, steps_c, stats = carry
             forced_t, use_forced_t = xs
             lp_full = jax.nn.log_softmax(
                 last_logits.astype(jnp.float32), axis=-1)
@@ -444,39 +492,34 @@ def _make_step_fn(model, vocab_size, horizon, all_greedy):
             chosen = jnp.where(active, chosen, 0)
             chosen_lp = jnp.take_along_axis(
                 lp_full, chosen[:, None].astype(jnp.int32), axis=-1)[:, 0]
-            caches = {"k_pages_layers": pools_k,
-                      "v_pages_layers": pools_v,
-                      "page_table": page_table, "lengths": lengths}
-            if quant:
-                caches["k_scales_layers"] = pools_ks
-                caches["v_scales_layers"] = pools_vs
+            caches = {**cache, "page_table": page_table,
+                      "lengths": lengths, "active": active}
             logits, new_caches = model.forward(
                 dec_params, chosen[:, None], kv_caches=caches,
                 position_ids=lengths[:, None],
             )
             steps_c = steps_c + (active & ~use_forced_t)
+            if routed:
+                stats = stats + new_caches["moe_stats"]
             # carry the logits at last_logits' dtype (fp32): a bf16-
             # compute model would otherwise flip the scan carry dtype
             # on the first step and fail trace (no-op for fp32 models,
             # so the bitwise-parity engines are untouched)
-            return ((new_caches["k_pages_layers"],
-                     new_caches["v_pages_layers"],
-                     new_caches.get("k_scales_layers", ()),
-                     new_caches.get("v_scales_layers", ()),
+            return (({k: new_caches[k] for k in cache},
                      new_caches["lengths"],
-                     logits[:, 0].astype(last_logits.dtype), steps_c),
+                     logits[:, 0].astype(last_logits.dtype), steps_c,
+                     stats),
                     (chosen, chosen_lp))
 
-        carry = (pools_k, pools_v, pools_ks, pools_vs, lengths,
-                 last_logits, sample_steps)
+        carry = (cache, lengths, last_logits, sample_steps,
+                 jnp.zeros((N_STATS,), jnp.int32) if routed else None)
         carry, (chosen_h, lp_h) = jax.lax.scan(
             body, carry, (forced.T, use_forced.T))
-        pools_k, pools_v, pools_ks, pools_vs, _, last_logits, _ = carry
+        cache, _, last_logits, _, stats = carry
         # (horizon, slots) -> (slots, horizon)
-        return (chosen_h.T, lp_h.T, last_logits, pools_k, pools_v,
-                pools_ks, pools_vs)
+        return chosen_h.T, lp_h.T, last_logits, cache, stats
 
-    return jax.jit(step, donate_argnums=(1, 2, 3, 4))
+    return _CacheStep(jax.jit(step, donate_argnums=1), model)
 
 
 @compile_contract(
@@ -510,17 +553,17 @@ def _make_mixed_step_fn(model, vocab_size, width, all_greedy):
     1), the decode scan's own shape. Decode rows sample from the carried
     last_logits BEFORE the forward, exactly like the decode scan body,
     so tokens and logprobs are independent of which step flavor served
-    them. Page pools are donated — the update is in place.
+    them. `cache` (the decode scan's: the engine's one tree of device
+    state) is donated — the update is in place.
 
     Returns per-slot (first token, its logprob under last_logits),
     the CHUNK slot's in-chunk logprobs [lp of chunk token p+1 at p],
-    the new last logits, and the pools. last_logits is PRESERVED for
-    idle slots."""
+    the new last logits, the cache and (a model that routes) the
+    round's `moe_stats`. last_logits is PRESERVED for idle slots."""
 
-    def step(dec_params, pools_k, pools_v, pools_ks, pools_vs,
-             page_table, lengths, last_logits, chunk_tokens, chunk_lens,
-             is_prefill, chunk_idx, greedy, temperature, top_k, top_p,
-             seeds, sample_steps):
+    def step(dec_params, cache, page_table, lengths, last_logits,
+             chunk_tokens, chunk_lens, is_prefill, chunk_idx, greedy,
+             temperature, top_k, top_p, seeds, sample_steps):
         # chunk_tokens: (width,) — the admitting slot's span; every
         # other operand is per-slot, as in the decode scan
         active = chunk_lens > 0
@@ -536,12 +579,8 @@ def _make_mixed_step_fn(model, vocab_size, width, all_greedy):
         first = jnp.where(active, first, 0)
         first_lp = jnp.take_along_axis(
             lp_full, first[:, None].astype(jnp.int32), axis=-1)[:, 0]
-        caches = {"k_pages_layers": pools_k, "v_pages_layers": pools_v,
-                  "page_table": page_table, "lengths": lengths,
+        caches = {**cache, "page_table": page_table, "lengths": lengths,
                   "chunk_lens": chunk_lens, "packed_chunk": chunk_idx}
-        if len(pools_ks) > 0:  # int8 pools carry scale pools
-            caches["k_scales_layers"] = pools_ks
-            caches["v_scales_layers"] = pools_vs
         chunk_pos = lengths[chunk_idx] + jnp.arange(width)
         logits, new_caches = model.forward(
             dec_params, jnp.concatenate([chunk_tokens, first])[None],
@@ -574,12 +613,10 @@ def _make_mixed_step_fn(model, vocab_size, width, all_greedy):
                              new_last.astype(last_logits.dtype),
                              last_logits)
         return (first, first_lp, chunk_lps, new_last,
-                new_caches["k_pages_layers"],
-                new_caches["v_pages_layers"],
-                new_caches.get("k_scales_layers", ()),
-                new_caches.get("v_scales_layers", ()))
+                {k: new_caches[k] for k in cache},
+                new_caches.get("moe_stats"))
 
-    return jax.jit(step, donate_argnums=(1, 2, 3, 4))
+    return _CacheStep(jax.jit(step, donate_argnums=1), model)
 
 
 @compile_contract(
@@ -601,9 +638,9 @@ def _make_prefill_fn(model, prefill_len, page_size):
     storage decision, ops/quantization.py). Returns updated pools, the
     slot's next-token logits, and the prompt logprobs of the prefix."""
 
-    def prefill(dec_params, pools_k, pools_v, pools_ks, pools_vs,
-                tokens, pt_row):
-        quant = len(pools_ks) > 0
+    def prefill(dec_params, cache, tokens, pt_row):
+        pools_k, pools_v = cache["k_pages_layers"], cache["v_pages_layers"]
+        quant = "k_scales_layers" in cache
         caches = model.init_kv_caches(1, prefill_len, layout="layers")
         logits, caches = model.forward(dec_params, tokens,
                                        kv_caches=caches)
@@ -623,7 +660,8 @@ def _make_prefill_fn(model, prefill_len, page_size):
 
             new_k, new_v, new_ks, new_vs = [], [], [], []
             for pk, pv, pks, pvs, kl, vl in zip(
-                    pools_k, pools_v, pools_ks, pools_vs,
+                    pools_k, pools_v, cache["k_scales_layers"],
+                    cache["v_scales_layers"],
                     caches["k_layers"], caches["v_layers"]):
                 pk, pks = scatter_quantized_rows(
                     pk, pks, pages, offs, kl[0].transpose(1, 0, 2))
@@ -633,17 +671,21 @@ def _make_prefill_fn(model, prefill_len, page_size):
                 new_v.append(pv)
                 new_ks.append(pks)
                 new_vs.append(pvs)
-            return (tuple(new_k), tuple(new_v), tuple(new_ks),
-                    tuple(new_vs), logits[0, -1], prompt_lp)
+            return ({"k_pages_layers": tuple(new_k),
+                     "v_pages_layers": tuple(new_v),
+                     "k_scales_layers": tuple(new_ks),
+                     "v_scales_layers": tuple(new_vs)},
+                    logits[0, -1], prompt_lp)
         pools_k = tuple(
             pk.at[pages, offs].set(kl[0].transpose(1, 0, 2))
             for pk, kl in zip(pools_k, caches["k_layers"]))
         pools_v = tuple(
             pv.at[pages, offs].set(vl[0].transpose(1, 0, 2))
             for pv, vl in zip(pools_v, caches["v_layers"]))
-        return pools_k, pools_v, (), (), logits[0, -1], prompt_lp
+        return ({"k_pages_layers": pools_k, "v_pages_layers": pools_v},
+                logits[0, -1], prompt_lp)
 
-    return jax.jit(prefill, donate_argnums=(1, 2, 3, 4))
+    return jax.jit(prefill, donate_argnums=1)
 
 
 @compile_contract(
@@ -680,12 +722,12 @@ def _make_spec_step_fn(model, vocab_size, width, all_greedy):
     Returns per-slot (first token, its logprob), the per-position
     greedy targets + their logprobs (the accepted tokens' stream
     values), the accepted counts, the new last logits (preserved for
-    idle slots), and the donated pools."""
+    idle slots), the donated cache tree and (a model that routes) the
+    round's `moe_stats`."""
 
-    def step(dec_params, pools_k, pools_v, pools_ks, pools_vs,
-             page_table, lengths, last_logits, chunk_tokens, chunk_lens,
-             is_spec, greedy, temperature, top_k, top_p, seeds,
-             sample_steps):
+    def step(dec_params, cache, page_table, lengths, last_logits,
+             chunk_tokens, chunk_lens, is_spec, greedy, temperature, top_k,
+             top_p, seeds, sample_steps):
         active = chunk_lens > 0
         lp_full = jax.nn.log_softmax(
             last_logits.astype(jnp.float32), axis=-1)
@@ -699,12 +741,8 @@ def _make_spec_step_fn(model, vocab_size, width, all_greedy):
         first_lp = jnp.take_along_axis(
             lp_full, first[:, None].astype(jnp.int32), axis=-1)[:, 0]
         toks = chunk_tokens.at[:, 0].set(first)
-        caches = {"k_pages_layers": pools_k, "v_pages_layers": pools_v,
-                  "page_table": page_table, "lengths": lengths,
+        caches = {**cache, "page_table": page_table, "lengths": lengths,
                   "chunk_lens": chunk_lens}
-        if len(pools_ks) > 0:  # int8 pools carry scale pools
-            caches["k_scales_layers"] = pools_ks
-            caches["v_scales_layers"] = pools_vs
         logits, new_caches = model.forward(
             dec_params, toks, kv_caches=caches,
             position_ids=lengths[:, None] + jnp.arange(width)[None, :],
@@ -732,12 +770,10 @@ def _make_spec_step_fn(model, vocab_size, width, all_greedy):
                              new_last.astype(last_logits.dtype),
                              last_logits)
         return (first, first_lp, gt, gt_lp, acc, new_last,
-                new_caches["k_pages_layers"],
-                new_caches["v_pages_layers"],
-                new_caches.get("k_scales_layers", ()),
-                new_caches.get("v_scales_layers", ()))
+                {k: new_caches[k] for k in cache},
+                new_caches.get("moe_stats"))
 
-    return jax.jit(step, donate_argnums=(1, 2, 3, 4))
+    return jax.jit(step, donate_argnums=1)
 
 
 @compile_contract(
@@ -761,14 +797,12 @@ def _make_page_copy_fn():
     scalars — one executable serves every COW. The read-before-write
     data dependency orders it against any later scatter into `dst`."""
 
-    def copy(pools_k, pools_v, pools_ks, pools_vs, src, dst):
-        pools_k = tuple(pk.at[dst].set(pk[src]) for pk in pools_k)
-        pools_v = tuple(pv.at[dst].set(pv[src]) for pv in pools_v)
-        pools_ks = tuple(ps.at[dst].set(ps[src]) for ps in pools_ks)
-        pools_vs = tuple(ps.at[dst].set(ps[src]) for ps in pools_vs)
-        return pools_k, pools_v, pools_ks, pools_vs
+    def copy(cache, src, dst):
+        # every leaf is a page pool: a model that keeps per-slot state
+        # is refused the features that copy pages (_refuse_for_slot_state)
+        return jax.tree.map(lambda pool: pool.at[dst].set(pool[src]), cache)
 
-    return jax.jit(copy, donate_argnums=(0, 1, 2, 3))
+    return jax.jit(copy, donate_argnums=0)
 
 
 @compile_contract(
@@ -795,12 +829,8 @@ def _make_page_export_fn():
     Pools are NOT donated: an export is a read, and the donor keeps
     serving from the same buffers."""
 
-    def export(pools_k, pools_v, pools_ks, pools_vs, ids):
-        rows_k = tuple(pk[ids] for pk in pools_k)
-        rows_v = tuple(pv[ids] for pv in pools_v)
-        rows_ks = tuple(ps[ids] for ps in pools_ks)
-        rows_vs = tuple(ps[ids] for ps in pools_vs)
-        return rows_k, rows_v, rows_ks, rows_vs
+    def export(cache, ids):
+        return jax.tree.map(lambda pool: pool[ids], cache)
 
     return jax.jit(export)
 
@@ -829,19 +859,10 @@ def _make_page_import_fn():
     row maps for reads. Pools are donated — the splice is in place,
     exactly like page_copy."""
 
-    def imp(pools_k, pools_v, pools_ks, pools_vs, ids,
-            rows_k, rows_v, rows_ks, rows_vs):
-        pools_k = tuple(pk.at[ids].set(rk)
-                        for pk, rk in zip(pools_k, rows_k))
-        pools_v = tuple(pv.at[ids].set(rv)
-                        for pv, rv in zip(pools_v, rows_v))
-        pools_ks = tuple(ps.at[ids].set(rs)
-                         for ps, rs in zip(pools_ks, rows_ks))
-        pools_vs = tuple(ps.at[ids].set(rs)
-                         for ps, rs in zip(pools_vs, rows_vs))
-        return pools_k, pools_v, pools_ks, pools_vs
+    def imp(cache, ids, rows):
+        return jax.tree.map(lambda pool, r: pool.at[ids].set(r), cache, rows)
 
-    return jax.jit(imp, donate_argnums=(0, 1, 2, 3))
+    return jax.jit(imp, donate_argnums=0)
 
 
 class DecodeEngine:
@@ -1008,6 +1029,10 @@ class DecodeEngine:
                 f"{kv_dtype!r}")
         self.model = model
         self.cfg = model.cfg
+        self._refuse_for_slot_state(
+            prefix_cache=prefix_cache, spec_decode_k=spec_decode_k,
+            prefill_chunk_tokens=prefill_chunk_tokens,
+            serving_tp=serving_tp, quantize_weights=quantize_weights)
         # -- tp mesh (ISSUE 14) -------------------------------------------
         # serving_tp > 1: the pools shard over the head/group axis
         # (kv_pool_spec, the zero1_axis one-rule idiom) and every
@@ -1157,14 +1182,12 @@ class DecodeEngine:
             slots, self.num_pages, page_size, self.max_pages_per_slot,
             kv_dtype=jnp.int8 if kv_dtype == "int8" else None,
             mesh_ctx=self._ctx)
-        self._pools_k = caches["k_pages_layers"]
-        self._pools_v = caches["v_pages_layers"]
-        # int8 engines (ISSUE 9): per-layer fp32 scale pools ride every
-        # jitted step alongside the data pools (donated, updated in
-        # place); fp engines carry empty tuples through the same
-        # signatures — ONE step-fn shape for both modes
-        self._pools_ks = caches.get("k_scales_layers", ())
-        self._pools_vs = caches.get("v_scales_layers", ())
+        # ONE tree of device state that every step takes, donates and
+        # hands back whole: the page pools, an int8 engine's fp32 scale
+        # pools (ISSUE 9), the per-slot state of layers that keep one.
+        # The page table and the lengths are the host's (mirrors below).
+        self._cache = {k: v for k, v in caches.items()
+                       if k not in ("page_table", "lengths")}
         if kv_dtype == "int8" and page_size % 32 != 0:
             # the int8 Pallas gate needs 32-sublane pages: with this
             # page_size every TPU step silently takes the dequantizing
@@ -1192,10 +1215,8 @@ class DecodeEngine:
             # program and the first round another (seen on the v5e at
             # PR 34 with committed leaves: three 32-layer compiles
             # inside a 50 s window)
-            (self._pools_k, self._pools_v, self._pools_ks, self._pools_vs,
-             self._last_logits) = jax.device_put(
-                (self._pools_k, self._pools_v, self._pools_ks,
-                 self._pools_vs, self._last_logits), homes.pop())
+            self._cache, self._last_logits = jax.device_put(
+                (self._cache, self._last_logits), homes.pop())
         # host-authoritative mirrors (tiny; shipped to device each step)
         self._pt = np.zeros((slots, self.max_pages_per_slot), np.int32)
         self._lengths = np.zeros((slots,), np.int32)
@@ -1376,6 +1397,7 @@ class DecodeEngine:
         # carried a real token, rounds and summed wall ms by kind, and
         # the summed durations of the round's phase spans
         self._rows_computed = 0
+        self._moe_stats = np.zeros(N_STATS, np.int64)
         self._rows_useful = 0
         self._kind_rounds = dict.fromkeys(ROUND_KINDS, 0)
         self._kind_ms = dict.fromkeys(ROUND_KINDS, 0.0)
@@ -1720,10 +1742,8 @@ class DecodeEngine:
                                 "cow_copy", rid=req.rid,
                                 src=match.cow_src,
                                 dst=pages[match.full_pages]):
-                            (self._pools_k, self._pools_v, self._pools_ks,
-                             self._pools_vs) = self._copy_fn(
-                                self._pools_k, self._pools_v,
-                                self._pools_ks, self._pools_vs,
+                            self._cache = self._copy_fn(
+                                self._cache,
                                 self._dev(match.cow_src, np.int32),
                                 self._dev(pages[match.full_pages],
                                           np.int32))
@@ -1739,11 +1759,9 @@ class DecodeEngine:
                 plen = bucket_prefill_len(len(req.prompt))
                 with self.tracer.span("prefill_bucket", rid=req.rid,
                                       slot=si, tokens=plen):
-                    (self._pools_k, self._pools_v, self._pools_ks,
-                     self._pools_vs, row_logits, plp) = \
+                    self._cache, row_logits, plp = \
                         self._prefill_fn(plen)(
-                            self._dec_params, self._pools_k, self._pools_v,
-                            self._pools_ks, self._pools_vs,
+                            self._dec_params, self._cache,
                             self._dev(np.asarray(req.prompt[:plen],
                                                  np.int32)[None]),
                             self._dev(self._pt[si]),
@@ -2213,6 +2231,8 @@ class DecodeEngine:
                 self._decode_ms.append(advance_ms)
             self._rows_computed += facts["rows_computed"]
             self._rows_useful += facts["rows_useful"]
+            if facts["moe_stats"] is not None:
+                self._moe_stats += facts["moe_stats"]
             self._kind_rounds[kind] += 1
             self._kind_ms[kind] += dt_ms
             for phase, span in facts["phases"].items():
@@ -2337,16 +2357,15 @@ class DecodeEngine:
                               greedy=all_greedy,
                               prefill_tokens=prefill_tokens,
                               decode_slots=len(live)) as sp_disp:
-            (chosen, chosen_lp, new_logits, self._pools_k, self._pools_v,
-             self._pools_ks, self._pools_vs) = \
+            chosen, chosen_lp, new_logits, self._cache, moe_stats = \
                 self._step_fn(hor, all_greedy)(
-                    self._dec_params, self._pools_k, self._pools_v,
-                    self._pools_ks, self._pools_vs, *operands)
+                    self._dec_params, self._cache, *operands)
             self._last_logits = new_logits
         with self.tracer.span("engine.fetch") as sp_fetch:
             chosen = np.asarray(chosen)  # (slots, hor) — the scheduler's
             # own data dependency: the next round cannot be built
             # without it
+            moe_stats = self._fetch_moe_stats(moe_stats)
             # P0 (graft-check GR006 dogfood): the logprob matrix is an
             # EXTRA per-round device->host transfer that most serving
             # traffic (return_log_probs=False) never reads — fetch it
@@ -2386,6 +2405,7 @@ class DecodeEngine:
             # stall, when any, rides this round's wall time — that IS
             # the interference)
             "advance_div": hor,
+            "moe_stats": moe_stats,
             "rows_computed": self.slots * hor,
             "rows_useful": len(live) * hor,
             "phases": {"build_inputs": sp_build, "dispatch": sp_disp,
@@ -2468,14 +2488,13 @@ class DecodeEngine:
                               greedy=all_greedy, rid=chunk_rid,
                               prefill_tokens=ln,
                               decode_slots=len(dec)) as sp_disp:
-            (first, first_lp, chunk_lps, new_last, self._pools_k,
-             self._pools_v, self._pools_ks, self._pools_vs) = \
-                self._mixed_fn(width, all_greedy)(
-                    self._dec_params, self._pools_k, self._pools_v,
-                    self._pools_ks, self._pools_vs, *operands)
+            (first, first_lp, chunk_lps, new_last, self._cache,
+             moe_stats) = self._mixed_fn(width, all_greedy)(
+                    self._dec_params, self._cache, *operands)
             self._last_logits = new_last
         with self.tracer.span("engine.fetch") as sp_fetch:
             first = np.asarray(first)
+            moe_stats = self._fetch_moe_stats(moe_stats)
             # P0 (graft-check GR006 dogfood): logprob outputs transfer
             # only when a live request asked for them — the mixed round
             # is the chunked-prefill interference path the decode-p95
@@ -2528,6 +2547,7 @@ class DecodeEngine:
             "log": {"prefill_tokens": ln, "decode_steps": 1,
                     "decode_slots": len(dec)},
             "advance_div": 1 if dec else None,
+            "moe_stats": moe_stats,
             # the chunk at its width plus one row a slot; the chunk's
             # tokens and one token a decoding slot are real
             "rows_computed": width + n,
@@ -2707,14 +2727,13 @@ class DecodeEngine:
                               kind="spec", width=width, greedy=all_greedy,
                               prefill_tokens=prefill_tokens,
                               decode_slots=len(live)) as sp_disp:
-            (first, first_lp, gt, gt_lp, acc, new_last, self._pools_k,
-             self._pools_v, self._pools_ks, self._pools_vs) = \
-                self._spec_fn(width, all_greedy)(
-                    self._dec_params, self._pools_k, self._pools_v,
-                    self._pools_ks, self._pools_vs, *operands)
+            (first, first_lp, gt, gt_lp, acc, new_last, self._cache,
+             moe_stats) = self._spec_fn(width, all_greedy)(
+                    self._dec_params, self._cache, *operands)
             self._last_logits = new_last
         with self.tracer.span("engine.fetch") as sp_fetch:
             first = np.asarray(first)
+            moe_stats = self._fetch_moe_stats(moe_stats)
             gt = np.asarray(gt)
             acc = np.asarray(acc)
             # P0 (graft-check GR006 dogfood): the two logprob matrices
@@ -2785,6 +2804,7 @@ class DecodeEngine:
             # per decode-token advance: one spec round advances
             # emitted/live tokens per slot
             "advance_div": max(emitted_total, 1) / len(live),
+            "moe_stats": moe_stats,
             # every slot is laid out k+1 wide; the booked tokens (first
             # + accepted drafts) are what the round was for
             "rows_computed": n * width,
@@ -2839,6 +2859,7 @@ class DecodeEngine:
         is a read, and LRU eviction reclaims them under pressure, so a
         hand-off that dies on the receiving side needs no donor-side
         cleanup at all."""
+        self._refuse_page_transfer("page export (export_prefix)")
         if self._prefix is None:
             raise ValueError(
                 "export_prefix needs prefix_cache=True: the transfer "
@@ -2858,6 +2879,7 @@ class DecodeEngine:
         caller falls back to prefilling locally). Geometry/dtype
         mismatches (page size, kv dtype, layer shapes) raise
         ValueError: splicing incompatible pages would poison decode."""
+        self._refuse_page_transfer("page import (import_prefix)")
         if self._prefix is None:
             raise ValueError(
                 "import_prefix needs prefix_cache=True: transferred "
@@ -2892,9 +2914,8 @@ class DecodeEngine:
                 f"{payload.get('dtype')} != pool "
                 f"{self.kv_pool_dtype()} — a cross-dtype splice would "
                 f"decode garbage")
-        for name, pools in (("k", self._pools_k), ("v", self._pools_v),
-                            ("ks", self._pools_ks),
-                            ("vs", self._pools_vs)):
+        for key, name in _PAYLOAD_NAMES.items():
+            pools = self._cache.get(key, ())
             rows = payload.get(name) or []
             if len(rows) != len(pools):
                 raise ValueError(
@@ -2982,20 +3003,14 @@ class DecodeEngine:
         try:
             ids = np.zeros(self.max_pages_per_slot, np.int32)
             ids[:n] = match.pages[:n]
-            rows_k, rows_v, rows_ks, rows_vs = self._export_fn(
-                self._pools_k, self._pools_v, self._pools_ks,
-                self._pools_vs, self._dev(ids))
-
-            def host(rows):
-                return [np.asarray(r)[:n] for r in rows]
-
+            rows = self._export_fn(self._cache, self._dev(ids))
             payload = {
                 "tokens": list(prompt[: n * self.page_size]),
                 "pages": n,
                 "page_size": self.page_size,
                 "dtype": self.kv_pool_dtype(),
-                "k": host(rows_k), "v": host(rows_v),
-                "ks": host(rows_ks), "vs": host(rows_vs),
+                **{name: [np.asarray(r)[:n] for r in rows.get(key, ())]
+                   for key, name in _PAYLOAD_NAMES.items()},
             }
         finally:
             self._prefix.unacquire(match)
@@ -3026,14 +3041,10 @@ class DecodeEngine:
                 out.append(self._dev(block))
             return tuple(out)
 
-        (self._pools_k, self._pools_v, self._pools_ks,
-         self._pools_vs) = self._import_fn(
-            self._pools_k, self._pools_v, self._pools_ks,
-            self._pools_vs, self._dev(ids),
-            pad(payload["k"], self._pools_k),
-            pad(payload["v"], self._pools_v),
-            pad(payload["ks"], self._pools_ks),
-            pad(payload["vs"], self._pools_vs))
+        self._cache = self._import_fn(
+            self._cache, self._dev(ids),
+            {key: pad(payload[_PAYLOAD_NAMES[key]], pools)
+             for key, pools in self._cache.items()})
         rejected = self._prefix.insert_chain(
             [int(t) for t in payload["tokens"]], pages)
         self._free_pages.extend(rejected)
@@ -3133,8 +3144,7 @@ class DecodeEngine:
     def _null_scan_args(self, h: int) -> tuple:
         n = self.slots
         zeros_i = self._dev(np.zeros((n,), np.int32))
-        return (self._dec_params, self._pools_k, self._pools_v,
-                self._pools_ks, self._pools_vs,
+        return (self._dec_params, self._cache,
                 self._dev(np.zeros_like(self._pt)), zeros_i,
                 self._last_logits,
                 self._dev(np.zeros(n, bool)),
@@ -3150,8 +3160,7 @@ class DecodeEngine:
     def _null_mixed_args(self, w: int) -> tuple:
         n = self.slots
         zeros_i = self._dev(np.zeros((n,), np.int32))
-        return (self._dec_params, self._pools_k, self._pools_v,
-                self._pools_ks, self._pools_vs,
+        return (self._dec_params, self._cache,
                 self._dev(np.zeros_like(self._pt)), zeros_i,
                 self._last_logits,
                 self._dev(np.zeros((w,), np.int32)),
@@ -3168,8 +3177,7 @@ class DecodeEngine:
     def _null_spec_args(self, w: int) -> tuple:
         n = self.slots
         zeros_i = self._dev(np.zeros((n,), np.int32))
-        return (self._dec_params, self._pools_k, self._pools_v,
-                self._pools_ks, self._pools_vs,
+        return (self._dec_params, self._cache,
                 self._dev(np.zeros_like(self._pt)), zeros_i,
                 self._last_logits,
                 self._dev(np.zeros((n, w), np.int32)),
@@ -3183,14 +3191,12 @@ class DecodeEngine:
                 zeros_i)
 
     def _null_prefill_args(self, plen: int) -> tuple:
-        return (self._dec_params, self._pools_k, self._pools_v,
-                self._pools_ks, self._pools_vs,
+        return (self._dec_params, self._cache,
                 self._dev(np.zeros((1, plen), np.int32)),
                 self._dev(self._pt[0]))
 
     def _null_copy_args(self) -> tuple:
-        return (self._pools_k, self._pools_v, self._pools_ks,
-                self._pools_vs, self._dev(0, np.int32),
+        return (self._cache, self._dev(0, np.int32),
                 self._dev(0, np.int32))
 
     def _null_xfer_ids(self):
@@ -3199,28 +3205,21 @@ class DecodeEngine:
         return self._dev(
             np.zeros(self.max_pages_per_slot, np.int32))
 
-    def _null_payload_rows(self) -> tuple:
+    def _null_payload_rows(self) -> dict:
         """Zero payload row blocks shaped like a full-width import —
         one (max_pages_per_slot, ...) block per layer pool, pool
         dtypes, on the engine's devices."""
         P = self.max_pages_per_slot
-
-        def rows(pools):
-            return tuple(
-                self._dev(np.zeros((P,) + tuple(p.shape[1:]),
-                                   np.dtype(p.dtype))) for p in pools)
-
-        return (rows(self._pools_k), rows(self._pools_v),
-                rows(self._pools_ks), rows(self._pools_vs))
+        return jax.tree.map(
+            lambda p: self._dev(np.zeros((P,) + tuple(p.shape[1:]),
+                                         np.dtype(p.dtype))), self._cache)
 
     def _null_export_args(self) -> tuple:
-        return (self._pools_k, self._pools_v, self._pools_ks,
-                self._pools_vs, self._null_xfer_ids())
+        return (self._cache, self._null_xfer_ids())
 
     def _null_import_args(self) -> tuple:
-        rk, rv, rks, rvs = self._null_payload_rows()
-        return (self._pools_k, self._pools_v, self._pools_ks,
-                self._pools_vs, self._null_xfer_ids(), rk, rv, rks, rvs)
+        return (self._cache, self._null_xfer_ids(),
+                self._null_payload_rows())
 
     def warmup(self):
         """Pre-trace every step executable the configured buckets can
@@ -3237,19 +3236,16 @@ class DecodeEngine:
 
     def _warmup_scoped(self):
         for h in horizon_buckets(self.step_horizon):
-            (_, _, _, self._pools_k, self._pools_v, self._pools_ks,
-             self._pools_vs) = self._step_fn(h, True)(
-                *self._null_scan_args(h))
+            self._cache = self._step_fn(h, True)(
+                *self._null_scan_args(h))[-2]
         if self.prefill_chunk_tokens:
             for w in mixed_width_buckets(self.prefill_chunk_tokens):
-                (_, _, _, _, self._pools_k, self._pools_v,
-                 self._pools_ks, self._pools_vs) = \
-                    self._mixed_fn(w, True)(*self._null_mixed_args(w))
+                self._cache = self._mixed_fn(w, True)(
+                    *self._null_mixed_args(w))[-2]
         if self.spec_decode_k:
             w = self.spec_decode_k + 1
-            (_, _, _, _, _, _, self._pools_k, self._pools_v,
-             self._pools_ks, self._pools_vs) = \
-                self._spec_fn(w, True)(*self._null_spec_args(w))
+            self._cache = self._spec_fn(w, True)(
+                *self._null_spec_args(w))[-2]
         if self._prefix is not None:
             # hand-off pair (ISSUE 17): the first cross-replica
             # transfer must not eat a compile stall mid-burst. The
@@ -3258,8 +3254,7 @@ class DecodeEngine:
             # it is invisible to traffic; pools are reassigned from
             # the donated outputs.
             self._export_fn(*self._null_export_args())
-            (self._pools_k, self._pools_v, self._pools_ks,
-             self._pools_vs) = self._import_fn(*self._null_import_args())
+            self._cache = self._import_fn(*self._null_import_args())
 
     def audit_entry_points(self):
         """(contract name, jitted fn, example args) for every jitted
@@ -3426,6 +3421,47 @@ class DecodeEngine:
 
     # -- observability -----------------------------------------------------
 
+    # the page pools of the cache tree, under the names they had as four
+    # tuples (read by tests, gauges and benchmark/program.py)
+    _pools_k = property(lambda self: self._cache["k_pages_layers"])
+    _pools_v = property(lambda self: self._cache["v_pages_layers"])
+    _pools_ks = property(
+        lambda self: self._cache.get("k_scales_layers", ()))
+    _pools_vs = property(
+        lambda self: self._cache.get("v_scales_layers", ()))
+
+    def _refuse_for_slot_state(self, **asked) -> None:
+        """THE capability check of a model that keeps per-slot state
+        beside the paged K/V (a conv layer's last gated inputs):
+        whatever copies, shares, exports or rolls back PAGES cannot yet
+        do so for a slot's state, and what builds the decode tree or the
+        mesh knows one kind of layer. Each is refused by name."""
+        if not self.cfg.has_slot_state:
+            return
+        why = "a layer keeps per-slot state beside the paged K/V"
+        refused = {
+            "prefix_cache": asked["prefix_cache"],
+            "spec_decode_k": asked["spec_decode_k"] > 0,
+            "whole-prompt admission (prefill_chunk_tokens=0)":
+                asked["prefill_chunk_tokens"] == 0,
+            "serving_tp": asked["serving_tp"] > 1,
+            "quantize_weights": asked["quantize_weights"],
+        }
+        for feature, on in refused.items():
+            if on:
+                raise CapabilityError(feature, why)
+
+    def _refuse_page_transfer(self, feature: str) -> None:
+        if self.cfg.has_slot_state:
+            raise CapabilityError(
+                feature, "a slot's state would have to travel with its "
+                "pages")
+
+    def _fetch_moe_stats(self, stats) -> Optional[np.ndarray]:
+        """The round's four routing integers (models/moe.py), fetched
+        beside the round's tokens; None for a model that does not route."""
+        return None if stats is None else np.asarray(stats)
+
     def kv_pool_dtype(self) -> str:
         """The pool's ACTUAL storage dtype (e.g. 'int8', 'bfloat16',
         'float32') — what the gauges report. kv_dtype='bf16' means
@@ -3446,7 +3482,7 @@ class DecodeEngine:
         HBM). Pinned by tests/test_tp_serving.py."""
         total = 0
         for x in (*self._pools_k, *self._pools_v,
-                  *self._pools_ks, *self._pools_vs):
+                  *self._pools_ks, *self._pools_vs):  # pages, not slot state
             shard = x.sharding.shard_shape(x.shape)
             total += int(np.prod(shard)) * x.dtype.itemsize
         return total
@@ -3603,6 +3639,11 @@ class DecodeEngine:
             for phase in HOST_PHASES:
                 out["serve_host_ms_" + phase] = round(
                     self._host_ms[phase], 3)
+            if self.cfg.num_experts:
+                # a model that routes, after everything pinned before:
+                # the rounds' own integers, booked by the serve loop
+                for name, value in zip(MOE_COUNTERS, self._moe_stats):
+                    out[name] = int(value)
         return out
 
     def export_gauges(self, timers=None):

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.extend.source_info_util import current_name_stack
 from jax.sharding import PartitionSpec as P
 
+from megatron_llm_tpu.models.norms import rms_norm
 from megatron_llm_tpu.models.remat import tag as _savepoint
 from megatron_llm_tpu.models.rope import apply_rope
 from megatron_llm_tpu.ops.quantization import qdot
@@ -363,6 +364,11 @@ def attention_block(
         # rebuild from it with elementwise ops only (models/remat.py)
         mixed = _savepoint(mixed, "qkv_proj")
         q, k, v = split_qkv(mixed, cfg)
+        if "q_norm" in attn_params:
+            # RMSNorm over each head's channels, one scale for the q
+            # heads and one for the k heads, before RoPE (qk_layernorm)
+            q = rms_norm(q, attn_params["q_norm"], cfg.layernorm_epsilon)
+            k = rms_norm(k, attn_params["k_norm"], cfg.layernorm_epsilon)
         q = shard_activation(q, "groups")
 
     if kv_cache is not None and "k_pages" in kv_cache:

@@ -252,17 +252,30 @@ class GPTModel:
                 "quantize_int8 requires the flattened GLU decode "
                 "layout (quantize_decode_layers quantizes the 2D "
                 "view); tp-sharded engines serve the fp decode tree")
-        L = self.cfg.num_layers
-        stacked = params["layers"]
+        from megatron_llm_tpu.config import CapabilityError
+        from megatron_llm_tpu.models.transformer import kind_stacks
 
-        def layer_slice(i):
-            layer = dict(jax.tree.map(lambda x: x[i], stacked))
-            attn = dict(layer["attention"])
-            wqkv = attn["wqkv"]
-            attn["wqkv"] = wqkv.T.reshape(-1, self.cfg.head_dim,
-                                          wqkv.shape[0])
-            layer["attention"] = attn
-            if self.cfg.glu_activation and flatten_glu:
+        kinds = self.cfg.layer_kinds
+        if quantize_int8 and set(kinds) != {("attention", "mlp")}:
+            raise CapabilityError(
+                "quantize_weights", "the int8 decode tree is cut for "
+                "attention + dense-MLP layers only")
+        stacks = kind_stacks(self.cfg, params["layers"])
+        taken = dict.fromkeys(stacks, 0)
+
+        def layer_slice(kind):
+            # the tree of the next layer of its kind, by what it holds:
+            # entry j of a kind's stack is that kind's j-th layer
+            j = taken[kind]
+            taken[kind] = j + 1
+            layer = dict(jax.tree.map(lambda x: x[j], stacks[kind]))
+            if "attention" in layer:
+                attn = dict(layer["attention"])
+                wqkv = attn["wqkv"]
+                attn["wqkv"] = wqkv.T.reshape(-1, self.cfg.head_dim,
+                                              wqkv.shape[0])
+                layer["attention"] = attn
+            if "mlp" in layer and self.cfg.glu_activation and flatten_glu:
                 mlp = dict(layer["mlp"])
                 w1 = mlp["w1"]
                 mlp["w1"] = w1.reshape(w1.shape[0], -1)
@@ -270,7 +283,7 @@ class GPTModel:
             return layer
 
         params = dict(params)
-        params["layers"] = tuple(layer_slice(i) for i in range(L))
+        params["layers"] = tuple(layer_slice(kind) for kind in kinds)
         if quantize_int8:
             from megatron_llm_tpu.ops.quantization import (
                 quantize_decode_layers,
@@ -351,8 +364,15 @@ class GPTModel:
         kv_pool_spec sharding (group axis over `model`,
         parallel/sharding.py — the per-chip pool is 1/tp the bytes,
         never allocated whole on one chip), while the page table and
-        lengths stay replicated scalar-prefetch operands."""
+        lengths stay replicated scalar-prefetch operands.
+
+        Pools are one an ATTENTION layer, in layer order. A layer whose
+        operator is the short convolution holds no page: its entry is
+        the slots' state, `conv_state_layers` (slots, taps - 1, h), one
+        a conv layer (models/short_conv.py)."""
         cfg = self.cfg
+        operators = [op for op, _ in cfg.layer_kinds]
+        n_attention = operators.count("attention")
         kv_dtype = cfg.compute_dtype if kv_dtype is None else kv_dtype
         shape = (num_pages, page_size, cfg.num_query_groups, cfg.head_dim)
 
@@ -394,9 +414,9 @@ class GPTModel:
 
         caches = {
             "k_pages_layers": tuple(zeros(shape, kv_dtype)
-                                    for _ in range(cfg.num_layers)),
+                                    for _ in range(n_attention)),
             "v_pages_layers": tuple(zeros(shape, kv_dtype)
-                                    for _ in range(cfg.num_layers)),
+                                    for _ in range(n_attention)),
             "page_table": zeros_rep((slots, max_pages_per_slot),
                                     jnp.int32),
             "lengths": zeros_rep((slots,), jnp.int32),
@@ -405,8 +425,13 @@ class GPTModel:
             sshape = shape[:-1]
             caches["k_scales_layers"] = tuple(
                 zeros(sshape, jnp.float32)
-                for _ in range(cfg.num_layers))
+                for _ in range(n_attention))
             caches["v_scales_layers"] = tuple(
                 zeros(sshape, jnp.float32)
-                for _ in range(cfg.num_layers))
+                for _ in range(n_attention))
+        if n_attention < len(operators):
+            caches["conv_state_layers"] = tuple(
+                zeros_rep((slots, cfg.conv_L_cache - 1, cfg.hidden_size),
+                          cfg.compute_dtype)
+                for _ in range(len(operators) - n_attention))
         return caches
