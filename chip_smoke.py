@@ -505,10 +505,11 @@ def check_kernels():
     n_pages = slots * ctx // page + 1
     table = (1 + jnp.arange(slots * (ctx // page), dtype=jnp.int32)
              ).reshape(slots, ctx // page)
-    kp, vp = rnd(n_pages, page, g, d), rnd(n_pages, page, g, d)
+    # lane-packed pools: a token's g heads side by side
+    kp, vp = rnd(n_pages, page, g * d), rnd(n_pages, page, g * d)
     k8 = jax.random.randint(next(keys), kp.shape, -127, 128, jnp.int8)
     v8 = jax.random.randint(next(keys), kp.shape, -127, 128, jnp.int8)
-    ks = jax.random.uniform(next(keys), kp.shape[:-1], jnp.float32,
+    ks = jax.random.uniform(next(keys), (n_pages, page, g), jnp.float32,
                             0.005, 0.02)
 
     def paged(use, window, qc, kn, vn, kpool, vpool, starts, lens, *scales):

@@ -8,9 +8,12 @@ This engine is the serving-side alternative, after Ragged Paged
 Attention (arxiv 2604.15464) and the slot-level-admission result of the
 Gemma-on-TPU serving study (arxiv 2605.25645):
 
-- the cache is a GLOBAL page pool per layer (num_pages, page_size, g,
-  d) plus one (slots, max_pages) page table and per-slot lengths
-  (models/gpt.py init_paged_kv_caches); HBM holds `page_budget` tokens
+- the cache is a GLOBAL page pool per layer, held lane-packed
+  (num_pages, page_size, g * d): a token's K/V heads side by side, the
+  order a round writes and reads (models/gpt.py init_paged_kv_caches
+  states the shape, ops/prefill_attention.py which head widths take
+  its kernel), plus one (slots, max_pages) page table and per-slot
+  lengths; HBM holds `page_budget` tokens
   of KV total, not slots * max_len;
 - a fixed number of SLOTS decode in lockstep through a jitted
   lax.scan of up to `step_horizon` single-token steps per host
@@ -632,10 +635,11 @@ def _make_prefill_fn(model, prefill_len, page_size):
     """Bucketed prefill, traced once per bucket: one causal forward over
     the prompt's bucket prefix through dense per-layer caches, whose
     K/V rows are scattered STRAIGHT into the slot's pool pages inside
-    the same jitted program (XLA fuses the relayout with the cache
-    write). Int8 pools quantize each (token, group) row at the same
-    scatter (the dense prefill math itself stays fp — quantization is a
-    storage decision, ops/quantization.py). Returns updated pools, the
+    the same jitted program, a token's heads side by side as the
+    lane-packed pool holds them. Int8 pools quantize each (token,
+    group) row at the same scatter (the dense prefill math itself
+    stays fp — quantization is a storage decision,
+    ops/quantization.py). Returns updated pools, the
     slot's next-token logits, and the prompt logprobs of the prefix."""
 
     def prefill(dec_params, cache, tokens, pt_row):
@@ -676,11 +680,14 @@ def _make_prefill_fn(model, prefill_len, page_size):
                      "k_scales_layers": tuple(new_ks),
                      "v_scales_layers": tuple(new_vs)},
                     logits[0, -1], prompt_lp)
+        def rows(x):  # (1, g, T, d) dense cache -> (T, g * d) pool rows
+            return x[0].transpose(1, 0, 2).reshape(prefill_len, -1)
+
         pools_k = tuple(
-            pk.at[pages, offs].set(kl[0].transpose(1, 0, 2))
+            pk.at[pages, offs].set(rows(kl))
             for pk, kl in zip(pools_k, caches["k_layers"]))
         pools_v = tuple(
-            pv.at[pages, offs].set(vl[0].transpose(1, 0, 2))
+            pv.at[pages, offs].set(rows(vl))
             for pv, vl in zip(pools_v, caches["v_layers"]))
         return ({"k_pages_layers": pools_k, "v_pages_layers": pools_v},
                 logits[0, -1], prompt_lp)
@@ -1034,8 +1041,9 @@ class DecodeEngine:
             prefill_chunk_tokens=prefill_chunk_tokens,
             serving_tp=serving_tp, quantize_weights=quantize_weights)
         # -- tp mesh (ISSUE 14) -------------------------------------------
-        # serving_tp > 1: the pools shard over the head/group axis
-        # (kv_pool_spec, the zero1_axis one-rule idiom) and every
+        # serving_tp > 1: the pools shard over their heads (the lanes
+        # of a lane-packed pool: kv_pool_spec, the zero1_axis one-rule
+        # idiom) and every
         # jitted step runs under pjit on a (1, 1, 1, tp) mesh via GSPMD
         # constraints; the paged attention call alone runs per shard
         # (parallel/mesh.shard_kernel). `devices` pins

@@ -314,7 +314,8 @@ def attention_block(
     - per-layer {"k": (b, maxT, g, d), "v": ..., "offset": scalar} for
       standalone single-layer use;
     - paged (the continuous-batching engine, inference/engine.py):
-      {"k_pages": (P, page_size, g, d), "v_pages": ..., "page_table":
+      {"k_pages": (P, page_size, g * d) (lane-packed: GPTModel.
+      init_paged_kv_caches), "v_pages": ..., "page_table":
       (slots, max_pages) int32, "lengths": (slots,) int32, optionally
       "chunk_lens": (slots,) int32} — the batch axis is SLOTS at ragged
       per-slot lengths; slot i contributes a ragged span of
@@ -339,8 +340,9 @@ def attention_block(
       the decode scan's own shape.
 
     On a tp serving mesh (DecodeEngine(serving_tp>1), ISSUE 14) BOTH
-    paged forms run group-sharded: the pools arrive sharded on the group
-    axis (kv_pool_spec), the shard_activation("groups"/"heads")
+    paged forms run group-sharded: the pools arrive sharded on their
+    lanes, each chip its own heads' (kv_pool_spec), the
+    shard_activation("groups"/"heads")
     constraint sites steer q and the attention output onto the same
     split, and the scatter + attention call runs per shard inside
     `shard_kernel` (each chip runs the kernel — or its XLA twin — over
@@ -438,10 +440,11 @@ def attention_block(
                            chunk_lens,
                            rest[-1] if doc_starts is not None else None)
 
-        # on a tp serving mesh the pools arrive sharded on the group
-        # axis (kv_pool_spec): each chip scatters and attends over its
-        # own groups against the replicated page table / lengths
-        pool = P(None, None, MODEL_AXIS, None)
+        # on a tp serving mesh the pools arrive sharded on their lanes
+        # (kv_pool_spec: a chip's slice is its own heads): each chip
+        # scatters and attends over its own groups against the
+        # replicated page table / lengths
+        pool = P(None, None, MODEL_AXIS)
         qspec = P(None, None, MODEL_AXIS, None, None)
         operands = [q, k, v, kv_cache["k_pages"], kv_cache["v_pages"],
                     page_table, lengths, chunk_lens]
@@ -449,8 +452,8 @@ def attention_block(
         out_specs = [qspec, pool, pool]
         if quantized:
             operands += [kv_cache["k_scales"], kv_cache["v_scales"]]
-            in_specs += [P(None, None, MODEL_AXIS)] * 2
-            out_specs += [P(None, None, MODEL_AXIS)] * 2
+            in_specs += [pool] * 2
+            out_specs += [pool] * 2
         if doc_starts is not None:
             operands.append(doc_starts)
             in_specs.append(P())

@@ -336,8 +336,13 @@ class GPTModel:
                              kv_dtype=None,
                              mesh_ctx=None) -> dict:
         """Paged KV cache for the continuous-batching engine
-        (inference/engine.py): per-layer GLOBAL page pools
-        (num_pages, page_size, g, d) shared by all slots, one
+        (inference/engine.py): per-layer GLOBAL page pools shared by
+        all slots, held LANE-PACKED, (num_pages, page_size, g * d): a
+        token's g K/V heads of width d side by side along the lanes,
+        head h at lanes h*d..(h+1)*d — the order the scatter writes and
+        the paged attention reads (ops/prefill_attention.py, where the
+        head-width gate of its kernel is stated), so no program re-lays
+        a pool out; one
         (slots, max_pages_per_slot) page table mapping each slot's
         logical pages to pool indices, and per-slot valid lengths.
         Pool page 0 is the NULL page (never allocated): fresh/retired
@@ -361,8 +366,9 @@ class GPTModel:
         `mesh_ctx` (ISSUE 14, the tp-sharded engine): a
         ParallelContext whose `model` axis the pools shard over —
         every pool leaf materialises DIRECTLY under its
-        kv_pool_spec sharding (group axis over `model`,
-        parallel/sharding.py — the per-chip pool is 1/tp the bytes,
+        kv_pool_spec sharding (the lanes over `model`, each chip its
+        own heads', parallel/sharding.py — the per-chip pool is 1/tp
+        the bytes,
         never allocated whole on one chip), while the page table and
         lengths stay replicated scalar-prefetch operands.
 
@@ -374,7 +380,8 @@ class GPTModel:
         operators = [op for op, _ in cfg.layer_kinds]
         n_attention = operators.count("attention")
         kv_dtype = cfg.compute_dtype if kv_dtype is None else kv_dtype
-        shape = (num_pages, page_size, cfg.num_query_groups, cfg.head_dim)
+        g = cfg.num_query_groups
+        shape = (num_pages, page_size, g * cfg.head_dim)
 
         if mesh_ctx is not None:
             import jax
@@ -402,7 +409,7 @@ class GPTModel:
             def zeros(shape, dtype):
                 return _sharded_zeros(
                     shape, dtype,
-                    mesh_ctx.sharding(*kv_pool_spec(shape, tp)))
+                    mesh_ctx.sharding(*kv_pool_spec(shape, tp, g)))
 
             def zeros_rep(shape, dtype):
                 return _sharded_zeros(shape, dtype, mesh_ctx.sharding())
@@ -422,7 +429,7 @@ class GPTModel:
             "lengths": zeros_rep((slots,), jnp.int32),
         }
         if jnp.dtype(kv_dtype) == jnp.int8:
-            sshape = shape[:-1]
+            sshape = shape[:2] + (g,)
             caches["k_scales_layers"] = tuple(
                 zeros(sshape, jnp.float32)
                 for _ in range(n_attention))

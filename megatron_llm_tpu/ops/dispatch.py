@@ -2,7 +2,8 @@
 
 Every entry point takes `use_pallas` (None = "on a TPU backend") and runs
 a static gate that may still say no (no block that divides the shape,
-a paged kernel's head dim, ...), in which case the numerically matching XLA
+a paged call whose K/V heads fill no 128-lane tile — Falcon-7B's one
+head of 64 — ...), in which case the numerically matching XLA
 reference serves the call. Off the TPU that is the normal path (the CPU
 suite relies on it) and nothing is recorded. On a TPU backend a call that
 asked for a kernel and did not get one is never silent: it is logged once

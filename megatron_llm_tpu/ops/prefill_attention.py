@@ -30,11 +30,30 @@ serving mesh. This module collapses the paged side to ONE kernel:
   ISSUE 19) are predicate parameterizations of this one body riding
   a double-ended DMA clamp, not new kernels.
 
+- **head width is a gate on the shape, not a variant** (ISSUE 38): a
+  page pool is held LANE-PACKED, (num_pages, page_size, g * d), the g
+  K/V heads of a token side by side along the lanes — the order the
+  scatter writes and the kernel and the twin read, so nothing re-lays
+  a pool out (a 4-D (..., g, d) pool with 64 in the lane dimension was
+  copied whole around every gather and scatter: PERF.md §6, PR 38). A
+  grid step serves `hp` neighbouring heads from ONE (page_size, hp * d)
+  block of the page: q rides in block-diagonal (head t's rows are zero
+  outside lanes t*d..(t+1)*d), so q . k^T over the block's lanes is
+  each head's own product plus exact zeros, and of p . v's lanes each
+  row keeps its own d — the SAME body at width hp * d, no loop over
+  heads. A chunk's step serves one lane tile's heads (one at
+  d % 128 == 0, the kernel as it was; two at d = 64 with an even g), a
+  decode row's all g, the page read whole (`_heads_a_step`).
+  Falcon-7B's g = 1 at d = 64 fills no tile: it keeps the twin, on the
+  same pool, and is counted (ops/dispatch.report_fallback).
+
 Kernel structure:
 
-- grid (chunk, group, q_block, page): each grid step reads one pool
-  page ONCE per GQA group and serves all `q_per_kv` query heads of the
-  group from it; the page dim carries the online-softmax state in VMEM
+- grid (chunk, head block, q_block, page): each grid step reads one
+  pool page ONCE for the `hp` K/V heads it serves and serves all their
+  `q_per_kv` query heads from it (a decode row takes all g heads, the
+  page read whole; a chunk one lane tile's); the page dim carries the
+  online-softmax state in VMEM
   scratch (exp2 domain, fp32 accumulation — the flash forward scheme);
 - the per-chunk START OFFSET and VALID LENGTH ride scalar-prefetch
   operands: causal-within-chunk masking is `col <= start + row`, rows
@@ -42,10 +61,10 @@ Kernel structure:
   K/V index map dereferences the page table with past-the-need pages
   clamped to the last needed page — Mosaic elides the repeated DMA, so
   cache traffic follows `start + len`, not the allocated table width;
-- interior/boundary split: page blocks fully below the causal diagonal
-  and fully inside the valid length run maskless; only straddling
-  blocks pay the iota/select VPU work (split_boundary=False under the
-  interpreter, the same vma workaround as the flash/decode kernels).
+- ONE masked body for every page a q block attends (pages past its
+  last valid row, or below its window / document floor, are skipped
+  and their DMAs elided): what the interpreter runs is what Mosaic
+  compiles.
 
 `ragged_paged_attention` is the ONE public paged entry point (a tier-1
 guard in tests/test_static_analysis.py holds it at one): it first
@@ -69,9 +88,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.extend.source_info_util import current_name_stack
 
 from megatron_llm_tpu.ops import dispatch
 from megatron_llm_tpu.ops.flash_attention import (
+    LANES,
     LOG2E,
     NEG_INF,
     _causal_invalid,
@@ -84,6 +105,12 @@ from megatron_llm_tpu.ops.flash_attention import (
 # folded (token, head) rows per grid program — the flash kernels' VMEM
 # bound for the fp32 score block and accumulator
 MAX_PAGED_ROWS = 2048
+# the width a lone narrower chunk is attended at (ragged_paged_attention):
+# a mixed round's widest chunk in every serving cell
+LONE_CHUNK_TOKENS = 128
+# folded rows x lanes of a grid step up to which the step serves ALL of
+# a row's K/V heads from one read of the whole page (`_heads_a_step`)
+WHOLE_PAGE_CELLS = 32 * 1024
 
 
 def _choose_block_q(C: int, qpk: int) -> Optional[int]:
@@ -97,18 +124,48 @@ def _choose_block_q(C: int, qpk: int) -> Optional[int]:
     return b if C % b == 0 and b * qpk <= MAX_PAGED_ROWS else None
 
 
+def _heads_a_step(C: int, g: int, qpk: int, d: int) -> Optional[int]:
+    """K/V heads a grid step serves (`hp`), or None where the heads
+    fill no lane tile. A step's K/V block is (page_size, hp * d) of the
+    lane-packed pool, so hp * d must be whole 128-lane tiles: any hp at
+    d % 128 == 0; at a d that divides 128 (64: two heads a tile) hp a
+    multiple of 128 / d, which g must be too (Falcon-7B's g = 1 at
+    d = 64: None). A step costs about the same whatever it holds while
+    its folded rows x lanes stay under WHOLE_PAGE_CELLS, so rows that
+    few (a decode row, C == 1) take ALL g heads a step, the page read
+    whole: a slot's pages cost one step each, not g (PERF.md §6, PR 38:
+    the decode shape is bound by its grid steps). Wider chunks take one
+    lane tile's heads: the block-diagonal q multiplies hp - 1 zeros for
+    every product it keeps."""
+    if d % LANES == 0:
+        tile = 1
+    elif LANES % d == 0 and g % (LANES // d) == 0:
+        tile = LANES // d
+    else:
+        return None
+    if C * g * qpk * g * d <= WHOLE_PAGE_CELLS:
+        return g
+    return tile
+
+
 def ragged_paged_block(s: int, qpk: int, d: int, page_size: int,
                        num_slot_pages: int, *,
+                       groups: int = 1,
                        min_cache: int = 0,
                        kv_dtype=None,
-                       interpret: bool = False) -> Optional[int]:
-    """Static dispatch check for the unified paged kernel: returns the
-    q block size (tokens per grid program) or None for the XLA path.
+                       interpret: bool = False) -> Optional[tuple]:
+    """Static dispatch check for the unified paged kernel: returns
+    (q block size in tokens, K/V heads a grid step) or None for the XLA
+    path.
 
-    Kernel territory: lane-aligned head dim, a page that tiles sublanes
+    Kernel territory: `groups` K/V heads that fill lane tiles
+    (`_heads_a_step`: d % 128 == 0, or d = 64 with an even g — what the
+    call can see, not a model's name), a page that tiles sublanes
     (the page IS the K/V DMA unit — 16 covers bf16/fp32, int8 pools
-    need the 32 int8 sublane tile), TPU-or-interpreter backend, and a
-    per-slot reach num_slot_pages * page_size of at least `min_cache`.
+    need the 32 int8 sublane tile), a q block Mosaic takes (its folded
+    rows the whole row axis or a multiple of 8), TPU-or-interpreter
+    backend, and a per-slot reach num_slot_pages * page_size of at
+    least `min_cache`.
     ONE gate for every phase: a decode row (s == 1) takes the same
     kernel-vs-XLA decision it would take as a width-1 chunk of a mixed
     step on the same pool, so a near-tie argmax can never flip when
@@ -116,7 +173,10 @@ def ragged_paged_block(s: int, qpk: int, d: int, page_size: int,
     """
     if not (interpret or dispatch.on_tpu()):
         return None
-    if s < 1 or d % 128 != 0:
+    if s < 1:
+        return None
+    hp = _heads_a_step(s, groups, qpk, d)
+    if hp is None:
         return None
     is_int8 = kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
     sublane = 32 if is_int8 else 16
@@ -124,7 +184,10 @@ def ragged_paged_block(s: int, qpk: int, d: int, page_size: int,
         return None
     if num_slot_pages * page_size < max(min_cache, 16):
         return None
-    return _choose_block_q(s, qpk)
+    bq = _choose_block_q(s, hp * qpk)
+    if bq is None or (bq != s and (bq * hp * qpk) % 8 != 0):
+        return None
+    return bq, hp
 
 
 # ---------------------------------------------------------------------------
@@ -133,18 +196,21 @@ def ragged_paged_block(s: int, qpk: int, d: int, page_size: int,
 
 
 def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
-                  page_size, qpk, d, num_pages, sm_scale,
-                  split_boundary=True, quantized=False, window=None,
-                  has_doc=False):
-    """Grid (chunk, group, q_block, page); the page dim carries the
-    online-softmax state. Row r of the folded (block_q*qpk, d) q block
+                  page_size, qpk, d, num_pages, sm_scale, hp=1,
+                  quantized=False, window=None, has_doc=False):
+    """Grid (chunk, head block, q_block, page); the page dim carries
+    the online-softmax state. A step serves `hp` K/V heads of width
+    d // hp from a (page_size, d) block of the lane-packed page (`d` is
+    the BLOCK's lanes, `qpk` the folded rows a token: hp x q_per_kv,
+    q block-diagonal — `_paged_pallas`). Row r of the folded
+    (block_q*qpk, d) q block
     is chunk token i*block_q + r // qpk (head fastest) at global
     position starts[c] + token; rows at tokens >= lens[c] are pad.
     `quantized` selects the int8-KV epilogue (ISSUE 9): k/v arrive int8
     with the page's per-(token, group) fp32 scales as two extra
-    (page_size, g) operands — this group's column is picked out
-    in-register — and are dequantized before the unchanged fp32
-    template math.
+    (page_size, g) operands — each served head's column is picked out
+    in-register and laid over that head's lanes — and are dequantized
+    before the unchanged fp32 template math.
 
     Lower-bound masks (ISSUE 19) are extra parameterizations of the
     SAME body, not new kernels — both default off, and off means the
@@ -153,8 +219,7 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
       position p attends cols [p - window + 1, p]. Pages wholly below
       the q block's FIRST row's window floor drop out of `run` (and
       the index map clamps them to the first needed page, eliding the
-      DMA), pages below the LAST row's floor leave `interior`, so the
-      window boundary pays the mask exactly like the causal boundary.
+      DMA).
     - `has_doc`: a fourth scalar-prefetch operand doc_starts (nc,)
       gives each chunk an attention FLOOR (its packed document's first
       position); cols below it mask out, resetting causality at doc
@@ -178,18 +243,31 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
 
     def _scale_col(s_ref):
         # Mosaic takes a scale block only at the pool's full (page_size,
-        # g) trailing dims; a one-hot lane reduce picks this grid step's
-        # group column out of it as the (page_size, 1) the dequant needs
+        # g) trailing dims; a one-hot lane reduce picks a served head's
+        # column out of it as the (page_size, 1) the dequant needs, and
+        # with several heads a step each column goes over its head's
+        # lanes of the (page_size, d) block
         sc = s_ref[:]
-        lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        return jnp.sum(jnp.where(lane == gi, sc, 0.0), axis=1,
-                       keepdims=True)
+        group = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+
+        def col(t):
+            return jnp.sum(jnp.where(group == gi * hp + t, sc, 0.0),
+                           axis=1, keepdims=True)
+
+        if hp == 1:
+            return col(0)
+        head = jax.lax.broadcasted_iota(
+            jnp.int32, (page_size, d), 1) // (d // hp)
+        out = jnp.zeros((page_size, d), jnp.float32)
+        for t in range(hp):
+            out = jnp.where(head == t, col(t), out)
+        return out
 
     @pl.when(j == 0)
     def _init():
         _softmax_init(m_scr, l_scr, acc_scr)
 
-    def _accum(masked):
+    def _accum():
         qb = q_ref[:].reshape(rows, d)
         kb = k_ref[:].reshape(page_size, d).astype(jnp.float32)
         if quantized:
@@ -201,24 +279,27 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * (sm_scale * LOG2E)
-        if masked:
-            # the shared causal predicate at the ragged-chunk
-            # parameterization: token t of the chunk sits at position
-            # start + t, may see cols <= start + t, and is pad when
-            # t >= len (pad rows mask EVERY column -> the finalize
-            # clamp emits exact zeros, the empty-slot contract).
-            # NEG_INF is a finite constant: a PAD row would degenerate
-            # to exp2(0)-everywhere garbage, so the finalize re-masks
-            # pad rows; valid rows always have a real max (page 0,
-            # col 0 is causal for every row), so their masked cells
-            # underflow to exact 0.
-            sc = jnp.where(
-                _causal_invalid(rows, page_size, qpk,
-                                start + i * block_q, j * page_size,
-                                valid_rows=clen - i * block_q,
-                                window=window, floor=doc0),
-                NEG_INF, sc,
-            )
+        # the shared causal predicate at the ragged-chunk
+        # parameterization: token t of the chunk sits at position
+        # start + t, may see cols <= start + t, and is pad when
+        # t >= len (pad rows mask EVERY column -> the finalize
+        # clamp emits exact zeros, the empty-slot contract).
+        # NEG_INF is a finite constant: a PAD row would degenerate
+        # to exp2(0)-everywhere garbage, so the finalize re-masks
+        # pad rows; valid rows always have a real max (page 0,
+        # col 0 is causal for every row), so their masked cells
+        # underflow to exact 0. ONE masked body for every page that
+        # runs: a maskless twin of it for pages wholly under the
+        # diagonal doubled what every call site's trace and Mosaic
+        # lowering cost a warm-up, for an iota and two compares on a
+        # (rows, page_size) block beside its exp2 (PERF.md §6, PR 38).
+        sc = jnp.where(
+            _causal_invalid(rows, page_size, qpk,
+                            start + i * block_q, j * page_size,
+                            valid_rows=clen - i * block_q,
+                            window=window, floor=doc0),
+            NEG_INF, sc,
+        )
         if quantized:
             vb = v_ref[:].reshape(page_size, d).astype(jnp.float32) \
                 * _scale_col(vs_ref)
@@ -244,29 +325,7 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
         if has_doc:
             first_lo = jnp.maximum(first_lo, doc0)
         run = run & ((j * page_size + page_size - 1) >= first_lo)
-    if split_boundary:
-        # maskless when every row is valid AND every column is causal
-        # for even the block's FIRST token
-        interior = ((i + 1) * block_q <= clen) & \
-            ((j * page_size + page_size - 1) <= (start + i * block_q))
-        if window is not None:
-            # ... AND in-window for even the LAST token's floor
-            interior = interior & \
-                ((j * page_size) >= (start + (i + 1) * block_q - window))
-        if has_doc:
-            interior = interior & ((j * page_size) >= doc0)
-
-        @pl.when(run & interior)
-        def _compute_interior():
-            _accum(False)
-
-        @pl.when(run & ~interior)
-        def _compute_boundary():
-            _accum(True)
-    else:
-        @pl.when(run)
-        def _compute():
-            _accum(True)
+    pl.when(run)(_accum)
 
     @pl.when(j == num_pages - 1)
     def _finalize():
@@ -280,9 +339,10 @@ def _paged_kernel(starts_ref, lens_ref, pt_ref, *rest, block_q,
 
 
 def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
-                  block_q, interpret, k_scales=None, v_scales=None,
+                  block_q, hp, interpret, k_scales=None, v_scales=None,
                   window=None, doc_starts=None):
-    """q: (nc, C, g, qpk, d); k/v_pages: (P, page_size, g, d);
+    """q: (nc, C, g, qpk, d); k/v_pages: (P, page_size, g * d), the
+    lane-packed pool (head h at lanes h*d..(h+1)*d);
     page_table: (nc, max_pages) int32; starts/chunk_lens: (nc,) int32.
     k/v_scales (int8 pools only): (P, page_size, g) fp32 per-(token,
     group) scales riding the same clamped page index map. `window`
@@ -291,8 +351,22 @@ def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
     clamps BOTH ends, so out-of-window / pre-document pages repeat an
     in-bound index and Mosaic elides their DMAs — decode-row traffic
     is O(window), not O(context). Returns (nc, C, g, qpk, d) in q's
-    dtype (pad rows exact zero)."""
+    dtype (pad rows exact zero).
+
+    `hp` K/V heads a grid step (`_heads_a_step`): q goes in as
+    (nc, C, g / hp, hp * qpk, hp * d), BLOCK-DIAGONAL — the rows of
+    head t zero outside lanes t*d..(t+1)*d — so one product against the
+    step's (page_size, hp * d) block of the page is each head's own
+    q . k^T (the other heads' lanes add exact zeros), and of p . v's
+    hp * d lanes each row keeps its own d. The scale is the TRUE head
+    width's. hp == 1 is the plain launch."""
     nc, C, g, qpk, d = q.shape
+    heads = (g, qpk, d)
+    if hp > 1:
+        own = jnp.eye(hp, dtype=bool)[:, None, :, None]
+        q = jnp.where(own, q.reshape(nc, C, g // hp, hp, qpk, 1, d), 0)
+        g, qpk, d = g // hp, hp * qpk, hp * d
+        q = q.reshape(nc, C, g, qpk, d)
     page_size = k_pages.shape[1]
     max_pages = page_table.shape[1]
     rows = block_q * qpk
@@ -301,12 +375,6 @@ def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
     has_doc = doc_starts is not None
 
     qf = q.transpose(0, 2, 1, 3, 4).reshape(nc, g, C * qpk, d)
-    # Mosaic wants a block's last two dims tile-aligned or whole, and a
-    # (page_size, d) block of the (P, page_size, g, d) pool would
-    # squeeze the second-minor group axis. The row-major (P, page_size,
-    # g*d) view is free and puts group gi at lane block gi instead.
-    k_pages = k_pages.reshape(*k_pages.shape[:2], g * d)
-    v_pages = v_pages.reshape(*v_pages.shape[:2], g * d)
     # rows below one fp32 sublane tile: launch q/o in fp32 (the small-
     # memref Mosaic workaround shared with the dense decode kernel)
     out_dtype = q.dtype if rows % 8 == 0 else jnp.float32
@@ -314,9 +382,8 @@ def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
 
     kernel = functools.partial(
         _paged_kernel, block_q=block_q, page_size=page_size, qpk=qpk,
-        d=d, num_pages=max_pages, sm_scale=1.0 / (d ** 0.5),
-        split_boundary=not interpret, quantized=quantized,
-        window=window, has_doc=has_doc,
+        d=d, num_pages=max_pages, sm_scale=1.0 / (heads[2] ** 0.5),
+        hp=hp, quantized=quantized, window=window, has_doc=has_doc,
     )
 
     def page_index(c, i, j, starts_ref, lens_ref, pt_ref, doc_ref=None):
@@ -360,7 +427,7 @@ def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
     operands = [qf, k_pages, v_pages]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (None, page_size, g),
+            (None, page_size, k_scales.shape[2]),
             lambda c, gi, i, j, *s_refs: (
                 page_index(c, i, j, *s_refs), 0, 0
             ),
@@ -388,16 +455,38 @@ def _paged_pallas(q, k_pages, v_pages, page_table, starts, chunk_lens,
         grid_spec=grid_spec,
         out_shape=_out_struct((nc, g, C * qpk, d), out_dtype, qf, k_pages,
                               v_pages),
-        # (chunk, group, q_block) steps are independent; only the page
-        # dim carries the online-softmax scratch state
+        # (chunk, head block, q_block) steps are independent; only the
+        # page dim carries the online-softmax scratch state
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
     )(*scalars, *operands)
-    return out.reshape(nc, g, C, qpk, d).transpose(0, 2, 1, 3, 4) \
+    out = out.reshape(nc, g, C, qpk, d).transpose(0, 2, 1, 3, 4) \
         .astype(q.dtype)
+    if hp > 1:
+        # each row's own lanes of the hp * d its step produced
+        out = jnp.sum(jnp.where(own, out.reshape(
+            nc, C, g, hp, qpk // hp, hp, d // hp), 0), axis=-2)
+    return out.reshape(nc, C, *heads)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _paged_call(statics, scope, *operands, **pools):
+    """`_paged_pallas` behind a call boundary (`statics` its block_q,
+    hp, interpret, window): the attention layers of a step share ONE
+    trace of a kernel shape and the lowered program holds its Mosaic
+    call once however many layers call it — a step's warm-up traces,
+    lowers and hashes its program whatever the compile cache holds
+    (PERF.md §7, the set-up row). Only the kernel sits behind it: the
+    twin stays inline in its callers, as it was. A called function's
+    operations do not inherit the call site's name stack, so the caller
+    hands over its `scope`."""
+    block_q, hp, interpret, window = statics
+    with jax.named_scope(scope):
+        return _paged_pallas(*operands, block_q, hp, interpret,
+                             window=window, **pools)
 
 
 # ---------------------------------------------------------------------------
@@ -458,7 +547,8 @@ def _xla_paged_reference(q, k_pages, v_pages, page_table, starts,
     the ONE parity-test oracle. kv dtype is a parameter here too:
     int8 pools pass their scale pools and dequantize to the fp32 view
     first (the quantize-then-dequantize oracle — the same fp32 values
-    the kernel's in-register epilogue feeds the same math). Pad rows
+    the kernel's in-register epilogue feeds the same math). The pools
+    are lane-packed, (P, page_size, g * d). Pad rows
     (token >= chunk_lens) pin to the kernel's exact-zero output.
     `window` / `doc_starts` (ISSUE 19) become a per-row lower bound
     row_lo = max(pos - window + 1, doc_starts[c], 0): this path
@@ -466,15 +556,21 @@ def _xla_paged_reference(q, k_pages, v_pages, page_table, starts,
     but the lower mask multiplies those columns by an exact fp 0, so
     mid-flight page reclamation is bitwise-invisible here too."""
     nc, C, g, qpk, d = q.shape
-    if k_scales is not None:
-        k_pages = k_pages.astype(jnp.float32) * k_scales[..., None]
-        v_pages = v_pages.astype(jnp.float32) * v_scales[..., None]
     page_size = k_pages.shape[1]
     max_pages = page_table.shape[1]
     T = max_pages * page_size
+
+    def view(pages, scales):
+        # whole lane-packed rows as they lie, cut by head; an int8 pool
+        # dequantizes the gathered view, not the pool
+        x = pages[page_table].reshape(nc, T, g, d)
+        if scales is not None:
+            x = x.astype(jnp.float32) \
+                * scales[page_table].reshape(nc, T, g, 1)
+        return x.transpose(0, 2, 1, 3)
+
     with jax.named_scope("page_gather"):
-        k = k_pages[page_table].reshape(nc, T, g, d).transpose(0, 2, 1, 3)
-        v = v_pages[page_table].reshape(nc, T, g, d).transpose(0, 2, 1, 3)
+        k, v = view(k_pages, k_scales), view(v_pages, v_scales)
     tok = jnp.arange(C * qpk) // qpk  # (rows,)
     row_pos = starts[:, None] + tok[None, :]  # (nc, rows)
     row_valid = tok[None, :] < chunk_lens[:, None]  # (nc, rows)
@@ -492,7 +588,9 @@ def _xla_paged_reference(q, k_pages, v_pages, page_table, starts,
 @jax.named_scope("kv_write")
 def scatter_chunk_kv(k_new, v_new, k_pages, v_pages, page_table, starts,
                      chunk_lens, k_scales=None, v_scales=None):
-    """Write a chunk's K/V rows into its slot's pages: token t (valid,
+    """Write a chunk's K/V rows (nc, C, g, d) into its slot's pages of
+    the lane-packed pools (P, page_size, g * d), a token's g heads side
+    by side as one row: token t (valid,
     t < chunk_lens) lands in pool page page_table[c, (starts+t) //
     page_size] at offset (starts+t) % page_size. Pad rows are routed to
     pool page 0 — the dead null page every table parks unowned entries
@@ -508,7 +606,12 @@ def scatter_chunk_kv(k_new, v_new, k_pages, v_pages, page_table, starts,
     pools and the fp32 scales land at the SAME [page, offset] of the
     scale pools (pad-row scales go to the null page with their data).
     Returns (k_pages, v_pages, k_scales, v_scales)."""
-    nc, C = k_new.shape[:2]
+    nc, C, g, d = k_new.shape
+    if k_pages.ndim != 3 or k_pages.shape[2] != g * d:
+        raise ValueError(
+            f"a K/V page pool is lane-packed, (num_pages, page_size, "
+            f"g * d) = (..., {g * d}) for {g} heads of width {d} "
+            f"(GPTModel.init_paged_kv_caches): got {tuple(k_pages.shape)}")
     page_size = k_pages.shape[1]
     max_pages = page_table.shape[1]
     quantized = k_pages.dtype == jnp.int8
@@ -530,8 +633,10 @@ def scatter_chunk_kv(k_new, v_new, k_pages, v_pages, page_table, starts,
         v_pages, v_scales = scatter_quantized_rows(
             v_pages, v_scales, pages, offs, v_new)
         return k_pages, v_pages, k_scales, v_scales
-    k_pages = k_pages.at[pages, offs].set(k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[pages, offs].set(v_new.astype(v_pages.dtype))
+    k_pages = k_pages.at[pages, offs].set(
+        k_new.astype(k_pages.dtype).reshape(nc, C, g * d))
+    v_pages = v_pages.at[pages, offs].set(
+        v_new.astype(v_pages.dtype).reshape(nc, C, g * d))
     return k_pages, v_pages
 
 
@@ -539,7 +644,7 @@ def ragged_paged_attention(
     q: jnp.ndarray,  # (nc, C, g, qpk, d) — C = padded chunk width
     k_new: jnp.ndarray,  # (nc, C, g, d) — this chunk's K (RoPE applied)
     v_new: jnp.ndarray,  # (nc, C, g, d)
-    k_pages: jnp.ndarray,  # (num_pages, page_size, g, d); int8 OK
+    k_pages: jnp.ndarray,  # (num_pages, page_size, g * d); int8 OK
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,  # (nc, max_pages) int32 pool indices
     starts: jnp.ndarray,  # (nc,) int32 — chunk start offset in the slot
@@ -557,6 +662,14 @@ def ragged_paged_attention(
     attention of chunk token t (global position starts + t) over cache
     positions 0..starts+t — served by the Pallas kernel on TPU (or
     under the interpreter) and by the gather-pages twin elsewhere.
+
+    The pools are LANE-PACKED: (num_pages, page_size, g * d), a token's
+    g K/V heads side by side along the lanes, head h at lanes
+    h*d..(h+1)*d — written once, in the order both paths read. The
+    kernel takes heads that fill 128-lane tiles: d % 128 == 0, or
+    d = 64 with an even g, two heads a tile (`ragged_paged_block`);
+    any other shape (Falcon-7B's g = 1 at d = 64) takes the twin on
+    the same pool and is counted (ops/dispatch.report_fallback).
 
     Phase is a shape: a decode row is chunk_lens == 1 at starts ==
     lengths (C == 1 in the engine's decode scan and for the decode
@@ -585,43 +698,42 @@ def ragged_paged_attention(
     nc, C, g, qpk, d = q.shape
     if window_size is not None and window_size <= 0:
         window_size = None
-    quantized = k_pages.dtype == jnp.int8
-    if quantized:
-        k_pages, v_pages, k_scales, v_scales = scatter_chunk_kv(
-            k_new, v_new, k_pages, v_pages, page_table, starts,
-            chunk_lens, k_scales=k_scales, v_scales=v_scales)
-    else:
-        k_pages, v_pages = scatter_chunk_kv(
-            k_new, v_new, k_pages, v_pages, page_table, starts,
-            chunk_lens)
+    # (k_pages, v_pages), and an int8 pool's (k_scales, v_scales) after
+    pools = scatter_chunk_kv(k_new, v_new, k_pages, v_pages, page_table,
+                             starts, chunk_lens, k_scales=k_scales,
+                             v_scales=v_scales)
+    k_pages, v_pages, k_scales, v_scales = (*pools, None, None)[:4]
     if dispatch.want_kernel(use_pallas, interpret):
-        bq = ragged_paged_block(C, qpk, d, k_pages.shape[1],
-                                page_table.shape[1],
-                                min_cache=min_cache,
-                                kv_dtype=k_pages.dtype,
-                                interpret=interpret)
-        if bq is None:
-            # a reach below min_cache is the caller's routing, not a
-            # refusal
-            if page_table.shape[1] * k_pages.shape[1] >= min_cache:
-                dispatch.report_fallback(
-                    "ragged_paged_attention", "ragged_paged_block", C=C,
-                    qpk=qpk, d=d, page_size=k_pages.shape[1],
-                    slot_pages=page_table.shape[1], kv=k_pages.dtype.name)
-        else:
+        # a lone chunk narrower than LONE_CHUNK_TOKENS goes to the
+        # kernel at that width, its tail as pad rows: the engine's
+        # mixed rounds come in log2 widths, each its own program, and
+        # one kernel shape serves them all from one trace
+        # (`_paged_call`). A width-1 call stays a decode row.
+        pad = LONE_CHUNK_TOKENS - C if nc == 1 and 1 < C < LONE_CHUNK_TOKENS \
+            else 0
+        block = ragged_paged_block(C + pad, qpk, d, k_pages.shape[1],
+                                   page_table.shape[1], groups=g,
+                                   min_cache=min_cache,
+                                   kv_dtype=k_pages.dtype,
+                                   interpret=interpret)
+        if block is not None:
             dispatch.note_kernel("ragged_paged_attention")
-            out = _paged_pallas(q, k_pages, v_pages, page_table,
-                                starts, chunk_lens, bq, interpret,
-                                k_scales=k_scales, v_scales=v_scales,
-                                window=window_size,
-                                doc_starts=doc_starts)
-            if quantized:
-                return out, k_pages, v_pages, k_scales, v_scales
-            return out, k_pages, v_pages
+            if pad:
+                q = jnp.pad(q, ((0, 0), (0, pad)) + ((0, 0),) * 3)
+            out = _paged_call(
+                (*block, interpret, window_size),
+                str(current_name_stack()), q, k_pages, v_pages,
+                page_table, starts, chunk_lens, k_scales=k_scales,
+                v_scales=v_scales, doc_starts=doc_starts)
+            return (out[:, :C], *pools)
+        # a reach below min_cache is the caller's routing, not a refusal
+        if page_table.shape[1] * k_pages.shape[1] >= min_cache:
+            dispatch.report_fallback(
+                "ragged_paged_attention", "ragged_paged_block", C=C,
+                g=g, qpk=qpk, d=d, page_size=k_pages.shape[1],
+                slot_pages=page_table.shape[1], kv=k_pages.dtype.name)
     out = _xla_paged_reference(q, k_pages, v_pages, page_table, starts,
                                chunk_lens, k_scales=k_scales,
                                v_scales=v_scales, window=window_size,
                                doc_starts=doc_starts)
-    if quantized:
-        return out, k_pages, v_pages, k_scales, v_scales
-    return out, k_pages, v_pages
+    return (out, *pools)

@@ -75,13 +75,15 @@ def dequantize_rows(data: jnp.ndarray, scale: jnp.ndarray,
 def scatter_quantized_rows(data_pool, scale_pool, pages, offs, x):
     """THE quantize-at-write point for int8 KV pools: quantize each
     (..., g, d) row of `x` over the head dim and write the int8 data
-    and its fp32 scale at the SAME [pages, offs] of the paired pools.
+    (a token's g heads side by side, the lane-packed pool's row) and
+    its g fp32 scales at the SAME [pages, offs] of the paired pools.
     Every scatter path (chunked prefill, the single-token decode
     branch, the whole-prompt bucketed prefill) goes through this one
     definition, so the rounding/scale convention can never fork between
     writers."""
     data, scale = quantize_rows(x)
-    return (data_pool.at[pages, offs].set(data),
+    return (data_pool.at[pages, offs].set(
+                data.reshape(*data.shape[:-2], -1)),
             scale_pool.at[pages, offs].set(scale))
 
 

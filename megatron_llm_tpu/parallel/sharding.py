@@ -197,36 +197,39 @@ def optimizer_state_specs(cfg, params: dict, dp: int, distributed: bool,
     return jax.tree.unflatten(treedef, out)
 
 
-def kv_pool_axis(shape: tuple, tp: int) -> Optional[int]:
+def kv_pool_axis(shape: tuple, tp: int, groups: int) -> Optional[int]:
     """The leaf axis the tp-sharded serving engine shards a paged KV
-    pool over `model`: the GROUP axis — index 2 of both the
-    (num_pages, page_size, g, d) data pools and the (num_pages,
-    page_size, g) int8 scale pools — when it divides by tp, else None
-    (replicated). The ONE divisibility rule for serving pools, the
-    zero1_axis idiom applied to the KV cache: kv_pool_spec, the
-    engine's pool allocation (inference/engine.py), and the tp2 audit
-    rows (analysis/audit.py) all derive from this so they can never
-    disagree on which pool leaves are sharded. Pages and page offsets
-    stay unsharded on purpose — the page table is a replicated
-    host-trivial scalar-prefetch operand, so every chip addresses the
-    same page ids and only the per-(group) blocks it owns."""
-    if tp <= 1:
-        return None
-    if len(shape) < 3 or shape[2] % tp != 0 or shape[2] < tp:
+    pool over `model`: index 2 of both the lane-packed
+    (num_pages, page_size, g * d) data pools — a token's heads side by
+    side, so an even split of the lanes is a split by head — and the
+    (num_pages, page_size, g) int8 scale pools, when the `groups` K/V
+    heads divide by tp, else None (replicated). The GROUPS decide, not
+    the lanes: Falcon-7B's one head of 64 lanes is never cut in half.
+    The ONE divisibility rule for serving pools, the zero1_axis idiom
+    applied to the KV cache: kv_pool_spec, the engine's pool allocation
+    (inference/engine.py), and the tp2 audit rows (analysis/audit.py)
+    all derive from this so they can never disagree on which pool
+    leaves are sharded. Pages and page offsets stay unsharded on
+    purpose — the page table is a replicated host-trivial
+    scalar-prefetch operand, so every chip addresses the same page ids
+    and only the heads it owns."""
+    if len(shape) != 3 or shape[2] % groups != 0:
+        raise ValueError(
+            f"a paged pool is (num_pages, page_size, g * d) or, for int8 "
+            f"scales, (num_pages, page_size, g) with g = {groups}: got "
+            f"{tuple(shape)}")
+    if tp <= 1 or groups % tp != 0:
         return None
     return 2
 
 
-def kv_pool_spec(shape: tuple, tp: int) -> P:
+def kv_pool_spec(shape: tuple, tp: int, groups: int) -> P:
     """PartitionSpec for one paged-pool leaf under serving tp (see
-    kv_pool_axis): group axis over `model`, everything else —
-    num_pages, page_size, head_dim — replicated per chip."""
-    k = kv_pool_axis(shape, tp)
-    if k is None:
+    kv_pool_axis): the heads' axis over `model`, pages and page offsets
+    replicated per chip."""
+    if kv_pool_axis(shape, tp, groups) is None:
         return P()
-    parts: list = [None] * len(shape)
-    parts[k] = MODEL_AXIS
-    return P(*parts)
+    return P(None, None, MODEL_AXIS)
 
 
 def decode_param_specs(cfg, dec_params: dict) -> dict:

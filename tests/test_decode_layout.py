@@ -24,7 +24,12 @@ Pinned here:
   weight-sized `copy` / `transpose` outside a fusion; handed the
   (hidden, qkv) leaf and a gathered table they hold the table's and one
   a layer for `wqkv` (the guard guards something); a head-128 GQA model
-  loses its `wqkv` copies and gains none.
+  loses its `wqkv` copies and gains none;
+- the same for the K/V page pools (ISSUE 38): held lane-packed,
+  (num_pages, page_size, g * d), a pool is written and read as it lies:
+  neither program at LFM2-8B-A1B's attention widths (32 slots, 8 K/V
+  heads of 64: the paged kernel) holds a `copy` / `transpose` of a
+  pool's size outside a fusion.
 """
 
 import re
@@ -42,7 +47,7 @@ from megatron_llm_tpu.inference.generation import (
     bucket_prefill_len,
     generate_tokens,
 )
-from megatron_llm_tpu.models import FalconModel, LlamaModel
+from megatron_llm_tpu.models import FalconModel, GPTModel, LlamaModel
 from megatron_llm_tpu.models import language_model
 from megatron_llm_tpu.models.attention import split_qkv
 from megatron_llm_tpu.models.language_model import (
@@ -373,7 +378,7 @@ def round_programs(model, dev, as_before: bool = False, slots=8,
     tail = (arr((n,), bool), arr((n,), jnp.float32), i32,
             arr((n,), jnp.float32), arr((n,), jnp.uint32), i32)
     logits = arr((n, V), jnp.float32)
-    key = ("test_decode_layout", cfg.hidden_size, as_before)
+    key = ("test_decode_layout", cfg.hidden_size, slots, as_before)
     scan = engine_mod._make_step_fn(model, V, 1, True, contract_key=key,
                                     contract_owner=None)
     mixed = engine_mod._make_mixed_step_fn(
@@ -449,3 +454,54 @@ def test_head128_gqa_gains_no_weight_sized_copy_v5e(one_chip,
         found = weight_sized_copies(text, floor)
         assert len(found) == 4, (name, found)
         assert sum("[4096,6144]" in line for line in found) == 2
+
+
+# ------------------------------------------- the K/V page pools (ISSUE 38)
+
+
+def lfm2_attention_widths(layers=3):
+    """LiquidAI/LFM2-8B-A1B's attention: hidden 2048, 32 heads of 64 over
+    8 K/V heads, RMSNorm on each q and k head, 65,536 rows; its dense
+    MLP's width for every layer (the routed MLP and the convolutions
+    touch no pool)."""
+    return GPTModel(ModelConfig(
+        num_layers=layers, hidden_size=2048, ffn_hidden_size=7168,
+        num_attention_heads=32, num_attention_heads_kv=8, kv_channels=64,
+        max_position_embeddings=2048, seq_length=2048,
+        padded_vocab_size=65536, use_rms_norm=True, use_bias=False,
+        glu_activation="swiglu", position_embedding_type="rotary",
+        tie_embed_logits=True, qk_layernorm=True, hidden_dropout=0.0,
+        attention_dropout=0.0, params_dtype=BF16, compute_dtype=BF16))
+
+
+@pytest.mark.parametrize("make,slots,pool,kernel,copies_a_layer", [
+    # 8 x 64 lanes = four tiles of two heads: the paged kernel
+    pytest.param(lfm2_attention_widths, 32, (1025, 64, 512), True, 0,
+                 id="lfm2-32-slots-kernel"),
+    # 1 x 64 lanes fill no tile: the twin, whose scatter and gather
+    # still want the pool's 64 lanes in two orders, K and V: four copies
+    # a layer a round, as on the (257, 64, 1, 64) pool before (1.9 ms a
+    # round, PERF.md §7) — pinned as it is, Falcon-7B's cells must not
+    # move with this layout
+    pytest.param(lambda: falcon7b(layers=3), 8, (257, 64, 64), False, 4,
+                 id="falcon7b-8-slots-twin"),
+])
+def test_pools_are_written_and_read_as_they_lie_v5e(
+        one_chip, lowering_for_tpu, make, slots, pool, kernel,
+        copies_a_layer):
+    """The engine's own decode_scan and mixed_step at a serving cell's
+    attention shape, three attention layers. `lfm2moe-serve-batch`'s
+    lane-packed pool (1025, 64, 512) goes through the scatter and the
+    paged kernel with no copy or transpose of its size outside a fusion
+    (on the 4-D pool `bf16[1025,64,8,64]` there were four a layer a
+    round, 5 ms: PERF.md §6, PR 38), and each program holds the Mosaic
+    call."""
+    model = make()
+    shapes = jax.eval_shape(
+        lambda: model.init_paged_kv_caches(slots, pool[0], 64, 32))
+    assert {x.shape for x in shapes["k_pages_layers"]
+            + shapes["v_pages_layers"]} == {pool}
+    for name, text in round_programs(model, one_chip, slots=slots).items():
+        found = weight_sized_copies(text, int(np.prod(pool)))
+        assert len(found) == 3 * copies_a_layer, (name, found)
+        assert ("tpu_custom_call" in text) is kernel, name

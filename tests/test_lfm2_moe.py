@@ -161,6 +161,61 @@ def test_engine_serves_the_reference_at_every_position(made):
     assert list(c)[-4:] == list(MOE_COUNTERS)
 
 
+def test_engine_serves_the_reference_through_the_paged_kernel(monkeypatch):
+    """(b) again at the published head width, 64: two K/V heads fill one
+    128-lane tile of the lane-packed pools (ISSUE 38) and every
+    attention call of the engine runs the paged kernel itself, under the
+    Pallas interpreter: chunks with ragged tails, decode rows beside
+    them and reused slots against the reference's full pass, 2e-5 as on
+    the XLA twin."""
+    import dataclasses
+
+    from megatron_llm_tpu.models import GPTModel
+    from megatron_llm_tpu.ops import prefill_attention
+
+    cfg = dict(CFG, head_dim=64)
+    fam = families.find(cfg)
+    L = USE["num_hidden_layers"]
+    glob = weights.make_globals(cfg, SEED)
+    tree = program.program_tree(cfg, weights.make_stacked(cfg, SEED, L), glob)
+    ref = {"globals": glob,
+           "layers": [weights.make_layer(cfg, SEED, i) for i in range(L)]}
+    model = GPTModel(dataclasses.replace(
+        fam.model(cfg, USE).cfg, use_decode_attn=True,
+        decode_attn_min_cache=0, decode_attn_interpret=True))
+    calls = []
+    kernel = prefill_attention._paged_pallas
+    monkeypatch.setattr(
+        prefill_attention, "_paged_pallas",
+        lambda q, *a, **kw: calls.append(q.shape) or kernel(q, *a, **kw))
+
+    def logits_of(tokens):
+        x = fam.reference.embed(ref["globals"], jnp.asarray(tokens))
+        for i, w in enumerate(ref["layers"]):
+            x = fam.reference.block(w, x, cfg, jnp.arange(len(tokens)),
+                                    layer=i)
+        return fam.reference.final_logits(ref["globals"], x, cfg)
+
+    rs = np.random.RandomState(4)
+    prompts = [list(rs.randint(2, 256, n)) for n in (5, 7, 2, 9)]
+    eng = engine_of(model, tree, slots=2)
+    assert eng._pools_k[0].shape == (eng.num_pages, 16, 2 * 64)
+    reqs = [eng.submit(p, 4, top_k=1, return_log_probs=True) for p in prompts]
+    eng.drain()
+    for p, r in zip(prompts, reqs):
+        tokens, lps = r.result(5)[:2]
+        want = np.asarray(jax.nn.log_softmax(logits_of(tokens), axis=-1))
+        picked = np.take_along_axis(
+            want[:-1], np.asarray(tokens)[1:, None], axis=-1)[:, 0]
+        assert np.abs(picked - np.asarray(lps)).max() < 2e-5, len(p)
+        assert list(np.argmax(want, -1)[len(p) - 1:-1]) == tokens[len(p):]
+    # every traced kernel shape: the decode rows (slots, 1), the
+    # width-1 chunk, and every wider chunk at the ONE lone-chunk width
+    # (its tail pad rows); 2 K/V heads x 2 of 64
+    assert calls and {s[2:] for s in calls} == {(2, 2, 64)}
+    assert {s[:2] for s in calls} == {(2, 1), (1, 1), (1, 128)}
+
+
 def test_a_reused_slot_answers_as_a_fresh_engine_does(made):
     """(b) one slot, two requests one after the other: the second finds
     the first's convolution state and K/V in its slot and must answer as

@@ -63,10 +63,15 @@ def _drop_kernel_caches():
     jax.clear_caches()
 
 
+# (K/V heads, q heads a K/V head, head width). At d = 64 two K/V heads
+# fill one 128-lane tile of the lane-packed pool (ISSUE 38): the SAME
+# kernel body, q block-diagonal over the heads of a grid step.
 HEADS = [
-    pytest.param(4, 1, id="mha"),
-    pytest.param(2, 2, id="gqa"),
-    pytest.param(1, 8, id="mqa"),
+    pytest.param(4, 1, 128, id="mha"),
+    pytest.param(2, 2, 128, id="gqa"),
+    pytest.param(1, 8, 128, id="mqa"),
+    pytest.param(2, 4, 64, id="gqa-2x4-head64"),
+    pytest.param(8, 1, 64, id="mha-8x1-head64"),
 ]
 
 # kv dtype axis: (pool dtype, q dtype, page_size, rtol/atol vs oracle).
@@ -92,8 +97,10 @@ PHASES = {
 
 def _case(nc, C, g, qpk, d, ps, mp, kv="fp32", seed=0):
     """Random chunk batch + pool + a page table of distinct shuffled
-    pages per chunk (page 0 reserved as null). int8 pools arrive
-    pre-quantized with their fp32 scale pools (scales None for fp)."""
+    pages per chunk (page 0 reserved as null). The pools are
+    lane-packed, (num_pages, ps, g * d): a token's heads side by side.
+    int8 pools arrive pre-quantized with their fp32 scale pools
+    (num_pages, ps, g) (scales None for fp)."""
     pool_dt, q_dt, _, _ = KV_DTYPES[kv]
     num_pages = 1 + nc * mp
     ks = jax.random.split(jax.random.key(seed), 5)
@@ -105,12 +112,15 @@ def _case(nc, C, g, qpk, d, ps, mp, kv="fp32", seed=0):
     rs = np.random.RandomState(seed)
     perm = rs.permutation(num_pages - 1) + 1
     pt = jnp.asarray(perm.reshape(nc, mp), jnp.int32)
+    def packed(x):
+        return x.reshape(num_pages, ps, g * d)
+
     if kv == "int8":
         kq, ksc = quantize_rows(kp)
         vq, vsc = quantize_rows(vp)
-        return q, k_new, v_new, kq, vq, pt, ksc, vsc
-    return q, k_new, v_new, kp.astype(pool_dt), vp.astype(pool_dt), pt, \
-        None, None
+        return q, k_new, v_new, packed(kq), packed(vq), pt, ksc, vsc
+    return q, k_new, v_new, packed(kp.astype(pool_dt)), \
+        packed(vp.astype(pool_dt)), pt, None, None
 
 
 def _both(q, kn, vn, kp, vp, pt, starts, lens, ks=None, vs=None,
@@ -148,14 +158,18 @@ class TestUnifiedKernelSweep:
     # ISSUE 18's sweep axes are kv in {bf16, int8}; fp32 rides as the
     # single exactness pin below rather than a third full column (single
     # core tier-1 pays ~1.5s per interpret-mode cell).
-    @pytest.mark.parametrize("g,qpk", HEADS)
+    @pytest.mark.parametrize("g,qpk,d", HEADS)
     @pytest.mark.parametrize("kv", ["bf16", "int8"])
     @pytest.mark.parametrize("phase", list(PHASES))
-    def test_kernel_matches_oracle(self, phase, kv, g, qpk):
-        _, _, ps, tol = KV_DTYPES[kv]
+    def test_kernel_matches_oracle(self, phase, kv, g, qpk, d):
+        pool_dt, _, ps, tol = KV_DTYPES[kv]
         C, starts_fn, lens = PHASES[phase]
-        q, kn, vn, kp, vp, pt, ks, vs = _case(3, C, g, qpk, 128, ps, 2,
+        assert ragged_paged_block(C, qpk, d, ps, 2, groups=g,
+                                  kv_dtype=pool_dt,
+                                  interpret=True) is not None
+        q, kn, vn, kp, vp, pt, ks, vs = _case(3, C, g, qpk, d, ps, 2,
                                               kv=kv)
+        assert kp.shape == (7, ps, g * d)
         starts = starts_fn(ps)
         out_k, out_x, pools_k, pools_x = _both(q, kn, vn, kp, vp, pt,
                                                starts, lens, ks, vs)
@@ -215,12 +229,12 @@ class TestUnifiedKernelSweep:
         args = (put(q, P(None, None, MODEL_AXIS, None, None)),
                 put(kn, P(None, None, MODEL_AXIS, None)),
                 put(vn, P(None, None, MODEL_AXIS, None)),
-                put(kp, kv_pool_spec(kp.shape, 2)),
-                put(vp, kv_pool_spec(vp.shape, 2)),
+                put(kp, kv_pool_spec(kp.shape, 2, g)),
+                put(vp, kv_pool_spec(vp.shape, 2, g)),
                 put(pt, P()), put(starts, P()), put(lens, P()),
-                put(ks, kv_pool_spec(ks.shape, 2)) if ks is not None
+                put(ks, kv_pool_spec(ks.shape, 2, g)) if ks is not None
                 else None,
-                put(vs, kv_pool_spec(vs.shape, 2)) if vs is not None
+                put(vs, kv_pool_spec(vs.shape, 2, g)) if vs is not None
                 else None)
         del gax
         got = jax.jit(op)(*args)
@@ -234,9 +248,12 @@ class TestWindowedAndPackedDocs:
     double-ended DMA clamp, so the sweep below is the same phase x kv
     matrix with the window axis added, against the same one oracle."""
 
+    @pytest.mark.parametrize("g,qpk,d", [
+        pytest.param(2, 2, 128, id="head128"),
+        pytest.param(2, 4, 64, id="head64")])
     @pytest.mark.parametrize("kv", ["bf16", "int8"])
     @pytest.mark.parametrize("phase", list(PHASES))
-    def test_window_axis_off_covering_binding(self, phase, kv):
+    def test_window_axis_off_covering_binding(self, phase, kv, g, qpk, d):
         """The three window regimes of one cell: W=None (the base
         trace), W >= context (must be BITWISE the base on both paths —
         the reclamation soundness anchor), and W < context (the mask
@@ -244,7 +261,7 @@ class TestWindowedAndPackedDocs:
         under the same window)."""
         _, _, ps, tol = KV_DTYPES[kv]
         C, starts_fn, lens = PHASES[phase]
-        q, kn, vn, kp, vp, pt, ks, vs = _case(3, C, 2, 2, 128, ps, 2,
+        q, kn, vn, kp, vp, pt, ks, vs = _case(3, C, g, qpk, d, ps, 2,
                                               kv=kv, seed=13)
         starts = starts_fn(ps)
         base_k, base_x, _, _ = _both(q, kn, vn, kp, vp, pt, starts,
@@ -278,7 +295,8 @@ class TestWindowedAndPackedDocs:
 
         _, _, ps, _ = KV_DTYPES[kv]
         C, starts_fn, lens = PHASES["partial-page"]
-        q, kn, vn, kp, vp, pt, ks, vs = _case(3, C, 2, 2, 128, ps, 2,
+        g = 2
+        q, kn, vn, kp, vp, pt, ks, vs = _case(3, C, g, 2, 128, ps, 2,
                                               kv=kv, seed=17)
         starts = jnp.asarray(starts_fn(ps), jnp.int32)
         lens = jnp.asarray(lens, jnp.int32)
@@ -303,12 +321,12 @@ class TestWindowedAndPackedDocs:
         args = (put(q, P(None, None, MODEL_AXIS, None, None)),
                 put(kn, P(None, None, MODEL_AXIS, None)),
                 put(vn, P(None, None, MODEL_AXIS, None)),
-                put(kp, kv_pool_spec(kp.shape, 2)),
-                put(vp, kv_pool_spec(vp.shape, 2)),
+                put(kp, kv_pool_spec(kp.shape, 2, g)),
+                put(vp, kv_pool_spec(vp.shape, 2, g)),
                 put(pt, P()), put(starts, P()), put(lens, P()),
-                put(ks, kv_pool_spec(ks.shape, 2)) if ks is not None
+                put(ks, kv_pool_spec(ks.shape, 2, g)) if ks is not None
                 else None,
-                put(vs, kv_pool_spec(vs.shape, 2)) if vs is not None
+                put(vs, kv_pool_spec(vs.shape, 2, g)) if vs is not None
                 else None)
         for a, b in zip(jax.jit(op(ps))(*args), win1):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -352,7 +370,8 @@ class TestWindowedAndPackedDocs:
         np.testing.assert_array_equal(np.asarray(got_x),
                                       np.asarray(base_x))
 
-    def test_packed_docs_attend_within_doc_only(self):
+    @pytest.mark.parametrize("d", [128, 64])
+    def test_packed_docs_attend_within_doc_only(self, d):
         """Packed multi-doc prefill: two documents as two chunks over
         the SAME slot pages, each floored at its own start — zero
         cross-document attention, so each chunk equals dense causal
@@ -362,7 +381,7 @@ class TestWindowedAndPackedDocs:
             grouped_attention,
         )
 
-        g, qpk, d, ps, C = 2, 2, 128, 16, 8
+        g, qpk, ps, C = 2, 2, 16, 8
         q, kn, vn, kp, vp, pt, _, _ = _case(2, C, g, qpk, d, ps, 2,
                                             seed=23)
         pt = jnp.tile(pt[:1], (2, 1))  # both docs share slot 0's pages
@@ -392,6 +411,150 @@ class TestWindowedAndPackedDocs:
                                  doc_starts=[0, 0])
         np.testing.assert_array_equal(np.asarray(zf_k),
                                       np.asarray(nof_k))
+
+
+class TestHead64:
+    """ISSUE 38: the serving cell's own shapes at head 64 (LFM2-8B-A1B:
+    8 K/V heads x 4, page 64) on the SAME kernel body: a grid step
+    serves the heads of whole 128-lane tiles of the lane-packed page,
+    q block-diagonal, the scale the true width's."""
+
+    @pytest.mark.parametrize("nc,C,lens", [
+        pytest.param(32, 1, [1] * 30 + [0, 0], id="decode-32-slots"),
+        pytest.param(1, 1, [1], id="chunk-1"),
+        pytest.param(1, 2, [1], id="chunk-2-tail-1"),
+        pytest.param(2, 3, [3, 2], id="chunk-3-tail-2"),
+        pytest.param(1, 128, [77], id="chunk-128-tail-77"),
+        pytest.param(2, 8, [0, 0], id="idle-and-all-pad"),
+    ])
+    def test_cell_shapes_match_oracle(self, nc, C, lens):
+        g, qpk, d, ps, mp = 8, 4, 64, 64, 4
+        q, kn, vn, kp, vp, pt, _, _ = _case(nc, C, g, qpk, d, ps, mp,
+                                            kv="bf16", seed=61)
+        rs = np.random.RandomState(61)
+        starts = rs.randint(0, ps * mp - C + 1, nc)
+        out_k, out_x, pools_k, pools_x = _both(q, kn, vn, kp, vp, pt,
+                                               starts, lens)
+        np.testing.assert_allclose(
+            np.asarray(out_k, np.float32), np.asarray(out_x, np.float32),
+            rtol=2e-2, atol=2e-2)
+        for a, b in zip(pools_k, pools_x):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        # pad rows and idle chunks: exact zeros, both paths
+        pad = np.arange(C)[None, :] >= np.asarray(lens)[:, None]
+        for out in (out_k, out_x):
+            assert not np.any(np.asarray(out, np.float32)[pad])
+
+    def test_fp32_exactness_and_true_width_scale(self):
+        """fp32 pools: 1e-5 against the oracle on the hardest phase; a
+        scale of 1 / sqrt(128) in place of 1 / sqrt(64) would miss by
+        far more."""
+        C, starts_fn, lens = PHASES["partial-page"]
+        q, kn, vn, kp, vp, pt, _, _ = _case(3, C, 4, 2, 64, 16, 2,
+                                            seed=37)
+        out_k, out_x, _, _ = _both(q, kn, vn, kp, vp, pt, starts_fn(16),
+                                   lens)
+        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_x),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("phase", ["decode-row", "partial-page"])
+    def test_other_heads_of_a_step_never_reach_an_output(self, phase):
+        """Huge K/V planted in head 1 (lanes 64..127 of the one tile),
+        cached and new: head 0's rows come out bit for bit as before —
+        q's zero lanes multiply them by an exact 0 and their lanes of
+        p . v are cut — and head 1's change."""
+        C, starts_fn, lens = PHASES[phase]
+        g, qpk, d = 2, 2, 64
+        q, kn, vn, kp, vp, pt, _, _ = _case(3, C, g, qpk, d, 16, 2,
+                                            seed=41)
+        starts = starts_fn(16)
+        base, base_x, _, _ = _both(q, kn, vn, kp, vp, pt, starts, lens)
+        big = jnp.float32(3e4)
+        kn2, vn2 = kn.at[:, :, 1].set(big), vn.at[:, :, 1].set(-big)
+        kp2, vp2 = kp.at[:, :, d:].set(-big), vp.at[:, :, d:].set(big)
+        got, got_x, _, _ = _both(q, kn2, vn2, kp2, vp2, pt, starts, lens)
+        for a, b in ((got, base), (got_x, base_x)):
+            np.testing.assert_array_equal(np.asarray(a[:, :, 0]),
+                                          np.asarray(b[:, :, 0]))
+        assert np.any(np.asarray(got[:, :, 1]) != np.asarray(base[:, :, 1]))
+
+    @pytest.mark.parametrize("phase", ["decode-row", "ragged-chunk"])
+    def test_tp2_each_chip_its_own_heads(self, phase):
+        """Four heads of 64 over two chips: the lanes are the sharded
+        axis (kv_pool_spec), each chip's slice one tile of its own two
+        heads, and the kernel runs per shard (shard_kernel, as
+        attention_block calls it): bit for bit the one-chip run, output
+        and pools."""
+        from megatron_llm_tpu.parallel.mesh import (
+            MODEL_AXIS,
+            ParallelContext,
+            build_mesh,
+            shard_kernel,
+            use_mesh,
+        )
+        from megatron_llm_tpu.parallel.sharding import kv_pool_spec
+
+        C, starts_fn, lens = PHASES[phase]
+        g, qpk, d = 4, 2, 64
+        q, kn, vn, kp, vp, pt, _, _ = _case(3, C, g, qpk, d, 16, 2,
+                                            seed=53)
+        starts = jnp.asarray(starts_fn(16), jnp.int32)
+        lens = jnp.asarray(lens, jnp.int32)
+
+        def op(q, kn, vn, kp, vp, pt, starts, lens):
+            return ragged_paged_attention(
+                q, kn, vn, kp, vp, pt, starts, lens, use_pallas=True,
+                interpret=INTERPRET)
+
+        ref = jax.jit(op)(q, kn, vn, kp, vp, pt, starts, lens)
+        ctx = ParallelContext(build_mesh(tp=2, devices=jax.devices()[:2]))
+        pool = kv_pool_spec(kp.shape, 2, g)
+        assert pool == P(None, None, MODEL_AXIS)
+        qs, ns = P(None, None, MODEL_AXIS, None, None), \
+            P(None, None, MODEL_AXIS, None)
+        with use_mesh(ctx):
+            got = jax.jit(shard_kernel(
+                op, (qs, ns, ns, pool, pool, P(), P(), P()),
+                (qs, pool, pool), check_vma=False))(
+                q, kn, vn, kp, vp, pt, starts, lens)
+        for a, b in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("g,qpk,d", [
+        pytest.param(1, 8, 64, id="falcon7b-mqa-half-a-tile"),
+        pytest.param(3, 1, 64, id="a-tile-and-a-half"),
+    ])
+    def test_what_fills_no_tile_takes_the_twin_and_is_counted(
+            self, monkeypatch, g, qpk, d):
+        """Falcon-7B's one K/V head of 64 is half a tile: the gate says
+        None, the twin serves the SAME lane-packed pool (g * d lanes,
+        never rounded up) exactly, and on a TPU backend the refusal is
+        counted (`kernel_fallbacks.batch/.chat` stay 8), not hidden."""
+        from megatron_llm_tpu.ops import dispatch
+
+        q, kn, vn, kp, vp, pt, _, _ = _case(2, 4, g, qpk, d, 16, 4,
+                                            seed=59)
+        assert kp.shape[2] == g * d
+        starts = jnp.asarray([0, 5], jnp.int32)
+        lens = jnp.asarray([4, 3], jnp.int32)
+        monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+        before = set(dispatch.fallbacks())
+        out, kp2, vp2 = ragged_paged_attention(
+            q, kn, vn, kp, vp, pt, starts, lens, use_pallas=True)
+        new, = set(dispatch.fallbacks()) - before
+        assert f"g={g}, qpk={qpk}, d={d}" in new \
+            and "gate=ragged_paged_block" in new
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(_xla_paged_reference(
+                q, kp2, vp2, pt, starts, lens)))
+
+    def test_a_pool_that_is_not_lane_packed_is_refused(self):
+        q, kn, vn, kp, vp, pt, _, _ = _case(2, 1, 2, 2, 64, 16, 2)
+        starts = jnp.asarray([3, 4], jnp.int32)
+        four_d = kp.reshape(5, 16, 2, 64)
+        with pytest.raises(ValueError, match="lane-packed"):
+            ragged_paged_attention(q, kn, vn, four_d, four_d, pt, starts,
+                                   jnp.ones_like(starts))
 
 
 class TestHistoricalPins:
@@ -474,7 +637,7 @@ class TestHistoricalPins:
         before = np.asarray(kp)
         after = np.asarray(kpk)
         changed = {int(p) for p in np.argwhere(
-            np.any(after != before, axis=(1, 2, 3)))[:, 0]}
+            np.any(after != before, axis=(1, 2)))[:, 0]}
         live = {int(np.asarray(pt)[1, (9 + t) // 16]) for t in range(3)}
         assert changed <= ({0} | live)
 
@@ -538,7 +701,7 @@ class TestHistoricalPins:
         g, qpk, d, ps = 2, 1, 128, 32
         num_pages = 1 + 2 * 2
         keys = jax.random.split(jax.random.key(11), 3)
-        kp = jnp.zeros((num_pages, ps, g, d), jnp.int8)
+        kp = jnp.zeros((num_pages, ps, g * d), jnp.int8)
         vp = jnp.zeros_like(kp)
         kps = jnp.zeros((num_pages, ps, g), jnp.float32)
         vps = jnp.zeros_like(kps)
@@ -553,7 +716,8 @@ class TestHistoricalPins:
         kp2, vp2, kps2, vps2 = scatter_chunk_kv(
             kn, vn, kp, vp, pt, starts, lens, k_scales=kps,
             v_scales=vps)
-        deq = dequantize_rows(kp2[pt[0, 0]], kps2[pt[0, 0]])
+        deq = dequantize_rows(kp2[pt[0, 0]].reshape(ps, g, d),
+                              kps2[pt[0, 0]])
         err = jnp.abs(deq[:8] - kn[0])
         assert bool(jnp.all(err <= kps2[pt[0, 0], :8, :, None] * 0.5
                             + 1e-7))
@@ -596,16 +760,14 @@ class TestDispatchGate:
         chunk), so a near-tie can never flip paths between the scan and
         mixed steps."""
         ok = dict(interpret=True)
-        assert ragged_paged_block(8, 1, 128, 16, 4, **ok) == 8
-        assert ragged_paged_block(1, 8, 128, 16, 4, **ok) == 1
+        # (q block in tokens, K/V heads a grid step)
+        assert ragged_paged_block(8, 1, 128, 16, 4, **ok) == (8, 1)
+        assert ragged_paged_block(1, 8, 128, 16, 4, **ok) == (1, 1)
         # the decode row: width 1 is kernel territory
-        assert ragged_paged_block(1, 1, 128, 64, 8, **ok) == 1
-        assert ragged_paged_block(256, 1, 128, 64, 8, **ok) == 256
+        assert ragged_paged_block(1, 1, 128, 64, 8, **ok) == (1, 1)
+        assert ragged_paged_block(256, 1, 128, 64, 8, **ok) == (256, 1)
         # wide GQA folds shrink the q block under the VMEM row cap
-        assert ragged_paged_block(2048, 8, 128, 16, 4, **ok) == 256
-        # lane alignment
-        assert ragged_paged_block(8, 1, 64, 16, 4, **ok) is None
-        assert ragged_paged_block(1, 1, 64, 64, 8, **ok) is None
+        assert ragged_paged_block(2048, 8, 128, 16, 4, **ok) == (256, 1)
         # page must tile sublanes
         assert ragged_paged_block(8, 1, 128, 8, 4, **ok) is None
         assert ragged_paged_block(8, 1, 128, 24, 4, **ok) is None
@@ -622,14 +784,44 @@ class TestDispatchGate:
         assert ragged_paged_block(8, 1, 128, 16, 4, min_cache=128,
                                   interpret=True) is None
         assert ragged_paged_block(8, 1, 128, 16, 8, min_cache=128,
-                                  interpret=True) == 8
+                                  interpret=True) == (8, 1)
         assert ragged_paged_block(1, 1, 128, 16, 4, min_cache=128,
                                   interpret=True) is None
         assert ragged_paged_block(1, 1, 128, 16, 8, min_cache=128,
-                                  interpret=True) == 1
+                                  interpret=True) == (1, 1)
         if jax.default_backend() != "tpu":
             assert ragged_paged_block(8, 1, 128, 16, 4,
                                       interpret=False) is None
+
+    @pytest.mark.parametrize("s,qpk,d,page,groups,kv,want", [
+        # heads that fill 128-lane tiles take the kernel (ISSUE 38): a
+        # decode row all of its heads a grid step, a chunk one tile's
+        pytest.param(1, 4, 64, 64, 8, None, (1, 8), id="lfm2-decode-row"),
+        pytest.param(128, 4, 64, 64, 8, None, (128, 2), id="lfm2-chunk"),
+        pytest.param(2, 4, 64, 64, 8, None, (2, 8), id="lfm2-width-2"),
+        pytest.param(1, 16, 64, 64, 2, None, (1, 2),
+                     id="falcon40b-tp4-chip-decode-row"),
+        pytest.param(1, 4, 64, 64, 8, jnp.int8, (1, 8), id="lfm2-int8"),
+        pytest.param(1, 4, 128, 64, 8, None, (1, 8),
+                     id="head128-decode-row-whole-page"),
+        pytest.param(8, 2, 32, 16, 4, None, (8, 4), id="four-heads-of-32"),
+        # ... and these fill none: the twin, on the same pool
+        pytest.param(1, 71, 64, 64, 1, None, None, id="falcon7b-mqa-head64"),
+        pytest.param(128, 71, 64, 64, 1, None, None,
+                     id="falcon7b-mqa-head64-chunk"),
+        pytest.param(8, 2, 64, 16, 3, None, None, id="a-tile-and-a-half"),
+        pytest.param(8, 2, 96, 16, 4, None, None, id="96-divides-no-tile"),
+        # a q block Mosaic takes: folded rows the whole axis or a
+        # multiple of 8 (a speculative width 5 at qpk 4 would be blocks
+        # of 4 rows of 20: PERF.md §7 had it as found and not cured)
+        pytest.param(5, 4, 128, 16, 1, None, None, id="spec-5-rows-of-4"),
+        pytest.param(5, 8, 128, 16, 1, None, (1, 1), id="spec-5-rows-of-8"),
+    ])
+    def test_head_width_gate(self, s, qpk, d, page, groups, kv, want):
+        """What the gate can see decides: the K/V heads of the call
+        fill lane tiles or they do not — no model's name, no option."""
+        assert ragged_paged_block(s, qpk, d, page, 32, groups=groups,
+                                  kv_dtype=kv, interpret=True) == want
 
     def test_ineligible_page_size_falls_back_exact(self):
         """Shapes the gate refuses are served by the XLA twin — for
@@ -708,12 +900,12 @@ class TestAttentionBlockPaged:
             pt[i] = np.arange(1 + i * mp, 1 + (i + 1) * mp)
         if random_pool:
             ks = jax.random.split(jax.random.key(seed), 2)
-            kp = jax.random.normal(ks[0], (num_pages, ps, g, d),
+            kp = jax.random.normal(ks[0], (num_pages, ps, g * d),
                                    jnp.float32)
-            vp = jax.random.normal(ks[1], (num_pages, ps, g, d),
+            vp = jax.random.normal(ks[1], (num_pages, ps, g * d),
                                    jnp.float32)
         else:
-            kp = jnp.zeros((num_pages, ps, g, d), jnp.float32)
+            kp = jnp.zeros((num_pages, ps, g * d), jnp.float32)
             vp = jnp.zeros_like(kp)
         cache = {
             "k_pages": kp, "v_pages": vp,
@@ -801,7 +993,7 @@ class TestAttentionBlockPaged:
                                       [8, 34])
         pt = np.asarray(cache["page_table"])
         changed = np.argwhere(
-            np.any(after_k != before_k, axis=(2, 3)))  # (page, off)
+            np.any(after_k != before_k, axis=2))  # (page, off)
         expect = {(int(pt[0, 7 // ps]), 7 % ps),
                   (int(pt[1, 33 // ps]), 33 % ps)}
         assert {tuple(map(int, rc)) for rc in changed} == expect
@@ -828,7 +1020,7 @@ class TestAttentionBlockPaged:
         changed_pages = set(
             int(p) for p in
             np.argwhere(np.any(after_k != before_k,
-                               axis=(1, 2, 3)))[:, 0]
+                               axis=(1, 2)))[:, 0]
         )
         assert changed_pages <= {0, int(pt[0, 5 // ps])}
 

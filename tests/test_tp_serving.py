@@ -67,17 +67,24 @@ def _engine(model, params, **over):
 
 class TestPoolSpecRule:
     def test_kv_pool_axis_is_the_group_axis_or_none(self):
-        assert kv_pool_axis((9, 16, 4, 8), 2) == 2   # data pool
-        assert kv_pool_axis((9, 16, 4), 2) == 2      # int8 scale pool
-        assert kv_pool_axis((9, 16, 4, 8), 1) is None  # tp=1
-        assert kv_pool_axis((9, 16, 3, 8), 2) is None  # indivisible
-        assert kv_pool_axis((9, 16, 1, 8), 2) is None  # MQA: g < tp
+        """A token's heads lie side by side along the lanes (index 2 of
+        the lane-packed pool) and the GROUPS decide: a chip's slice is
+        its own heads' lanes."""
+        assert kv_pool_axis((9, 16, 32), 2, 4) == 2   # data pool, 4 x 8
+        assert kv_pool_axis((9, 16, 4), 2, 4) == 2    # int8 scale pool
+        assert kv_pool_axis((9, 16, 32), 1, 4) is None  # tp=1
+        assert kv_pool_axis((9, 16, 24), 2, 3) is None  # indivisible
+        assert kv_pool_axis((9, 16, 64), 2, 1) is None  # MQA: g < tp,
+        # although 64 lanes would halve: Falcon-7B's one head is not cut
+        with pytest.raises(ValueError, match="num_pages, page_size"):
+            kv_pool_axis((9, 16, 4, 8), 2, 4)  # the 4-D pool is gone
 
     def test_kv_pool_spec_mirrors_the_axis(self):
-        assert kv_pool_spec((9, 16, 4, 8), 2) == P(
-            None, None, MODEL_AXIS, None)
-        assert kv_pool_spec((9, 16, 4), 2) == P(None, None, MODEL_AXIS)
-        assert kv_pool_spec((9, 16, 4, 8), 1) == P()
+        assert kv_pool_spec((9, 16, 32), 2, 4) == P(
+            None, None, MODEL_AXIS)
+        assert kv_pool_spec((9, 16, 4), 2, 4) == P(None, None, MODEL_AXIS)
+        assert kv_pool_spec((9, 16, 32), 1, 4) == P()
+        assert kv_pool_spec((9, 16, 64), 2, 1) == P()
 
     def test_decode_param_specs_refuses_flattened_glu(self, tiny_model):
         model, params = tiny_model
@@ -132,10 +139,11 @@ class TestPerChipGauges:
         e1 = _engine(model, params)
         e2 = _engine(model, params, serving_tp=2)
         # pools follow the one rule; scalar-prefetch operands replicated
-        g = model.cfg.num_query_groups
+        g, d = model.cfg.num_query_groups, model.cfg.head_dim
         for pool in (*e2._pools_k, *e2._pools_v):
-            assert pool.sharding.spec == kv_pool_spec(pool.shape, 2)
-            assert pool.sharding.shard_shape(pool.shape)[2] == g // 2
+            assert pool.shape[2] == g * d  # lane-packed
+            assert pool.sharding.spec == kv_pool_spec(pool.shape, 2, g)
+            assert pool.sharding.shard_shape(pool.shape)[2] == g // 2 * d
         assert e1.kv_pool_bytes() == 2 * e2.kv_pool_bytes()
         assert e1.kv_bytes_per_token() == 2 * e2.kv_bytes_per_token()
         c = e2.counters()
@@ -148,7 +156,8 @@ class TestPerChipGauges:
         e2 = _engine(model, params, kv_dtype="int8", page_size=32,
                      max_context=96, serving_tp=2)
         for pool in (*e2._pools_ks, *e2._pools_vs):
-            assert pool.sharding.spec == kv_pool_spec(pool.shape, 2)
+            assert pool.sharding.spec == kv_pool_spec(
+                pool.shape, 2, model.cfg.num_query_groups)
         assert e1.kv_pool_bytes() == 2 * e2.kv_pool_bytes()
 
     def test_single_chip_gauges_unchanged(self, tiny_model):
