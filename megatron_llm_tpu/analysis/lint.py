@@ -90,6 +90,9 @@ HOT_PATHS: Dict[str, Set[str]] = {
         "DecodeEngine._decode_round",
         "DecodeEngine._mixed_round",
         "DecodeEngine._spec_round",
+        # every operand copy up and every fetch back of a round
+        "DecodeEngine._upload",
+        "DecodeEngine._fetch",
         "DecodeEngine._book_token",
         "DecodeEngine._admit",
         # ISSUE 15 device-cost accounting: per-retire cost record,

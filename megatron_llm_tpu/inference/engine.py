@@ -175,6 +175,13 @@ SSM_COUNTERS = ("serve_ssm_rows", "serve_ssm_chunk_tokens",
                 "serve_ssm_chunks")
 HOST_PHASES = ("schedule", "build_inputs", "dispatch", "fetch", "book",
                "wait")
+# parts of a phase, each timed by its own child span and counted as
+# serve_host_ms_<part>, so each NESTS inside its phase's counter: the
+# operand copies to the device (`engine.upload`, inside
+# `engine.build_inputs`), the wait for the device to finish the round
+# (`engine.fetch.wait`) and the copies back (`engine.fetch.copy`, both
+# inside `engine.fetch`)
+HOST_SUBPHASES = ("upload", "fetch_wait", "fetch_copy")
 
 
 def _name_os_thread(name: str) -> None:
@@ -1417,7 +1424,7 @@ class DecodeEngine:
         self._rows_useful = 0
         self._kind_rounds = dict.fromkeys(ROUND_KINDS, 0)
         self._kind_ms = dict.fromkeys(ROUND_KINDS, 0.0)
-        self._host_ms = dict.fromkeys(HOST_PHASES, 0.0)
+        self._host_ms = dict.fromkeys(HOST_PHASES + HOST_SUBPHASES, 0.0)
         # fault-injection hook (ISSUE 20, inference/chaos.py): called at
         # the top of every scheduler round INSIDE the round's timed
         # window, so an injected stall rides the round wall the perf
@@ -1451,6 +1458,42 @@ class DecodeEngine:
         if self._ctx is None:
             return jnp.asarray(x)
         return jax.device_put(np.asarray(x), self._rep)
+
+    def _upload(self, *host):
+        """A round's operand copies to the device, one `_dev` each in
+        the order given, under the `engine.upload` span (a child of
+        `engine.build_inputs`). Its `copies` and `bytes` are counted
+        from the numpy operands handed in. Returns the device arrays
+        and the span."""
+        with self.tracer.span("engine.upload", copies=len(host),
+                              bytes=sum(x.nbytes for x in host)) as sp:
+            out = tuple(self._dev(x) for x in host)
+        return out, sp
+
+    def _fetch(self, tokens, *more):
+        """Every device-to-host fetch of a round, inside its
+        `engine.fetch` span. `engine.fetch.wait` waits for the round's
+        token output to be ready (the wait for the device);
+        `engine.fetch.copy` then copies back the tokens and `more`, in
+        the order given: the routing integers (`models/moe.py`), the
+        spec round's targets and accepted counts, the log-probs; a None
+        (a model that does not route, log-probs no request asked for)
+        is not fetched and comes back None. Its `copies` and `bytes`
+        count the arrays fetched. Returns the host arrays and the two
+        spans by their serve_host_ms_* part."""
+        # the tokens' copy back is asked for before the wait, as
+        # `np.asarray` of an unfinished round asks for it: the runtime
+        # then sends them as soon as they exist, and timing the wait
+        # apart costs the round no extra trip to the device
+        tokens.copy_to_host_async()
+        with self.tracer.span("engine.fetch.wait") as sp_wait:
+            jax.block_until_ready(tokens)
+        outs = (tokens, *more)
+        live = [x for x in outs if x is not None]
+        with self.tracer.span("engine.fetch.copy", copies=len(live),
+                              bytes=sum(x.nbytes for x in live)) as sp_copy:
+            host = tuple(None if x is None else np.asarray(x) for x in outs)
+        return host, {"fetch_wait": sp_wait, "fetch_copy": sp_copy}
 
     def _capture_cost(self, name: str, key, fn, args_thunk) -> None:
         """Compiled-cost capture for one freshly MINTED executable
@@ -2326,8 +2369,7 @@ class DecodeEngine:
         live = [i for i, s in enumerate(self._slots) if s.req is not None]
         if not live:
             return None
-        with self.tracer.span("engine.build_inputs",
-                              transfers=11) as sp_build:
+        with self.tracer.span("engine.build_inputs") as sp_build:
             # nearest completion: forced tokens still owed + sampling
             # budget
             remaining = min(
@@ -2367,14 +2409,10 @@ class DecodeEngine:
                 seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
                 sample_steps[i] = s.sample_step
             all_greedy = all(self._slots[i].req.greedy for i in live)
-            operands = (
-                self._dev(self._pt), self._dev(self._lengths),
-                self._last_logits, self._dev(active),
-                self._dev(forced), self._dev(use_forced),
-                self._dev(greedy), self._dev(temperature),
-                self._dev(top_k), self._dev(top_p),
-                self._dev(seeds), self._dev(sample_steps),
-            )
+            up, sp_up = self._upload(
+                self._pt, self._lengths, active, forced, use_forced,
+                greedy, temperature, top_k, top_p, seeds, sample_steps)
+            operands = (*up[:2], self._last_logits, *up[2:])
         with self.tracer.span("engine.dispatch", fn="decode_scan",
                               kind="decode", width=hor,
                               greedy=all_greedy,
@@ -2385,17 +2423,14 @@ class DecodeEngine:
                     self._dec_params, self._cache, *operands)
             self._last_logits = new_logits
         with self.tracer.span("engine.fetch") as sp_fetch:
-            chosen = np.asarray(chosen)  # (slots, hor) — the scheduler's
-            # own data dependency: the next round cannot be built
-            # without it
-            moe_stats = self._fetch_moe_stats(moe_stats)
             # P0 (graft-check GR006 dogfood): the logprob matrix is an
             # EXTRA per-round device->host transfer that most serving
             # traffic (return_log_probs=False) never reads — fetch it
             # only when some live request actually asked
             want_lp = any(self._slots[i].req.return_log_probs
                           for i in live)
-            chosen_lp = np.asarray(chosen_lp) if want_lp else None
+            (chosen, moe_stats, chosen_lp), fetched = self._fetch(
+                chosen, moe_stats, chosen_lp if want_lp else None)
         self._steps += hor
 
         with self.tracer.span("engine.book") as sp_book:
@@ -2431,8 +2466,9 @@ class DecodeEngine:
             "moe_stats": moe_stats,
             "rows_computed": self.slots * hor,
             "rows_useful": len(live) * hor,
-            "phases": {"build_inputs": sp_build, "dispatch": sp_disp,
-                       "fetch": sp_fetch, "book": sp_book},
+            "phases": {"build_inputs": sp_build, "upload": sp_up,
+                       "dispatch": sp_disp, "fetch": sp_fetch, **fetched,
+                       "book": sp_book},
             "cost_key": (hor, all_greedy),
             "event_args": {"horizon": hor, "decode_slots": len(live),
                            "prefill_tokens": prefill_tokens},
@@ -2455,8 +2491,7 @@ class DecodeEngine:
         round's correlation key: a streaming client's stalled `id:`
         greps straight to these rounds) and the (width, greedy)
         executable key the dispatch-overhead accounting reads."""
-        with self.tracer.span("engine.build_inputs",
-                              transfers=12) as sp_build:
+        with self.tracer.span("engine.build_inputs") as sp_build:
             n = self.slots
             pref = [i for i, s in enumerate(self._slots) if s.prefilling]
             ci = min(pref, key=lambda i: self._slots[i].req.rid)
@@ -2496,15 +2531,11 @@ class DecodeEngine:
                 seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
                 sample_steps[i] = self._slots[i].sample_step
             all_greedy = all(self._slots[i].req.greedy for i in dec)
-            operands = (
-                self._dev(self._pt), self._dev(self._lengths),
-                self._last_logits, self._dev(chunk_tokens),
-                self._dev(chunk_lens), self._dev(is_prefill),
-                self._dev(ci, np.int32),
-                self._dev(greedy), self._dev(temperature),
-                self._dev(top_k), self._dev(top_p),
-                self._dev(seeds), self._dev(sample_steps),
-            )
+            up, sp_up = self._upload(
+                self._pt, self._lengths, chunk_tokens, chunk_lens,
+                is_prefill, np.int32(ci), greedy, temperature, top_k,
+                top_p, seeds, sample_steps)
+            operands = (*up[:2], self._last_logits, *up[2:])
         chunk_rid = s_c.req.rid
         with self.tracer.span("engine.dispatch", fn="mixed_step",
                               kind="mixed", width=width,
@@ -2516,8 +2547,6 @@ class DecodeEngine:
                     self._dec_params, self._cache, *operands)
             self._last_logits = new_last
         with self.tracer.span("engine.fetch") as sp_fetch:
-            first = np.asarray(first)
-            moe_stats = self._fetch_moe_stats(moe_stats)
             # P0 (graft-check GR006 dogfood): logprob outputs transfer
             # only when a live request asked for them — the mixed round
             # is the chunked-prefill interference path the decode-p95
@@ -2525,9 +2554,9 @@ class DecodeEngine:
             want_lp = (s_c.req.return_log_probs
                        or any(self._slots[i].req.return_log_probs
                               for i in dec))
-            first_lp = np.asarray(first_lp) if want_lp else None
-            chunk_lps = (np.asarray(chunk_lps)
-                         if s_c.req.return_log_probs else None)
+            (first, moe_stats, first_lp, chunk_lps), fetched = self._fetch(
+                first, moe_stats, first_lp if want_lp else None,
+                chunk_lps if s_c.req.return_log_probs else None)
         self._steps += 1
         self._prefill_tokens += ln
 
@@ -2575,8 +2604,9 @@ class DecodeEngine:
             # tokens and one token a decoding slot are real
             "rows_computed": width + n,
             "rows_useful": ln + len(dec),
-            "phases": {"build_inputs": sp_build, "dispatch": sp_disp,
-                       "fetch": sp_fetch, "book": sp_book},
+            "phases": {"build_inputs": sp_build, "upload": sp_up,
+                       "dispatch": sp_disp, "fetch": sp_fetch, **fetched,
+                       "book": sp_book},
             "cost_key": (width, all_greedy),
             "event_args": {"rid": chunk_rid, "prefill_tokens": ln,
                            "decode_slots": len(dec)},
@@ -2703,8 +2733,7 @@ class DecodeEngine:
         exactly the booked count, which IS the rejection rollback (the
         next round's writes overwrite stale K/V past it). Returns the
         round's facts for `_emit_round`."""
-        with self.tracer.span("engine.build_inputs",
-                              transfers=11) as sp_build:
+        with self.tracer.span("engine.build_inputs") as sp_build:
             width = self.spec_decode_k + 1
             n = self.slots
             live = [i for i, s in enumerate(self._slots)
@@ -2738,14 +2767,11 @@ class DecodeEngine:
                 seeds[i] = np.uint32(r.seed & 0xFFFFFFFF)
                 sample_steps[i] = s.sample_step
             all_greedy = all(self._slots[i].req.greedy for i in live)
-            operands = (
-                self._dev(self._pt), self._dev(self._lengths),
-                self._last_logits, self._dev(chunk_tokens),
-                self._dev(chunk_lens), self._dev(is_spec),
-                self._dev(greedy), self._dev(temperature),
-                self._dev(top_k), self._dev(top_p),
-                self._dev(seeds), self._dev(sample_steps),
-            )
+            up, sp_up = self._upload(
+                self._pt, self._lengths, chunk_tokens, chunk_lens,
+                is_spec, greedy, temperature, top_k, top_p, seeds,
+                sample_steps)
+            operands = (*up[:2], self._last_logits, *up[2:])
         with self.tracer.span("engine.dispatch", fn="spec_step",
                               kind="spec", width=width, greedy=all_greedy,
                               prefill_tokens=prefill_tokens,
@@ -2755,18 +2781,16 @@ class DecodeEngine:
                     self._dec_params, self._cache, *operands)
             self._last_logits = new_last
         with self.tracer.span("engine.fetch") as sp_fetch:
-            first = np.asarray(first)
-            moe_stats = self._fetch_moe_stats(moe_stats)
-            gt = np.asarray(gt)
-            acc = np.asarray(acc)
             # P0 (graft-check GR006 dogfood): the two logprob matrices
             # are EXTRA per-round device->host transfers that
             # logprob-less traffic (the common case) never reads — fetch
             # them only when some live request actually asked
             want_lp = any(self._slots[i].req.return_log_probs
                           for i in live)
-            first_lp = np.asarray(first_lp) if want_lp else None
-            gt_lp = np.asarray(gt_lp) if want_lp else None
+            if not want_lp:
+                first_lp = gt_lp = None
+            (first, moe_stats, gt, acc, first_lp, gt_lp), fetched = \
+                self._fetch(first, moe_stats, gt, acc, first_lp, gt_lp)
         self._steps += 1
         self._spec_rounds += 1
 
@@ -2832,8 +2856,9 @@ class DecodeEngine:
             # + accepted drafts) are what the round was for
             "rows_computed": n * width,
             "rows_useful": emitted_total,
-            "phases": {"build_inputs": sp_build, "dispatch": sp_disp,
-                       "fetch": sp_fetch, "book": sp_book},
+            "phases": {"build_inputs": sp_build, "upload": sp_up,
+                       "dispatch": sp_disp, "fetch": sp_fetch, **fetched,
+                       "book": sp_book},
             "cost_key": (width, all_greedy),
             "event_args": {"decode_slots": len(live),
                            "emitted": emitted_total,
@@ -3481,11 +3506,6 @@ class DecodeEngine:
                 feature, "a slot's state would have to travel with its "
                 "pages")
 
-    def _fetch_moe_stats(self, stats) -> Optional[np.ndarray]:
-        """The round's four routing integers (models/moe.py), fetched
-        beside the round's tokens; None for a model that does not route."""
-        return None if stats is None else np.asarray(stats)
-
     def kv_pool_dtype(self) -> str:
         """The pool's ACTUAL storage dtype (e.g. 'int8', 'bfloat16',
         'float32') — what the gauges report. kv_dtype='bf16' means
@@ -3663,6 +3683,11 @@ class DecodeEngine:
             for phase in HOST_PHASES:
                 out["serve_host_ms_" + phase] = round(
                     self._host_ms[phase], 3)
+            # after every key pinned before them: the parts of two
+            # phases, each already counted in its phase's total
+            for part in HOST_SUBPHASES:
+                out["serve_host_ms_" + part] = round(
+                    self._host_ms[part], 3)
             if self.cfg.num_experts:
                 # a model that routes, after everything pinned before:
                 # the rounds' own integers, booked by the serve loop

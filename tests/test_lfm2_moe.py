@@ -161,6 +161,31 @@ def test_engine_serves_the_reference_at_every_position(made):
     assert list(c)[-4:] == list(MOE_COUNTERS)
 
 
+def test_a_round_fetches_its_routing_integers_beside_its_tokens(made,
+                                                                 tmp_path):
+    """Every round copies back two arrays, the tokens and the four routing
+    integers, in its `engine.fetch.copy` span, after its
+    `engine.fetch.wait`; and copies up its eleven (decode) or twelve
+    (mixed) operands in its `engine.upload` span."""
+    _, model, tree, _ = made
+    eng = engine_of(model, tree, trace_dir=str(tmp_path))
+    reqs = [eng.submit([5, 6, 7, 8, 9], 4, top_k=1),
+            eng.submit([40, 41], 4, top_k=1)]
+    eng.drain()
+    for r in reqs:
+        r.result(5)
+    evs = [e for e in eng.tracer.events() if e["ph"] == "X"]
+    copies = [e["args"]["copies"] for e in evs
+              if e["name"] == "engine.fetch.copy"]
+    uploads = [e["args"]["copies"] for e in evs
+               if e["name"] == "engine.upload"]
+    kinds = [e["args"]["kind"] for e in evs
+             if e["name"] == "engine.dispatch"]
+    assert copies == [2] * len(kinds) and "decode" in kinds
+    assert uploads == [{"mixed": 12, "decode": 11}[k] for k in kinds]
+    assert sum(e["name"] == "engine.fetch.wait" for e in evs) == len(kinds)
+
+
 def test_engine_serves_the_reference_through_the_paged_kernel(monkeypatch):
     """(b) again at the published head width, 64: two K/V heads fill one
     128-lane tile of the lane-packed pools (ISSUE 38) and every

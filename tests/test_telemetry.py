@@ -322,6 +322,9 @@ LEGACY_METRICS_KEYS = [
     "serve_host_ms_schedule", "serve_host_ms_build_inputs",
     "serve_host_ms_dispatch", "serve_host_ms_fetch", "serve_host_ms_book",
     "serve_host_ms_wait",
+    # the parts of two phases, after every key pinned before them
+    "serve_host_ms_upload", "serve_host_ms_fetch_wait",
+    "serve_host_ms_fetch_copy",
 ]
 
 
@@ -718,17 +721,24 @@ ROUND_PHASES = ["engine.schedule", "engine.build_inputs", "engine.dispatch",
 SCRIPT = dict(prompts=[list(range(5, 16)), list(range(40, 45))], out=5,
               rows_computed=(8 + 2) + (4 + 2) + (8 + 2) + 2 * 4 + 2 * 1,
               rows_useful=11 + 5 + 5 + 5, mixed=3, decode=2)
+# operand copies to the device a round: twelve for a mixed step, eleven
+# for a decode scan (the carried logits stay on the device)
+UPLOADS = {"mixed": 12, "decode": 11}
 
 
 def _span_reduce():
+    return _bench("span_reduce")
+
+
+def _bench(name):
+    """A module of the repo's `benchmark/` package."""
+    import importlib
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
-    from benchmark import span_reduce
-
-    return span_reduce
+    return importlib.import_module("benchmark." + name)
 
 
 def _children(spans):
@@ -795,7 +805,7 @@ class TestSpansInTheProfilersTrace:
         rounds = [i for i, sp in enumerate(spans)
                   if sp["name"] == "engine.round"]
         assert len(rounds) == SCRIPT["mixed"] + SCRIPT["decode"]
-        kinds, rids = [], set()
+        kinds, rids, uploads = [], set(), []
         for n, i in enumerate(rounds):
             assert spans[i]["line"] == "engine-serve"
             assert spans[i]["parent"] is None
@@ -809,9 +819,23 @@ class TestSpansInTheProfilersTrace:
             assert disp["width"] >= 1 and "decode_slots" in disp
             if disp["kind"] == "mixed":
                 rids.add(disp["rid"])
-            assert spans[kids[i][1]]["args"]["transfers"] >= 11
+            # the uploads inside the build, the wait and the copy back
+            # inside the fetch
+            build, fetch = kids[i][1], kids[i][3]
+            assert [spans[k]["name"] for k in kids[build]] == \
+                ["engine.upload"]
+            assert [spans[k]["name"] for k in kids[fetch]] == \
+                ["engine.fetch.wait", "engine.fetch.copy"]
+            up = spans[kids[build][0]]["args"]
+            assert up["copies"] == UPLOADS[disp["kind"]] and up["bytes"] > 0
+            uploads.append(up["copies"])
+            copy = spans[kids[fetch][1]]["args"]
+            # the tokens alone: no request asked for log-probs, the
+            # model does not route
+            assert copy["copies"] == 1 and copy["bytes"] > 0
             assert spans[i]["self_ns"] >= 0
         assert kinds == ["mixed"] * 3 + ["decode"] * 2
+        assert sum(uploads) == 3 * 12 + 2 * 11
         assert rids == set(captured["rids"])
         waits = [sp for sp in spans if sp["name"] == "engine.wait_for_work"]
         assert waits and all(w["line"] == "engine-serve"
@@ -899,6 +923,11 @@ class TestRoundCounters:
         assert all(x > 0 for x in spent)
         assert sum(spent) <= wall + 0.01  # children of the rounds
         assert c["serve_host_ms_wait"] == 0.0  # drain() never waits
+        # the parts nest in their phases
+        for part, whole in (("upload", "build_inputs"),
+                            ("fetch_wait", "fetch"), ("fetch_copy", "fetch")):
+            assert 0 < c["serve_host_ms_" + part] <= \
+                c["serve_host_ms_" + whole], part
         # the flight recorder kept its event names
         kinds = [e["kind"] for e in eng.recorder.snapshot()["events"]]
         assert kinds.count("round.mixed") == SCRIPT["mixed"]
@@ -907,10 +936,109 @@ class TestRoundCounters:
     def test_emit_helper_is_a_hot_path(self):
         from megatron_llm_tpu.analysis.lint import HOT_PATHS
 
-        assert "DecodeEngine._emit_round" in \
+        assert {"DecodeEngine._emit_round", "DecodeEngine._upload",
+                "DecodeEngine._fetch"} <= \
             HOT_PATHS["megatron_llm_tpu/inference/engine.py"]
         assert {"SpanTracer.step_span", "_Span.note"} <= \
             HOT_PATHS["megatron_llm_tpu/telemetry/trace.py"]
+
+
+class TestUploadsAndFetches:
+    # a prompt on which the tiny model's greedy continuation cycles, so
+    # the drafter proposes and spec rounds run (tests/test_spec_decode.py)
+    CYCLE = [9, 206, 145, 115]
+    UPLOADS = {"mixed": 12, "decode": 11, "spec": 11}
+    # arrays copied back a round: the tokens (spec: also the greedy
+    # targets and accepted counts), and the log-probs when asked for
+    COPIES = {False: {"mixed": 1, "decode": 1, "spec": 3},
+              True: {"mixed": 3, "decode": 2, "spec": 5}}
+
+    @pytest.mark.parametrize("log_probs", [False, True])
+    def test_copies_by_round_kind(self, tiny_model, tmp_path, log_probs):
+        """Every round's `engine.upload` and `engine.fetch.copy`, read
+        from the ring, count what its kind copies up and back."""
+        sr = _span_reduce()
+        eng = _engine(tiny_model, tmp=tmp_path, prefill_chunk_tokens=8,
+                      spec_decode_k=4)
+        req = eng.submit(self.CYCLE, 30, top_k=1,
+                         return_log_probs=log_probs)
+        eng.drain()
+        req.result(5)
+        spans = sr.program_spans(_ring_as_trace(eng.tracer))
+        kids = _children(spans)
+        seen = set()
+        for i in kids[None]:
+            if spans[i]["name"] != "engine.round":
+                continue
+            phase = {spans[k]["name"]: k for k in kids[i]}
+            kind = spans[phase["engine.dispatch"]]["args"]["kind"]
+            seen.add(kind)
+            up, = kids[phase["engine.build_inputs"]]
+            assert spans[up]["args"]["copies"] == self.UPLOADS[kind]
+            wait, copy = kids[phase["engine.fetch"]]
+            assert spans[copy]["name"] == "engine.fetch.copy"
+            assert spans[copy]["args"]["copies"] == \
+                self.COPIES[log_probs][kind], kind
+        assert seen == {"mixed", "decode", "spec"}
+        c = eng.counters()
+        assert c["serve_spec_rounds"] > 0
+        assert 0 < c["serve_host_ms_upload"] <= c["serve_host_ms_build_inputs"]
+
+
+def _two_rounds(wait=True):
+    """A hand-made trace of two serve rounds (times in us). Round 1 is
+    causal: its one device operation runs 130-250 inside the host's
+    wait 120-280. Round 2's operation ends at 580, after its wait ended
+    at 560, which no clock that agrees with the host's can show."""
+    def ev(name, t0, t1, **args):
+        return [name, t0 * 1e3, (t1 - t0) * 1e3, args]
+
+    serve = []
+    for rnd, base in ((1, 0), (2, 300)):
+        serve += [ev("engine.round", base + 90, base + 310, round=rnd),
+                  ev("engine.dispatch", base + 100, base + 120),
+                  ev("engine.fetch", base + 120, base + 300)]
+        if wait:
+            serve += [ev("engine.fetch.wait", base + 120, base + 260
+                         if rnd == 2 else base + 280),
+                      ev("engine.fetch.copy", base + 280, base + 300)]
+    ops = [ev("fusion.1", 130, 250), ev("fusion.2", 440, 580)]
+    return {"planes": [
+        {"name": "/device:TPU:0",
+         "lines": [{"name": "XLA Ops", "events": ops}]},
+        {"name": "/host:CPU",
+         "lines": [{"name": "engine-serve", "events": serve}]}]}
+
+
+class TestClockCheck:
+    def test_lags_counts_and_reader_on_a_hand_made_trace(self):
+        cc = _bench("clock_check")
+        trace = _two_rounds()
+        lags = cc.ready_lags(trace)
+        assert [r["round"] for r in lags] == [1, 2]
+        # ready: 280 - 250 and 560 - 580; launch: 130 - 100, 440 - 400
+        assert [r["ready_lag_ms"] for r in lags] == \
+            [pytest.approx(0.030), pytest.approx(-0.020)]
+        assert [r["launch_lag_ms"] for r in lags] == \
+            [pytest.approx(0.030), pytest.approx(0.040)]
+        got = cc.clock_check(trace)
+        assert got["rounds"] == 2 and got["acausal_rounds"] == 1
+        assert got["ready_lag_ms_min"] == pytest.approx(-0.020)
+        assert got["ready_lag_ms_p50"] == pytest.approx(0.005)
+        assert got["launch_lag_ms_min"] == pytest.approx(0.030)
+        # the device plane reads 0.02-0.03 ms later than the host's
+        assert got["skew_ms"] == [pytest.approx(0.020),
+                                  pytest.approx(0.030)]
+        assert cc.read_ready_lag({"trace": trace}, {}) == \
+            pytest.approx(0.005)
+
+    def test_a_program_without_the_wait_span_reads_nothing(self):
+        cc = _bench("clock_check")
+        trace = _two_rounds(wait=False)
+        assert cc.ready_lags(trace) == []
+        assert cc.clock_check(trace)["rounds"] == 0
+        assert cc.read_ready_lag({"trace": trace}, {}) is None
+        assert cc.read_ready_lag({"trace": None}, {}) is None
 
 
 TRAIN_SCOPES = ["embed", "layers", "block", "norm", "attention",
