@@ -1097,11 +1097,12 @@ class DecodeEngine:
             if quantize_weights and self.serving_tp > 1:
                 raise ValueError(
                     "quantize_weights is single-chip-layout only (the "
-                    "weight-only int8 decode tree bakes the flattened "
-                    "(h, 2f) GLU view, whose gate|up concat crosses "
-                    "the tp shard boundary); serve the fp decode tree "
-                    "on a tp mesh, or quantize at tp=1 (docs/GUIDE.md "
-                    "'Serving on a tp mesh & replica routing')")
+                    "weight-only int8 decode tree bakes the single-chip "
+                    "(2, f, h) GLU form, one scale a flat gate|up "
+                    "column, which tp does not shard); serve the fp "
+                    "decode tree on a tp mesh, or quantize at tp=1 "
+                    "(docs/GUIDE.md 'Serving on a tp mesh & replica "
+                    "routing')")
             self._ctx = ParallelContext(
                 build_mesh(tp=self.serving_tp, devices=devices))
             self._rep = self._ctx.sharding()  # replicated operands
@@ -1196,9 +1197,10 @@ class DecodeEngine:
                     "transform)")
             dec = model.prepare_decode_params(params, quantize_int8=True)
         elif hasattr(model, "prepare_decode_params"):
-            # tp engines keep the UNFLATTENED (h, 2, f) GLU layout: the
-            # single-chip (h, 2f) flatten concatenates gate|up along
-            # the axis tp shards (parallel/sharding.decode_param_specs)
+            # tp engines keep the UNFLATTENED (h, 2, f) GLU layout,
+            # whose f axis tp shards (parallel/sharding.
+            # decode_param_specs); single-chip engines hold it gate|up-
+            # major, (2, f, h), as the matvec reads it
             dec = model.prepare_decode_params(
                 params, flatten_glu=(self.serving_tp == 1))
         else:

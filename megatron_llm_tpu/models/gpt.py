@@ -205,26 +205,34 @@ class GPTModel:
           read+write of all layer weights per step (traced on v5e:
           ~95us/layer/step, i.e. the GEMVs paid double their weight
           traffic). transformer_stack unrolls over the tuple;
-        - the GLU up/gate weight (h, 2, f) is flattened to (h, 2f) (a
-          row-major bitcast): the 2-sized axis otherwise tiles into
-          sublanes and the matvec streams at ~33% of HBM bandwidth;
+        - the GLU up/gate weight (h, 2, f) is held gate|up-major,
+          (2, f, h), its transpose: `_mlp_up` issues one matvec over
+          its 2f output columns (`ops/quantization.qdot`'s rank-3
+          path), gate | up. Read as (h, 2, f) the 2-sized axis tiles
+          into sublanes and the matvec streams at ~33% of HBM
+          bandwidth. The flat (h, 2f) view this form replaces lies
+          2f-minor by a TPU's default layout wherever h pads no less
+          than 2f against the 128-lane tile, while the product reads h
+          minor: every program re-laid every layer's weight out
+          (Falcon-H1-34B's (5120, 43008): 440 MB read and written a
+          layer, 5.7 ms of a ~20 ms device round, traced on v5e). A
+          rank-3 array lies row-major, h minor, at any widths;
         - `quantize_int8=True` (ISSUE 9, decode-only — the fp tree is
           untouched and stays the default): the four big per-layer GEMV
           weights (wqkv, wo, w1, w2) are one-shot quantized to
-          weight-only int8 with per-output-channel fp32 scales
+          weight-only int8 with per-output-channel fp32 scales, the
+          rank-3 wqkv and w1 over their last axis
           (ops/quantization.quantize_decode_layers); the decode matvecs
           read half the weight bytes. Biases/norms/embeddings/head stay
           fp — see the accuracy contract in docs/GUIDE.md ("Quantized
           serving");
         - `flatten_glu=False` (ISSUE 14, the tp-sharded serving
           engine): keep the GLU weight in the training (h, 2, f)
-          layout. The flat (h, 2f) view concatenates [gate | up] along
-          exactly the axis tensor parallelism shards, so a contiguous
-          model split would separate gates from ups and force a
-          mid-MLP reshard; the unflattened layout shards f per chip
-          and keeps the GLU elementwise-local
-          (parallel/sharding.decode_param_specs). Single-chip engines
-          keep the flatten (the sublane-bandwidth win above).
+          layout, which shards f per chip and keeps the GLU
+          elementwise-local (parallel/sharding.decode_param_specs).
+          `flatten_glu=True` (single-chip engines) holds the (2, f, h)
+          form above; the name is that of the flat (h, 2f) view it
+          replaced, whose output columns it keeps.
 
         - `wqkv` (h, qkv) is held HEAD-major, (heads, head_dim, h) with
           heads = groups x (q_per_kv + 2) in `split_qkv`'s order — its
@@ -250,8 +258,9 @@ class GPTModel:
         if quantize_int8 and not flatten_glu:
             raise ValueError(
                 "quantize_int8 requires the flattened GLU decode "
-                "layout (quantize_decode_layers quantizes the 2D "
-                "view); tp-sharded engines serve the fp decode tree")
+                "layout (flatten_glu=True: quantize_decode_layers "
+                "scales the (2, f, h) leaf's 2f output columns); "
+                "tp-sharded engines serve the fp decode tree")
         from megatron_llm_tpu.config import CapabilityError
         from megatron_llm_tpu.models.transformer import kind_stacks
 
@@ -277,8 +286,7 @@ class GPTModel:
                 layer["attention"] = attn
             if "mlp" in layer and self.cfg.glu_activation and flatten_glu:
                 mlp = dict(layer["mlp"])
-                w1 = mlp["w1"]
-                mlp["w1"] = w1.reshape(w1.shape[0], -1)
+                mlp["w1"] = mlp["w1"].transpose(1, 2, 0)
                 layer["mlp"] = mlp
             return layer
 

@@ -235,17 +235,33 @@ def _init_mamba(cfg, key, key_out, L: int, std, out_std, dt) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def glu_w1_is_hidden_major(w1, hidden_size: int) -> bool:
+    """Whether a GLU `w1` is held (h, 2, f), the training tree's and the
+    tp-sharded decode tree's form, and not gate|up-major (2, f, h), the
+    single-chip decode tree's (GPTModel.prepare_decode_params), or a
+    flat (h, 2f) leaf. Told by the leaf's shape against the hidden
+    size: the two rank-3 forms coincide only at hidden = f = 2."""
+    if is_quantized_weight(w1) or w1.ndim != 3 \
+            or w1.shape[:2] != (hidden_size, 2):
+        return False
+    assert w1.shape != (2, 2, 2), (
+        "a GLU w1 of shape (2, 2, 2) reads as (h, 2, f) and as "
+        "(2, f, h) alike")
+    return True
+
+
 @jax.named_scope("mlp")
 def mlp_block(mlp_params, cfg, hidden, dropout_rng, deterministic):
     """ParallelMLP (ref: transformer.py:77-142): h -> [2x]ffn -> act -> h.
 
     Weight-only int8 decode trees (prepare_decode_params
     (quantize_int8=True), ISSUE 9) arrive with w1/w2 as
-    {"int8_data", "scale"} dicts — always in the pre-flattened 2D
-    decode layout — and route through `qdot` (int8 GEMV + per-channel
-    scale); fp weights take the bitwise-unchanged matmuls. The published
-    muP multipliers (`mlp_multipliers`: the GLU gate's input, the down
-    projection's output) are 1 but for the Falcon-H1 family."""
+    {"int8_data", "scale"} dicts — always in the single-chip decode
+    layout, a GLU `w1` gate|up-major (2, f, h) — and route through
+    `qdot` (int8 GEMV + per-channel scale); fp weights take the
+    bitwise-unchanged matmuls. The published muP multipliers
+    (`mlp_multipliers`: the GLU gate's input, the down projection's
+    output) are 1 but for the Falcon-H1 family."""
     dt = cfg.compute_dtype
     w1 = mlp_params["w1"]
     with jax.named_scope("up"):
@@ -271,13 +287,14 @@ def mlp_block(mlp_params, cfg, hidden, dropout_rng, deterministic):
 def _mlp_up(mlp_params, cfg, hidden, w1, dt):
     """h -> [2x]ffn, bias and the named save point (the `mlp/up` scope)."""
     if cfg.glu_activation:
-        if is_quantized_weight(w1) or w1.ndim == 2:
-            # Pre-flattened (h, 2f) decode layout (see
-            # prepare_decode_params): the (h, 2, f) einsum tiles the
-            # 2-sized gate/up axis into sublanes and streams the weight
-            # at ~33% of HBM bandwidth at single-token shapes (traced on
-            # v5e); the SAME bytes as one flat matvec stream at ~72%
-            # like every other GEMV.
+        if not glu_w1_is_hidden_major(w1, hidden.shape[-1]):
+            # The single-chip decode layout, gate|up-major (2, f, h)
+            # (see prepare_decode_params), or a flat (h, 2f) leaf: one
+            # matvec over the 2f output columns, gate | up. The
+            # (h, 2, f) einsum tiles the 2-sized gate/up axis into
+            # sublanes and streams the weight at ~33% of HBM bandwidth
+            # at single-token shapes (traced on v5e); the matvec streams
+            # the SAME bytes at ~72% like every other GEMV.
             b, s, h = hidden.shape
             x = qdot(hidden, w1, dt).reshape(b, s, 2, -1)
         else:
@@ -285,8 +302,7 @@ def _mlp_up(mlp_params, cfg, hidden, w1, dt):
             # Also the tp-sharded DECODE path (ISSUE 14): mesh engines
             # keep this layout (prepare_decode_params(flatten_glu=
             # False)) so f shards over `model` and the GLU combine
-            # stays elementwise-local per chip — the flat (h, 2f) view
-            # concatenates gate|up along exactly the sharded axis.
+            # stays elementwise-local per chip.
             x = jnp.einsum("bsh,hcf->bscf", hidden, w1.astype(dt))
         if "b1" in mlp_params:
             x = x + mlp_params["b1"].astype(dt)

@@ -97,14 +97,16 @@ def quantize_weight(w: jnp.ndarray) -> dict:
     scale over axis 0, so `x @ W ~= (x @ int8) * scale[None, :]` — the
     scale application is a cheap per-column multiply on the GEMV output
     instead of a full dequantized weight materialization. The decode
-    tree's head-major `wqkv` (heads, head_dim, in_dim) quantizes over
-    its LAST axis: the same channels, their scales in column order."""
+    tree's rank-3 leaves, the head-major `wqkv` (heads, head_dim,
+    in_dim) and the gate|up-major GLU `w1` (2, f, in_dim), quantize
+    over their LAST axis: the same channels, their scales in column
+    order."""
     if w.ndim == 3:
         data, scale = quantize_rows(w, axis=-1)
         return {"int8_data": data, "scale": scale.reshape(-1)}
     assert w.ndim == 2, (
-        "weight-only quantization expects the 2D decode layout "
-        f"(prepare_decode_params flattens GLU first), got {w.shape}")
+        "weight-only quantization expects the decode layout, (in, out) "
+        f"or rank 3 with `in` last, got {w.shape}")
     data, scale = quantize_rows(w, axis=0)
     return {"int8_data": data, "scale": scale}
 
@@ -115,9 +117,9 @@ def is_quantized_weight(w) -> bool:
 
 def _wdot(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """`x @ w` for an (in, out) weight. A rank-3 weight is the decode
-    tree's head-major `wqkv` (heads, head_dim, in)
-    (GPTModel.prepare_decode_params): the same products summed over
-    `in`, the columns coming out in the same order."""
+    tree's head-major `wqkv` (heads, head_dim, in) or gate|up-major GLU
+    `w1` (2, f, in) (GPTModel.prepare_decode_params): the same products
+    summed over `in`, the columns coming out in the same order."""
     if w.ndim == 3:
         y = jnp.einsum("...h,ndh->...nd", x, w)
         return y.reshape(*y.shape[:-2], -1)
@@ -148,10 +150,11 @@ def qdot(x: jnp.ndarray, w, dt) -> jnp.ndarray:
 def _make_weight_quant_fn():
     """The jitted one-shot weight quantizer: maps the unrolled decode
     layer tuple (prepare_decode_params layout — per-layer standalone
-    trees, GLU already flattened) to the weight-only int8 tree. Biases,
-    norms, embeddings, and the LM head stay fp: their bytes are noise
-    next to the four big GEMV weights, and the head's logit precision
-    is exactly what the accuracy contract protects."""
+    trees, the GLU `w1` already gate|up-major (2, f, h)) to the
+    weight-only int8 tree. Biases, norms, embeddings, and the LM head
+    stay fp: their bytes are noise next to the four big GEMV weights,
+    and the head's logit precision is exactly what the accuracy
+    contract protects."""
 
     def quant_layers(layers):
         def one(layer):

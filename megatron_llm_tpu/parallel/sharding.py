@@ -242,12 +242,11 @@ def decode_param_specs(cfg, dec_params: dict) -> dict:
     - wqkv (head-major (heads, head_dim, h): the heads axis) / b1 (glu
       (2, f)) column-parallel: output dim over `model`
     - wo / w2 row-parallel: input dim over `model`
-    - w1 in the UNFLATTENED (h, 2, f) GLU layout: f over `model`. The
-      single-chip decode flatten to (h, 2f) concatenates [gate | up]
-      along the sharded axis, so a contiguous model split would hand
-      chip 0 all gates and chip 1 all ups and force a reshard before
-      the elementwise GLU — tp engines keep the training layout
-      (prepare_decode_params(flatten_glu=False)).
+    - w1 in the UNFLATTENED (h, 2, f) GLU layout: f over `model`, the
+      GLU elementwise-local on every chip — tp engines keep the
+      training layout (prepare_decode_params(flatten_glu=False)); the
+      single-chip decode tree's gate|up-major (2, f, h) leaf is
+      refused.
     - embedding / lm_head vocab-parallel; norms and small biases
       replicated (same rules as param_specs).
     - a layer's other parts, by what it holds: a conv's and a Mamba-2
@@ -310,13 +309,13 @@ def _attention_decode_specs(tree: dict) -> dict:
 
 
 def _mlp_decode_specs(cfg, tree: dict) -> dict:
-    w1 = tree["w1"]
+    from megatron_llm_tpu.models.transformer import glu_w1_is_hidden_major
+
     if cfg.glu_activation:
-        assert getattr(w1, "ndim", 3) == 3, (
+        assert glu_w1_is_hidden_major(tree["w1"], cfg.hidden_size), (
             "tp-sharded decode params need the UNFLATTENED (h, 2, f) "
-            "GLU layout (prepare_decode_params(flatten_glu=False)): "
-            "the flat (h, 2f) layout concatenates gate|up along the "
-            "axis tp would shard")
+            "GLU layout (prepare_decode_params(flatten_glu=False)), "
+            "which these specs shard")
         mlp = {"w1": P(None, None, MODEL_AXIS), "w2": P(MODEL_AXIS, None)}
         if "b1" in tree:
             mlp["b1"] = P(None, MODEL_AXIS)

@@ -19,12 +19,17 @@ Pinned here:
 - an engine built on a Falcon-form toy serves the tokens
   `generate_tokens` emits; on a committed tree (a restored
   checkpoint's) warm-up compiles the programs the rounds then run;
+- a GLU `w1` is held gate|up-major, (2, ffn, hidden): the training
+  (hidden, 2, ffn) leaf's transpose, fp and int8 (one scale an output
+  column, in the flat (hidden, 2 ffn) leaf's column order), and the
+  MLP's output is the flat leaf's;
 - compile-only, for a described v5e (nothing runs): the engine's own
   `decode_scan` and `mixed_step` at Falcon-7B's widths hold no
   weight-sized `copy` / `transpose` outside a fusion; handed the
   (hidden, qkv) leaf and a gathered table they hold the table's and one
   a layer for `wqkv` (the guard guards something); a head-128 GQA model
-  loses its `wqkv` copies and gains none;
+  and Falcon-H1-34B's hybrid layers hold none either, and handed the
+  flat (hidden, 2 ffn) GLU leaf one a layer for `w1`;
 - the same for the K/V page pools (ISSUE 38): held lane-packed,
   (num_pages, page_size, g * d), a pool is written and read as it lies:
   neither program at LFM2-8B-A1B's attention widths (32 slots, 8 K/V
@@ -32,6 +37,8 @@ Pinned here:
   pool's size outside a fusion.
 """
 
+import json
+import os
 import re
 
 import jax
@@ -55,6 +62,10 @@ from megatron_llm_tpu.models.language_model import (
     _rows_by_one_hot,
     embed_tokens,
 )
+from megatron_llm_tpu.models.transformer import (
+    glu_w1_is_hidden_major,
+    mlp_block,
+)
 from megatron_llm_tpu.ops import dispatch
 from megatron_llm_tpu.ops.quantization import qdot, quantize_weight
 from megatron_llm_tpu.parallel.mesh import ParallelContext, build_mesh
@@ -64,6 +75,7 @@ from megatron_llm_tpu.parallel.sharding import (
 )
 
 BF16 = jnp.bfloat16
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def falcon7b(layers=2, **over):
@@ -116,18 +128,21 @@ def decode_shapes(model, **kw):
 # ------------------------------------------------- wqkv, head-major
 
 
-@pytest.mark.parametrize("make,want", [
-    (falcon7b, (73, 64, 4544)),        # 71 q + k + v heads of 64
-    (gqa_head128, (48, 128, 4096)),    # 8 groups x (4 q + k + v) of 128
+@pytest.mark.parametrize("make,want,w1", [
+    # 71 q + k + v heads of 64; GELU: the (hidden, ffn) w1 as it is
+    (falcon7b, (73, 64, 4544), (4544, 18176)),
+    # 8 groups x (4 q + k + v) of 128; SwiGLU: w1 gate|up-major
+    (gqa_head128, (48, 128, 4096), (2, 14336, 4096)),
 ])
-def test_wqkv_is_held_head_major(make, want):
+def test_wqkv_is_held_head_major(make, want, w1):
     model = make(layers=3)
     dec = decode_shapes(model)
     assert len(dec["layers"]) == 3
     for layer in dec["layers"]:
         assert layer["attention"]["wqkv"].shape == want
         assert layer["attention"]["wo"].ndim == 2
-        assert layer["mlp"]["w1"].ndim == 2 and layer["mlp"]["w2"].ndim == 2
+        assert layer["mlp"]["w1"].shape == w1
+        assert layer["mlp"]["w2"].ndim == 2
 
 
 def test_wqkv_head_major_is_the_transpose_cut_by_head():
@@ -212,6 +227,87 @@ def test_tp_tree_shards_the_heads_axis_every_chip_its_columns():
         np.testing.assert_array_equal(
             np.asarray(shard.data).reshape(-1, h).T,
             w2d[:, r * cols:(r + 1) * cols])
+
+
+# ------------------------------------------------ GLU w1, gate|up-major
+
+
+FALCON_H1_MLP_MULTIPLIERS = (0.1767766952966369, 0.011160714285714284)
+
+
+@pytest.mark.parametrize("bias,multipliers,int8", [
+    (False, (1.0, 1.0), False),
+    (True, (1.0, 1.0), False),
+    (False, FALCON_H1_MLP_MULTIPLIERS, False),
+    (False, FALCON_H1_MLP_MULTIPLIERS, True),
+], ids=["swiglu", "swiglu-b1", "falcon-h1-multipliers", "int8"])
+def test_gate_up_major_w1_serves_what_the_flat_leaf_served(
+        bias, multipliers, int8):
+    """The decode tree's GLU `w1` is the training (hidden, 2, ffn)
+    leaf's transpose, (2, ffn, hidden), bit for bit; `mlp_block` over
+    it gives what it gave over the flat (hidden, 2 ffn) leaf, in fp32,
+    and what the training leaf gives. int8: the flat leaf's scales, in
+    its column order, and its int8 values but for rounding."""
+    model = GPTModel(tiny_config(
+        num_layers=1, use_bias=bias, mlp_multipliers=multipliers,
+        compute_dtype=jnp.float32, use_decode_attn=False))
+    cfg, h, f = model.cfg, model.cfg.hidden_size, model.cfg.ffn_hidden_size
+    params = model.init(jax.random.key(11))
+    mlp = params["layers"]["mlp"]
+    if bias:  # drawn, not the zeros init gives
+        k1, k2 = jax.random.split(jax.random.key(12))
+        mlp["b1"] = jax.random.normal(k1, mlp["b1"].shape)
+        mlp["b2"] = jax.random.normal(k2, mlp["b2"].shape)
+    trained = jax.tree.map(lambda x: x[0], mlp)
+    dec = model.prepare_decode_params(params, quantize_int8=int8)
+    held = dec["layers"][0]["mlp"]
+    w1 = held["w1"]
+    if int8:
+        assert w1["int8_data"].shape == (2, f, h)
+        want = quantize_weight(trained["w1"].reshape(h, -1))
+        np.testing.assert_allclose(np.asarray(w1["scale"]),
+                                   np.asarray(want["scale"]), rtol=1e-6)
+        data = np.asarray(w1["int8_data"], np.int32).reshape(-1, h).T
+        steps = np.abs(data - np.asarray(want["int8_data"], np.int32))
+        assert steps.max() <= 1 and (steps > 0).mean() < 1e-3
+        flat = {"int8_data": w1["int8_data"].reshape(-1, h).T,
+                "scale": w1["scale"]}
+    else:
+        np.testing.assert_array_equal(
+            np.asarray(w1), np.asarray(trained["w1"].transpose(1, 2, 0)))
+        flat = trained["w1"].reshape(h, -1)
+    x = jax.random.normal(jax.random.key(13), (2, 5, h))
+
+    def out(tree):
+        return np.asarray(mlp_block(tree, cfg, x, None, True))
+
+    got = out(held)
+    # the same products summed over hidden (equal on the CPU; another
+    # order of the sums would differ in the last bits)
+    tol = dict(rtol=1e-6, atol=1e-7 * np.abs(got).max())
+    np.testing.assert_allclose(got, out({**held, "w1": flat}), **tol)
+    if not int8:
+        np.testing.assert_allclose(got, out(trained), **tol)
+
+
+def test_glu_w1_forms_are_told_apart_by_shape():
+    """(hidden, 2, ffn) is the training and tp form; (2, ffn, hidden)
+    and a flat (hidden, 2 ffn) leaf are not; the two rank-3 forms
+    coincide at hidden = ffn = 2 alone, which is refused."""
+    def leaf(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    assert glu_w1_is_hidden_major(leaf(64, 2, 64), 64)
+    assert glu_w1_is_hidden_major(leaf(64, 2, 96), 64)
+    assert not glu_w1_is_hidden_major(leaf(2, 96, 64), 64)
+    assert not glu_w1_is_hidden_major(leaf(2, 64, 64), 64)
+    assert not glu_w1_is_hidden_major(leaf(64, 192), 64)
+    assert not glu_w1_is_hidden_major(
+        {"int8_data": leaf(2, 96, 64), "scale": leaf(192)}, 64)
+    assert glu_w1_is_hidden_major(leaf(2, 2, 8), 2)    # (h, 2, f)
+    assert not glu_w1_is_hidden_major(leaf(2, 8, 2), 2)  # (2, f, h)
+    with pytest.raises(AssertionError, match="alike"):
+        glu_w1_is_hidden_major(leaf(2, 2, 2), 2)
 
 
 # ------------------------------------------- the table's rows, one-hot
@@ -347,25 +443,30 @@ def lowering_for_tpu(monkeypatch):
         yield
 
 
-def round_programs(model, dev, as_before: bool = False, slots=8,
-                   chunk=128, page_size=64, max_context=2048):
+def round_programs(model, dev, as_before=(), slots=8, chunk=128,
+                   page_size=64, max_context=2048):
     """{name: compiled text} of the engine's own decode_scan (horizon
     1) and mixed_step (the chunk's width), lowered as
     `benchmark/sizing.py size_serve` lowers them, on the decode tree's
-    shapes. `as_before`: the tree as it was held before ISSUE 34 (the
-    (hidden, qkv) leaf; the caller also turns the one-hot rows off)."""
+    shapes. `as_before`: the rank-3 leaves held flat, as the decode
+    tree once held them: "wqkv" the (hidden, qkv) leaf (the caller also
+    turns the one-hot rows off), "w1" a GLU's (hidden, 2 ffn) leaf."""
     cfg = model.cfg
     L, V = cfg.num_layers, cfg.padded_vocab_size
 
     def arr(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=dev)
 
+    def flat(w):  # (..., hidden) -> (hidden, ...): the columns in order
+        return w.reshape(-1, w.shape[-1]).T
+
     def tree():
         dec = model.prepare_decode_params(model.init(jax.random.key(0)))
-        if as_before:
-            for layer in dec["layers"]:
-                w = layer["attention"]["wqkv"]
-                layer["attention"]["wqkv"] = w.reshape(-1, w.shape[-1]).T
+        for layer in dec["layers"]:
+            if "wqkv" in as_before:
+                layer["attention"]["wqkv"] = flat(layer["attention"]["wqkv"])
+            if "w1" in as_before and cfg.glu_activation:
+                layer["mlp"]["w1"] = flat(layer["mlp"]["w1"])
         return dec
 
     dec = jax.tree.map(lambda x: arr(x.shape, x.dtype), jax.eval_shape(tree))
@@ -431,7 +532,7 @@ def test_no_weight_sized_copy_v5e(one_chip, lowering_for_tpu, monkeypatch):
         assert weight_sized_copies(text, floor) == [], name
     monkeypatch.setattr(language_model, "_rows_by_one_hot",
                         lambda *a: False)
-    for name, text in round_programs(model, one_chip, True).items():
+    for name, text in round_programs(model, one_chip, ("wqkv",)).items():
         found = weight_sized_copies(text, floor)
         assert len(found) == 3, (name, found)
         assert sum("[65024,4544]" in line for line in found) == 1
@@ -441,19 +542,54 @@ def test_no_weight_sized_copy_v5e(one_chip, lowering_for_tpu, monkeypatch):
 def test_head128_gqa_gains_no_weight_sized_copy_v5e(one_chip,
                                                     lowering_for_tpu):
     """A Llama-3-8B-shaped model: its table lies hidden-minor and is
-    gathered as before; the head-major `wqkv` loses its copy a layer.
-    What is left is the flat GLU `w1`'s, one a layer, as before (its
-    columns are cut into gate | up the same way: PERF.md §7)."""
+    gathered as before; the head-major `wqkv` and the gate|up-major GLU
+    `w1` lose their copy a layer each. Handed the flat leaves, each
+    program holds both again: the (hidden, qkv) leaf's, and the flat
+    (hidden, 2 ffn) `w1`'s, whose columns are cut into gate | up as the
+    projection's are cut into heads."""
     model = gqa_head128(layers=2)
     floor = model.cfg.hidden_size ** 2
     for name, text in round_programs(model, one_chip).items():
-        found = weight_sized_copies(text, floor)
-        assert len(found) == 2, (name, found)
-        assert all("[28672,4096]" in line for line in found), (name, found)
-    for name, text in round_programs(model, one_chip, True).items():
+        assert weight_sized_copies(text, floor) == [], name
+    for name, text in round_programs(model, one_chip,
+                                     ("wqkv", "w1")).items():
         found = weight_sized_copies(text, floor)
         assert len(found) == 4, (name, found)
         assert sum("[4096,6144]" in line for line in found) == 2
+        assert sum("[28672,4096]" in line for line in found) == 2
+
+
+def falcon_h1_34b(layers=2):
+    """tiiuae/Falcon-H1-34B-Instruct as its serving cell builds it
+    (`benchmark/families/falcon_h1.py`): every layer attention (20
+    heads of 128 over 4 K/V heads) and a Mamba-2 mixer side by side on
+    one normed input, then SwiGLU at 21,504; hidden 5,120, an untied
+    261,120-row table and head, bf16."""
+    from benchmark import families
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "falcon-h1-34b-instruct.json")) as f:
+        cfg = json.load(f)
+    use = dict(cfg["serve"], num_hidden_layers=layers)
+    return families.find(cfg).model(cfg, use)
+
+
+def test_falcon_h1_hybrid_layers_hold_no_weight_sized_copy_v5e(
+        one_chip, lowering_for_tpu):
+    """Falcon-H1-34B's widths, 2 hybrid layers, 64 slots (its serving
+    cell's): neither program re-lays a weight out. Handed the flat
+    (hidden, 2 ffn) GLU leaf, each holds one copy of it a layer,
+    `[43008,5120]`: 440 MB read and written a layer a round."""
+    model = falcon_h1_34b(layers=2)
+    assert model.cfg.layer_kinds == (("hybrid", "mlp"),) * 2
+    floor = model.cfg.hidden_size ** 2
+    for name, text in round_programs(model, one_chip, slots=64).items():
+        assert weight_sized_copies(text, floor) == [], name
+    for name, text in round_programs(model, one_chip, ("w1",),
+                                     slots=64).items():
+        found = weight_sized_copies(text, floor)
+        assert len(found) == 2, (name, found)
+        assert all("[43008,5120]" in line for line in found), (name, found)
 
 
 # ------------------------------------------- the K/V page pools (ISSUE 38)

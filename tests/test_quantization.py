@@ -116,10 +116,15 @@ class TestWeightQuant:
         model = LlamaModel(cfg)
         params = model.init(jax.random.key(0))
         dec = model.prepare_decode_params(params)
-        for layer in dec["layers"]:
+        for j, layer in enumerate(dec["layers"]):
             assert isinstance(layer["attention"]["wqkv"], jax.Array)
             assert isinstance(layer["mlp"]["w1"], jax.Array)
-            assert layer["mlp"]["w1"].ndim == 2  # flattened GLU
+            # the GLU gate|up-major, (2, f, h): the training leaf's
+            # transpose, bit for bit
+            np.testing.assert_array_equal(
+                np.asarray(layer["mlp"]["w1"]),
+                np.asarray(params["layers"]["mlp"]["w1"][j]
+                           .transpose(1, 2, 0)))
 
 
 # ---------------------------------------------------------------------------

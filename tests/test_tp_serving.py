@@ -6,8 +6,8 @@ The contract, pinned here:
   group axis of a paged-pool leaf (data AND int8 scale pools) when tp
   divides it; the engine's live pools follow the rule, page tables /
   lengths / sampling arrays stay replicated, and the decode param tree
-  shards by `decode_param_specs` (which refuses the flattened-GLU
-  layout whose gate|up concat crosses the shard boundary).
+  shards by `decode_param_specs` (which refuses the single-chip
+  gate|up-major GLU layout, (2, f, h), and wants (h, 2, f), f sharded).
 - **Parity.** The tp2 virtual-CPU-mesh engine's greedy TOKEN streams
   are BITWISE the single-chip engine's across chunked prefill,
   prefix-cache COW, speculative decoding, whole-prompt prefill, and
@@ -88,7 +88,11 @@ class TestPoolSpecRule:
 
     def test_decode_param_specs_refuses_flattened_glu(self, tiny_model):
         model, params = tiny_model
-        flat = model.prepare_decode_params(params)  # flatten_glu=True
+        # flatten_glu=True: the GLU gate|up-major, (2, f, h), rank 3
+        # like the (h, 2, f) form the specs shard, and refused by form
+        flat = model.prepare_decode_params(params)
+        assert flat["layers"][0]["mlp"]["w1"].shape == (
+            2, model.cfg.ffn_hidden_size, model.cfg.hidden_size)
         with pytest.raises(AssertionError, match="UNFLATTENED"):
             decode_param_specs(model.cfg, flat)
 
